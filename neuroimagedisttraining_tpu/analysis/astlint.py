@@ -96,11 +96,6 @@ def _allowlisted(rel: str) -> bool:
             return True
     return False
 
-#: module prefixes exempt from the MODULE-WIDE host-sync family (the
-#: traced-context rules still apply): standalone kernel debug harnesses
-#: whose whole point is printing device values — not on any round path
-HOST_SYNC_ALLOWLIST_PREFIXES = ("ops/experimental/",)
-
 #: higher-order functions whose function-valued arguments are traced
 _TRACING_HOFS = {
     "jax.jit", "jit", "jax.vmap", "vmap", "jax.pmap", "pmap",
@@ -111,7 +106,7 @@ _TRACING_HOFS = {
     "jax.lax.while_loop", "lax.while_loop",
     "jax.lax.fori_loop", "lax.fori_loop",
     "jax.lax.associative_scan", "lax.associative_scan",
-    "shard_map", "jax.experimental.shard_map.shard_map",
+    "shard_map", "jax.shard_map",
 }
 
 #: dotted roots that mark an expression as a JAX array computation
@@ -422,11 +417,8 @@ class PackageLint:
                         "utils.profiling.Timer is a deprecated shim; "
                         "use obs.metrics.SectionTimer"))
 
-        # module-wide host-sync family (jit-path packages, minus the
-        # reviewed debug-harness prefixes)
-        posix_rel = rel.replace(os.sep, "/")
-        if jit_path and not posix_rel.startswith(
-                HOST_SYNC_ALLOWLIST_PREFIXES):
+        # module-wide host-sync family (jit-path packages)
+        if jit_path:
             out.extend(self._host_sync_rules(mod, mod.tree))
 
         # use-after-donation: every module (driver paths call the

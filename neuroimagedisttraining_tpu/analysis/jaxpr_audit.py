@@ -15,7 +15,7 @@ checks the contracts the runtime tests can only sample:
   that.
 * **collective consistency** — the SPMD race-detector analog this
   codebase needs: the multiset of collective primitives (``psum`` /
-  ``psum2``, ``all_gather``, ``ppermute``, ``reduce_scatter``, ...)
+  ``psum_invariant``, ``all_gather``, ``ppermute``, ``reduce_scatter``, ...)
   with their axis names must be (a) identical between the fused and
   unfused round programs and (b) identical across the branches of
   every ``lax.cond`` (the guard's clean/quarantine split, watchdog
@@ -44,10 +44,14 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from .findings import Finding
 
-#: explicit collective primitives (shard_map spells psum as psum2)
+#: explicit collective primitives, as jax 0.9 names them in a traced
+#: jaxpr: under shard_map's varying-axes check (``check_vma``, the default)
+#: a psum / all_gather whose result is replicated is traced as the
+#: ``*_invariant`` primitive; with the check off the plain names appear
 COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "all_gather", "all_to_all", "ppermute",
-    "reduce_scatter", "pmin", "pmax", "pgather", "pbroadcast",
+    "psum", "psum_invariant", "all_gather", "all_gather_invariant",
+    "all_gather_reduced", "all_to_all", "ppermute", "reduce_scatter",
+    "pmin", "pmax", "pgather", "pbroadcast",
 })
 
 #: dtypes legal on the round hot path (str(aval.dtype)); PRNG key
@@ -150,14 +154,13 @@ class JaxprSummary:
 def summarize(fn: Callable, *args, x64: bool = False) -> JaxprSummary:
     """Trace ``fn(*args)`` (no compute) and summarize its jaxpr.
 
-    ``x64=True`` traces under ``jax.experimental.enable_x64`` so latent
+    ``x64=True`` traces under ``jax.enable_x64`` so latent
     f64 promotions (Python floats, np scalars) surface as f64 in the
     jaxpr instead of being silently demoted by the global x64-off
     default — the mode the seeded-violation fixtures run in."""
     import jax
 
-    ctx = jax.experimental.enable_x64() if x64 \
-        else contextlib.nullcontext()
+    ctx = jax.enable_x64(True) if x64 else contextlib.nullcontext()
     with ctx:
         jaxpr = jax.make_jaxpr(fn)(*args)
     s = JaxprSummary()

@@ -211,7 +211,11 @@ def build_parser(algo: Optional[str] = None) -> argparse.ArgumentParser:
                         "distinguish an explicit choice from the default "
                         "when continuing a pre-round-4 lineage")
     p.add_argument("--client_chunk", type=int, default=0,
-                   help="chunk vmapped clients to bound HBM (0 = full vmap)")
+                   help="clients trained at once per lax.map step, to "
+                        "bound HBM (0 = auto: one at a time where a "
+                        "single memory-limited device holds the whole "
+                        "cohort, the full vmap on a clients mesh or on "
+                        "CPU)")
     p.add_argument("--fuse_rounds", type=int, default=1,
                    help="execute the round loop in K-round fused programs "
                         "(lax.scan over rounds — one dispatch + one metric "
@@ -295,18 +299,7 @@ def build_parser(algo: Optional[str] = None) -> argparse.ArgumentParser:
                         "never enters run identity; 0 restores the "
                         "contract-everything-then-reduce order for A/B "
                         "timing")
-    import os as _os
-
-    p.add_argument("--donate_state", type=int,
-                   # product default: ON. The env override exists for
-                   # compile-budget-bound CI (tests/conftest.py): a
-                   # donated executable cannot use the persistent
-                   # compilation cache (base._no_persistent_cache_write
-                   # — jaxlib 0.4.37 corrupts donated executables on
-                   # reload), so the suite runs the borrow default and
-                   # the donation suites opt in explicitly
-                   default=int(_os.environ.get(
-                       "NIDT_DONATE_STATE_DEFAULT", "1")),
+    p.add_argument("--donate_state", type=int, default=1,
                    help="state-ownership protocol: round/fused/finetune "
                         "entry points take ownership of their input "
                         "state (jit donate_argnums), so the [C, model] "

@@ -805,12 +805,11 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     # disagree on a verdict: the same per-metric defaults (comm SLO
     # metrics are lower-is-better with their own band), the same
     # fresh-clone auto-backfill of the default history from the
-    # committed BENCH_r*/MULTICHIP_r* artifacts, and the same
+    # committed MULTICHIP_r* artifacts, and the same
     # own-commit exclusion (a rerun's just-appended measurement must
     # not join its own baseline)
     if not os.path.exists(args.history) and \
             args.history == "results/bench_history.jsonl":
-        obs_regress.backfill_bench_files(os.getcwd(), args.history)
         obs_regress.backfill_multichip_files(os.getcwd(), args.history)
     defaults = obs_regress.metric_gate_defaults(args.metric)
     verdict = obs_regress.gate(

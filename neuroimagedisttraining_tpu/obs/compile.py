@@ -96,7 +96,12 @@ class CompileWatch:
             return
         try:
             if event.startswith(COMPILE_CACHE_EVENTS_PREFIX):
-                self._registry.counter(_cache_counter_name(event)).inc()
+                # labeled by entry point like the durations above, so a
+                # run can say WHICH program hit the persistent cache
+                c = self._registry.counter(_cache_counter_name(event))
+                c.inc()
+                c.labels(entry=obs_trace.current_span_name()
+                         or "untraced").inc()
         except Exception:  # same unguarded-listener rule as above
             logger.debug("cache-event recording failed", exc_info=True)
 

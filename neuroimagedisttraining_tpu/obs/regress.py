@@ -1,13 +1,11 @@
 """Noise-aware cross-run performance regression detection.
 
-Every perf PR so far was judged by eyeballing one ``bench.py`` JSON line
-against the previous round's ``BENCH_r*.json``. This module makes the
-verdict mechanical and noise-aware:
+Makes the verdict over one ``bench.py`` JSON line mechanical and
+noise-aware:
 
 * ``results/bench_history.jsonl`` is the durable trajectory — one JSON
   line per bench result (metric, value, unit, git SHA, source).
-  ``bench.py`` appends to it on every run; :func:`backfill_bench_files`
-  seeds it once from the committed ``BENCH_r*.json`` driver artifacts.
+  ``bench.py`` appends to it on every run.
 * :func:`detect_regression` compares a current value against the
   history's recent window with a median/MAD band: the allowed drop is
   ``max(rel_threshold * median, mad_k * 1.4826 * MAD)`` — a noisy
@@ -32,7 +30,7 @@ from typing import Any, Dict, List, Optional
 __all__ = [
     "EXIT_NO_HISTORY", "EXIT_OK", "EXIT_REGRESSION",
     "METRIC_GATE_DEFAULTS", "MULTICHIP_METRICS", "append_history",
-    "backfill_bench_files", "backfill_multichip_files",
+    "backfill_multichip_files",
     "detect_regression", "gate", "git_sha", "last_json_result",
     "metric_gate_defaults", "parse_multichip_artifact", "read_history",
 ]
@@ -130,52 +128,6 @@ def last_json_result(text: str,
         if isinstance(cand, dict) and all(k in cand for k in required):
             result = cand
     return result
-
-
-def parse_bench_artifact(path: str) -> Optional[Dict[str, Any]]:
-    """One committed ``BENCH_r*.json`` driver artifact -> the bench
-    result JSON object its captured stdout tail holds (None when the
-    run failed or printed no JSON line)."""
-    with open(path) as f:
-        doc = json.load(f)
-    if doc.get("rc") not in (0, None):
-        return None
-    result = last_json_result(str(doc.get("tail", "")))
-    if result is not None and isinstance(doc.get("n"), int):
-        result = {**result, "bench_round": doc["n"]}
-    return result
-
-
-def backfill_bench_files(repo_root: str, history_path: str) -> int:
-    """One-shot seed of the history from the repo's ``BENCH_r*.json``
-    files. Idempotent: artifacts whose (metric, bench_round) already
-    appear in the history are skipped. Returns entries appended."""
-    import glob
-
-    existing = {(e.get("metric"), e.get("bench_round"))
-                for e in read_history(history_path)
-                if e.get("bench_round") is not None}
-    appended = 0
-    for path in sorted(glob.glob(os.path.join(repo_root,
-                                              "BENCH_r*.json"))):
-        result = parse_bench_artifact(path)
-        if result is None:
-            continue
-        key = (result.get("metric"), result.get("bench_round"))
-        if key in existing:
-            continue
-        # bench_round carried on the entry keeps the backfill
-        # idempotent; git_sha is deliberately blank — the artifact's
-        # value was NOT measured at the current checkout, and gate()'s
-        # own-commit exclusion must never drop the seeded baseline
-        append_history(history_path, result,
-                       source=os.path.basename(path),
-                       repo_root=repo_root,
-                       bench_round=result.get("bench_round"),
-                       git_sha="")
-        existing.add(key)
-        appended += 1
-    return appended
 
 
 #: the scale-32 line a MULTICHIP_r*.json dry-run tail prints when the

@@ -12,8 +12,10 @@ gradient *before* the momentum accumulate, ``DisPFL/my_model_trainer.py:
 147-172``).
 
 Layout: each leaf is raveled and padded to (rows, 128) float32 — the VPU
-lane width; rows are padded to the (8, 128) f32 tile. On non-TPU backends
-the kernels run in interpreter mode so CPU tests exercise identical code.
+lane width; rows are padded to the (8, 128) f32 tile. On the ``cpu``
+backend the kernels run in interpreter mode so the tests exercise identical
+code; on a TPU they compile through Mosaic (``pytest -m tpu`` pins every
+kernel at the flagship's shapes); any other backend is an error.
 """
 from __future__ import annotations
 
@@ -31,7 +33,18 @@ _BLOCK_ROWS = 512  # 512x128 f32 = 256 KiB/operand: comfortably inside VMEM
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Emulate only where the tests run. These kernels are written for the
+    TPU's memory spaces; on any other accelerator an emulation would pass
+    for the kernel while running something else."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the pallas kernels target TPU (interpreted on cpu for tests); "
+        f"backend {backend!r} has no lowering for them — use the XLA "
+        "spellings (--agg_kernels xla, --fused_kernels 0)")
 
 
 def _to_2d(x: jax.Array) -> Tuple[jax.Array, int]:
@@ -193,43 +206,51 @@ def fused_weighted_sum(stacked_tree: Any, weights: jax.Array) -> Any:
 # largest bit pattern with count >= k), so the backends are bit-identical
 # by construction, not by tolerance.
 
-#: per-row element cap for the VMEM-resident search: the row (f32), its
-#: int32 bit view and one compare temp must share VMEM, so rows above
-#: this fall back to the XLA search (same bits, different residency)
-THRESHOLD_MAX_N = 1 << 20
+#: per-row element cap for the VMEM-resident search: the row is held in
+#: VMEM (double-buffered by the pipeline, 2 x 4 B x n) while the count
+#: passes stream over it chunk by chunk. 4 Mi elements = 32 MiB, inside
+#: every TPU generation's VMEM; the flagship's whole-model SNIP score
+#: row (2.57 M) fits. Larger rows are refused, not rerouted.
+THRESHOLD_MAX_N = 1 << 22
 
-#: f32-block byte budget used to pick how many rows share one kernel
-#: instance (x + bits + temp keeps the total well under VMEM)
-_THRESH_BLOCK_BYTES = 1 << 22
-
-
-def threshold_supported(n: int) -> bool:
-    """Can the pallas threshold kernel hold an n-element row in VMEM?"""
-    return int(n) <= THRESHOLD_MAX_N
+#: rows of LANES counted per inner step (512 x 128 f32 = 256 KiB of
+#: temporaries, whatever the row length)
+_THRESH_CHUNK_ROWS = 512
 
 
 def _threshold_kernel(k_ref, av_ref, out_ref, *, iters: int,
-                      bits_hi: int):
-    """Bit-space binary search over one (cb, rows, LANES) magnitude
-    block: lo converges to the k-th largest magnitude's bit pattern
-    (topk_select.exact_threshold, same invariant/fixed point)."""
-    bits = jax.lax.bitcast_convert_type(av_ref[:], jnp.int32)
-    k = k_ref[0]
-    cb = bits.shape[0]
-    lo0 = jnp.zeros((cb, 1, 1), jnp.int32)
-    hi0 = jnp.full((cb, 1, 1), bits_hi, jnp.int32)
+                      bits_hi: int, chunk_rows: int):
+    """Bit-space binary search over ONE row's (rows, LANES) magnitude
+    panel: lo converges to the k-th largest magnitude's bit pattern
+    (topk_select.exact_threshold, same invariant/fixed point). Counts
+    accumulate in f32 — exact, the row holds < 2^24 elements — because
+    float reductions are the ones every Mosaic version lowers."""
+    k = k_ref[0].astype(jnp.float32)
+    n_chunks = av_ref.shape[0] // chunk_rows
+
+    def count_ge(mid):
+        def chunk(j, acc):
+            start = pl.multiple_of(j * chunk_rows, chunk_rows)
+            bits = jax.lax.bitcast_convert_type(
+                av_ref[pl.ds(start, chunk_rows), :], jnp.int32)
+            hit = jnp.where(bits >= mid, 1.0, 0.0).astype(jnp.float32)
+            return acc + jnp.sum(
+                hit.reshape(chunk_rows // SUBLANES, SUBLANES, LANES),
+                axis=0)
+
+        acc = jax.lax.fori_loop(
+            0, n_chunks, chunk, jnp.zeros((SUBLANES, LANES), jnp.float32))
+        return jnp.sum(acc)
 
     def body(_, lohi):
         lo, hi = lohi
-        mid = lo + (hi - lo) // 2
-        cnt = jnp.sum((bits >= mid).astype(jnp.int32), axis=(1, 2),
-                      keepdims=True)
-        ok = cnt >= k
+        mid = lo + ((hi - lo) >> 1)     # hi > lo >= 0: shift == floor-div
+        ok = count_ge(mid) >= k
         return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid)
 
-    lo, _ = jax.lax.fori_loop(0, iters, body, (lo0, hi0))
-    thr = jax.lax.bitcast_convert_type(lo, jnp.float32)
-    out_ref[:] = jnp.broadcast_to(thr[:, 0], (cb, LANES))
+    lo, _ = jax.lax.fori_loop(
+        0, iters, body, (jnp.int32(0), jnp.int32(bits_hi)))
+    out_ref[:] = jnp.full(out_ref.shape, lo, jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -237,38 +258,48 @@ def threshold_topk(av: jax.Array, k: int) -> jax.Array:
     """Exact k-th largest magnitude per row of a [C, n] nonneg f32
     matrix, VMEM-resident; returns [C, 1] f32 — bit-identical to
     ``topk_select.exact_threshold(av, k)`` (and so to the sort
-    spelling) under the tie-break contract. Rows are zero-padded to the
-    (SUBLANES, LANES) tile; pad bits (0) never reach a count at any
-    positive cut, and a cut can only fall to 0 when the true threshold
-    IS 0.0, where counting pads is already harmless."""
+    spelling) under the tie-break contract. One grid step per row; rows
+    are zero-padded to whole count chunks; pad bits (0) never reach a
+    count at any positive cut, and a cut can only fall to 0 when the
+    true threshold IS 0.0, where counting pads is already harmless."""
     from .topk_select import _BITS_HI, SEARCH_ITERS
 
     c, n = av.shape
-    per_panel = LANES * SUBLANES
-    n_pad = ((n + per_panel - 1) // per_panel) * per_panel
-    rows = n_pad // LANES
-    cb = max(1, min(c, _THRESH_BLOCK_BYTES // (n_pad * 4)))
-    c_pad = ((c + cb - 1) // cb) * cb
-    av2 = jnp.pad(av.astype(jnp.float32),
-                  ((0, c_pad - c), (0, n_pad - n)))
-    panels = av2.reshape(c_pad, rows, LANES)
+    if n > THRESHOLD_MAX_N:
+        raise ValueError(
+            f"the pallas threshold kernel keeps each row VMEM-resident "
+            f"and takes at most THRESHOLD_MAX_N={THRESHOLD_MAX_N} elements "
+            f"per row, got {n}; agg_kernels='xla' computes the identical "
+            "threshold at any size")
+    rows = -(-n // (LANES * SUBLANES)) * SUBLANES
+    chunk_rows = min(_THRESH_CHUNK_ROWS, rows)
+    rows = -(-rows // chunk_rows) * chunk_rows
+    panels = jnp.pad(av.astype(jnp.float32),
+                     ((0, 0), (0, rows * LANES - n))).reshape(
+                         c, rows, LANES)
 
     kernel = functools.partial(_threshold_kernel, iters=SEARCH_ITERS,
-                               bits_hi=int(_BITS_HI))
+                               bits_hi=int(_BITS_HI),
+                               chunk_rows=chunk_rows)
     out = pl.pallas_call(
         kernel,
-        grid=(c_pad // cb,),
+        grid=(c,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # k scalar
-            pl.BlockSpec((cb, rows, LANES), lambda i: (i, 0, 0),
+            pl.BlockSpec((None, rows, LANES), lambda i: (i, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((cb, LANES), lambda i: (i, 0),
+        out_specs=pl.BlockSpec((None, SUBLANES, LANES),
+                               lambda i: (i, 0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((c_pad, LANES), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((c, SUBLANES, LANES), jnp.int32),
+        # the double-buffered row plus headroom for the count chunks;
+        # the default scoped limit (16 MiB on v5e) is below one 4 Mi row
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=2 * rows * LANES * 4 + (8 << 20)),
         interpret=_interpret(),
     )(jnp.asarray([k], jnp.int32), panels)
-    return out[:c, :1]
+    return jax.lax.bitcast_convert_type(out[:, 0, :1], jnp.float32)
 
 
 # -- fused int8 quantize + weighted bucketed reduce ---------------------------
@@ -300,15 +331,6 @@ def threshold_topk(av: jax.Array, k: int) -> jax.Array:
 _QR_CHUNK_BYTES = 1 << 21
 
 
-def quantize_reduce_supported(bucket: int) -> bool:
-    """Fused-kernel eligibility: chunks must tile (SUBLANES x LANES)
-    exactly and align to bucket boundaries (one scale per chunk), so
-    the bucket must be a multiple of the 1024-element panel; anything
-    else routes to the bit-identical XLA spelling."""
-    per_panel = LANES * SUBLANES
-    return int(bucket) % per_panel == 0
-
-
 def _qreduce_kernel(w_ref, x_ref, u_ref, s_ref, out_ref):
     x = x_ref[:]                        # (C, chunk)
     u = u_ref[:]
@@ -316,7 +338,9 @@ def _qreduce_kernel(w_ref, x_ref, u_ref, s_ref, out_ref):
     y = x / scale
     f = jnp.floor(y)
     q = jnp.clip(f + (u < (y - f)).astype(jnp.float32), -127.0, 127.0)
-    out_ref[:] = jnp.dot(w_ref[:], q * scale)   # (1,C)@(C,chunk)
+    out_ref[:] = jnp.dot(w_ref[:], q * scale,   # (8,C)@(C,chunk)
+                         preferred_element_type=jnp.float32,
+                         precision=jax.lax.Precision.HIGHEST)
 
 
 @jax.jit
@@ -330,19 +354,31 @@ def fused_quantize_reduce(buckets: jax.Array, weights: jax.Array,
     max-abs/127 scale — both computed by the caller with the exact
     spelling of the XLA chain, so backend bit-identity needs only this
     kernel's chunk math to match (it does: shared dot primitive, see
-    module comment). Returns [nb, b] f32. Caller guards with
-    :func:`quantize_reduce_supported`."""
+    module comment). Returns [nb, b] f32.
+
+    Any bucket size runs: buckets are zero-padded here to the 1024-element
+    (SUBLANES x LANES) panel the chunks tile — a zero quantizes to zero
+    under every draw, and the scales come from the caller's unpadded
+    buckets — and the pad columns are sliced off the result."""
     c, nb, b = buckets.shape
-    n = nb * b
-    x = buckets.astype(jnp.float32).reshape(c, n)
-    u = uniforms.astype(jnp.float32).reshape(c, n)
     per_panel = LANES * SUBLANES
+    b_pad = -(-b // per_panel) * per_panel
+    n = nb * b_pad
+    pad = ((0, 0), (0, 0), (0, b_pad - b))
+    x = jnp.pad(buckets.astype(jnp.float32), pad).reshape(c, n)
+    u = jnp.pad(uniforms.astype(jnp.float32), pad).reshape(c, n)
     budget = max(per_panel,
                  (_QR_CHUNK_BYTES // (max(c, 1) * 4)) // per_panel
                  * per_panel)
-    chunk = min(b, budget)
-    while b % chunk:                    # b % per_panel == 0 (guard), so
-        chunk -= per_panel              # this terminates at per_panel
+    chunk = min(b_pad, budget)
+    while b_pad % chunk:                # b_pad % per_panel == 0, so this
+        chunk -= per_panel              # terminates at per_panel
+    # the weights ride the MXU's minimum 8-row tile (rows 1..7 zero);
+    # scales are laid out [nb, C, 1] so each chunk's (C, 1) block spans
+    # the array's last two dimensions whole, which the TPU tiling accepts
+    w8 = jnp.zeros((SUBLANES, c), jnp.float32).at[0].set(
+        weights.astype(jnp.float32))
+    s3 = scales.astype(jnp.float32).T[..., None]
 
     block = pl.BlockSpec((c, chunk), lambda ci: (0, ci),
                          memory_space=pltpu.VMEM)
@@ -350,18 +386,18 @@ def fused_quantize_reduce(buckets: jax.Array, weights: jax.Array,
         _qreduce_kernel,
         grid=(n // chunk,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),      # (1, C) weights
+            pl.BlockSpec(memory_space=pltpu.VMEM),      # (8, C) weights
             block, block,
-            pl.BlockSpec((c, 1), lambda ci: (0, ci * chunk // b),
+            pl.BlockSpec((None, c, 1),
+                         lambda ci: (ci * chunk // b_pad, 0, 0),
                          memory_space=pltpu.VMEM),      # bucket scale
         ],
-        out_specs=pl.BlockSpec((1, chunk), lambda ci: (0, ci),
+        out_specs=pl.BlockSpec((SUBLANES, chunk), lambda ci: (0, ci),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((SUBLANES, n), jnp.float32),
         interpret=_interpret(),
-    )(weights.astype(jnp.float32).reshape(1, c), x, u,
-      scales.astype(jnp.float32))
-    return out.reshape(nb, b)
+    )(w8, x, u, s3)
+    return out[0].reshape(nb, b_pad)[:, :b]
 
 
 # -- fused SNIP mask ops (SalientGrads selection path) ------------------------

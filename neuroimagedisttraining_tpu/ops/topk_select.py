@@ -48,8 +48,9 @@ Backends (the ``--agg_kernels`` surface, threaded from
 * ``"pallas"`` — the fused Pallas kernel (ops/pallas_kernels.py): the
   magnitudes stay VMEM-resident across all 31 count passes, one HBM
   read total. Bit-identical to ``"xla"`` by construction (both converge
-  to the same unique integer fixed point); rows too large for VMEM fall
-  back to the XLA search, which changes nothing but residency.
+  to the same unique integer fixed point); a row too large for VMEM
+  (``pallas_kernels.THRESHOLD_MAX_N``) is refused with the reason —
+  a ``pallas`` request never runs the XLA search in silence.
 * ``"sort"`` — the legacy ``lax.top_k`` spelling, kept as the internal
   reference for parity tests and bench baselines (not a flag choice).
 """
@@ -155,10 +156,7 @@ def select_threshold(av: jax.Array, k: int, *, kernels: str = "xla",
     if kernels == "pallas":
         from . import pallas_kernels as pk
 
-        if pk.threshold_supported(n):
-            return pk.threshold_topk(av, k)
-        # VMEM-oversized rows: the XLA search computes the identical
-        # integer fixed point — residency changes, bits do not
+        return pk.threshold_topk(av, k)  # refuses rows VMEM cannot hold
     return exact_threshold(av, k)
 
 

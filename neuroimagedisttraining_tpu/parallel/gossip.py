@@ -15,12 +15,8 @@ import functools
 from typing import Any, Tuple
 
 import jax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:  # jax >= 0.7 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax ships it in experimental
-    from jax.experimental.shard_map import shard_map
 
 
 def ring_mix(

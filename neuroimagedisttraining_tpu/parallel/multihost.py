@@ -72,9 +72,8 @@ def initialize_distributed(
     environment; pass them explicitly for CPU/GPU clusters. Returns True if
     a multi-process runtime is active after the call.
 
-    ``timeout_s`` bounds the coordinator handshake (older jax without the
-    ``initialization_timeout`` parameter falls back to its default), and
-    transient init failures retry under ``max_retries`` bounded retries
+    ``timeout_s`` bounds the coordinator handshake, and transient init
+    failures retry under ``max_retries`` bounded retries
     with linear backoff — a slow coordinator degrades to a few logged
     retries instead of hanging the whole SLURM allocation.
 
@@ -93,20 +92,13 @@ def initialize_distributed(
         if explicit:
             kw = dict(coordinator_address=coordinator_address,
                       num_processes=num_processes, process_id=process_id)
+        if timeout_s:
+            # ceil, floor 1: int() truncation would turn a sub-second
+            # --multihost_timeout_s into an instant zero-second
+            # handshake timeout
+            kw["initialization_timeout"] = max(
+                1, int(-(-float(timeout_s) // 1)))
         try:
-            if timeout_s:
-                try:
-                    # ceil, floor 1: int() truncation would turn a
-                    # sub-second --multihost_timeout_s into an instant
-                    # zero-second handshake timeout
-                    jax.distributed.initialize(
-                        initialization_timeout=max(
-                            1, int(-(-float(timeout_s) // 1))), **kw)
-                    return
-                except TypeError:
-                    logger.warning(
-                        "this jax has no initialization_timeout parameter;"
-                        " using its default handshake timeout")
             jax.distributed.initialize(**kw)
         except (RuntimeError, OSError, TimeoutError) as e:
             msg = str(e)

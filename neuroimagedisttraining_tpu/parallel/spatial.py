@@ -32,21 +32,8 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:  # jax >= 0.7 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax ships it in experimental
-    from jax.experimental.shard_map import shard_map
-
-import inspect as _inspect
-
-#: disable shard_map's static replication check portably: the kwarg is
-#: ``check_vma`` on current jax, ``check_rep`` on older releases
-NOCHECK_KW = (
-    {"check_rep": False}
-    if "check_rep" in _inspect.signature(shard_map).parameters
-    else {"check_vma": False})
 
 SPACE_AXIS = "space"
 
@@ -216,7 +203,7 @@ def make_sharded_conv3d(mesh: Mesh, axis_name: str = SPACE_AXIS):
         mesh=mesh,
         in_specs=(spec_x, P(), P()),
         out_specs=spec_x,
-        **NOCHECK_KW,
+        check_vma=False,
     )
 
 

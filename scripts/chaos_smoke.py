@@ -29,6 +29,8 @@ is gated bit-identical through ``obs/diff.params_diff`` (attacks and
 defenses are deterministic, or they are not debuggable).
 
 Prints ONE JSON line; exits nonzero on any assertion failure.
+A CI gate: runs on the CPU platform unless ``JAX_PLATFORMS`` is set (the
+chip check is ``chip_smoke.py``).
 """
 from __future__ import annotations
 

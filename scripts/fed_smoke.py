@@ -39,6 +39,8 @@ asserts the contracts the subsystem stands on:
     python scripts/fed_smoke.py --rounds 3 --clients 9
 
 Prints ONE JSON line; exits nonzero on any assertion failure.
+A CI gate: runs on the CPU platform unless ``JAX_PLATFORMS`` is set (the
+chip check is ``chip_smoke.py``).
 """
 from __future__ import annotations
 

@@ -43,6 +43,9 @@ central entry points donated — a regression to un-donated exits 1):
     python scripts/lint_gate.py --only jaxpr --json - | \
         python -c "import json,sys; \
             print(json.load(sys.stdin)['reports']['jaxpr'])"
+
+A CI gate: runs on the CPU platform (8 virtual devices) unless
+``JAX_PLATFORMS`` is set.
 """
 from __future__ import annotations
 

@@ -48,6 +48,8 @@ asserts the obs acceptance contract:
     python scripts/obs_smoke.py --model 3dcnn       # dry-run-sized rounds
 
 Prints ONE JSON line; exits nonzero on any failure.
+A CI gate: runs on the CPU platform unless ``JAX_PLATFORMS`` is set (the
+chip check is ``chip_smoke.py``).
 """
 from __future__ import annotations
 

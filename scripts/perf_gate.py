@@ -9,8 +9,8 @@ noise band) and exits
   2  not enough history to judge (bootstrap; pipelines may soft-pass)
 
 Usage:
-    # seed the history once from the committed BENCH_r*.json AND
-    # MULTICHIP_r*.json artifacts (the comm SLO baseline)
+    # seed the history once from the committed MULTICHIP_r*.json
+    # artifacts (the comm SLO baseline)
     python scripts/perf_gate.py --backfill
 
     # gate an explicit value
@@ -71,18 +71,17 @@ def main(argv=None) -> int:
                    help="metric regresses UPWARD (e.g. ms/aggregation; "
                         "auto for the comm SLO / agg_ms_* metrics)")
     p.add_argument("--backfill", action="store_true",
-                   help="seed the history from BENCH_r*.json + "
-                        "MULTICHIP_r*.json and exit")
+                   help="seed the history from MULTICHIP_r*.json and "
+                        "exit")
     p.add_argument("--append", action="store_true",
                    help="append the gated value to the history when the "
                         "verdict is pass/no-history")
     args = p.parse_args(argv)
 
     if args.backfill:
-        n = regress.backfill_bench_files(REPO_ROOT, args.history)
         nm = regress.backfill_multichip_files(REPO_ROOT, args.history)
         total = len(regress.read_history(args.history))
-        print(json.dumps({"backfilled": n, "backfilled_multichip": nm,
+        print(json.dumps({"backfilled_multichip": nm,
                           "history_points": total,
                           "history": args.history}))
         return regress.EXIT_OK
@@ -103,13 +102,12 @@ def main(argv=None) -> int:
     metric = args.metric or (result or {}).get("metric") or DEFAULT_METRIC
 
     # fresh clone bootstrap: results/ is gitignored, so the DEFAULT
-    # history auto-seeds from the committed BENCH_r*.json artifacts the
-    # first time the gate runs (idempotent; explicit --history paths
-    # are left alone)
+    # history auto-seeds from the committed MULTICHIP_r*.json artifacts
+    # the first time the gate runs (idempotent; explicit --history
+    # paths are left alone)
     if not os.path.exists(args.history) and \
             os.path.abspath(args.history) == \
             os.path.abspath(DEFAULT_HISTORY):
-        regress.backfill_bench_files(REPO_ROOT, args.history)
         regress.backfill_multichip_files(REPO_ROOT, args.history)
 
     # per-metric gate defaults (obs/regress.py): the comm SLO metrics

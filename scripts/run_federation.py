@@ -24,6 +24,12 @@ the aggregator's; site processes are terminated if they outlive the
 aggregator by ``--site_grace`` seconds (a deliberately-straggling site
 may still be asleep in its handler when the federation finishes).
 
+Platform: the processes run on the CPU unless ``JAX_PLATFORMS`` says
+otherwise. A TPU chip serves ONE process, and the launcher assigns no
+chips: under a TPU platform it refuses a world larger than the host's
+chip count, and each process must be pinned to its own chip by the caller
+(``TPU_VISIBLE_CHIPS``).
+
 Prints one JSON line describing the launch (ports, pids, exit codes).
 """
 from __future__ import annotations
@@ -52,6 +58,19 @@ def free_ports(n: int, host: str = "127.0.0.1"):
     for s in socks:
         s.close()
     return ports
+
+
+def local_tpu_chips() -> int:
+    """TPU chips this host exposes, counted WITHOUT touching JAX (a
+    launcher that initialized the backend would hold the chips its
+    children need)."""
+    import glob
+
+    visible = os.environ.get("TPU_VISIBLE_CHIPS", "")
+    if visible:
+        return len([c for c in visible.split(",") if c.strip()])
+    return len(glob.glob("/dev/accel[0-9]*")) or \
+        len(glob.glob("/dev/vfio/[0-9]*"))
 
 
 def main(argv=None) -> int:
@@ -103,6 +122,12 @@ def main(argv=None) -> int:
         common += ["--fed_out", args.out]
     env = dict(os.environ)
     env.setdefault("JAX_PLATFORMS", "cpu")
+    if "tpu" in env["JAX_PLATFORMS"].lower():
+        chips = local_tpu_chips()
+        if world > chips:
+            p.error(f"JAX_PLATFORMS={env['JAX_PLATFORMS']}: {world} "
+                    f"processes (1 aggregator + {args.sites} sites) need "
+                    f"{world} TPU chips, one each; this host has {chips}")
 
     procs = {}
     try:

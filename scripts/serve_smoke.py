@@ -43,6 +43,8 @@ subsystem stands on:
     python scripts/serve_smoke.py --requests 128 --rounds 3
 
 Prints ONE JSON line; exits nonzero on any assertion failure.
+A CI gate: runs on the CPU platform unless ``JAX_PLATFORMS`` is set (the
+chip check is ``chip_smoke.py``).
 """
 from __future__ import annotations
 

@@ -37,6 +37,8 @@ contract of the online SLO engine (obs/slo.py + obs/events.py):
 
 Prints ONE JSON line; exits 0 when the whole contract holds, 1 on any
 violation.
+A CI gate: runs on the CPU platform unless ``JAX_PLATFORMS`` is set (the
+chip check is ``chip_smoke.py``).
 """
 from __future__ import annotations
 

@@ -45,12 +45,8 @@ def branch_collective():
     (processes disagree on whether to enter the psum)."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-
-    try:  # jax >= 0.7 exports shard_map at top level
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
 
     devs = jax.devices()
     mesh = Mesh(np.array(devs), ("clients",))
@@ -62,13 +58,8 @@ def branch_collective():
             lambda v: v * 2.0,
             x)
 
-    import inspect
-
-    kw = {"check_rep": False} \
-        if "check_rep" in inspect.signature(shard_map).parameters \
-        else {"check_vma": False}
     fn = shard_map(inner, mesh=mesh, in_specs=P("clients"),
-                   out_specs=P("clients"), **kw)
+                   out_specs=P("clients"), check_vma=False)
     return fn, (np.ones((len(devs), 3), np.float32),)
 
 

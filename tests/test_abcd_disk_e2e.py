@@ -52,8 +52,8 @@ def test_abcd_disk_salientgrads_checkpoint_resume_stat_info(tmp_path):
         "--frac", "1.0", "--epochs", "1", "--batch_size", "2",
         "--lr", "1e-3", "--frequency_of_the_test", "1",
         "--final_finetune", "0",
-        # single-device path, like the attached real chip: sharding THIS
-        # full-size program over the suite's virtual CPU mesh aborts
+        # single-device path: sharding THIS full-size program over the
+        # suite's virtual CPU mesh aborts
         # inside XLA:CPU (observed "Fatal Python error: Aborted" at the
         # result fetch); the multi-device disk path is covered by the
         # 2-process test below with the small model

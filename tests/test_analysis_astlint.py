@@ -107,16 +107,6 @@ def test_host_sync_not_module_wide_outside_jit_path(tmp_path):
     assert fs == []
 
 
-def test_experimental_debug_harness_allowlisted(tmp_path):
-    fs = _lint_src(tmp_path, """
-        import jax.numpy as jnp
-
-        def selftest(x):
-            print(float(jnp.max(jnp.abs(x))))
-        """, rel="ops/experimental/mod.py")
-    assert fs == []
-
-
 # -- np-on-jax --------------------------------------------------------------
 
 def test_np_math_on_jax_value_flagged(tmp_path):

@@ -208,9 +208,23 @@ def test_sync_retry_wrapper_retries_transient_then_succeeds():
     assert len(calls) == 2  # initial try + 1 retry, then gave up
 
 
-def test_initialize_distributed_single_process_still_degrades():
+def test_initialize_distributed_single_process_still_degrades(monkeypatch):
     """The hardened wrapper keeps the auto-detect degradation contract:
-    no cluster environment -> False, no retry storm, no raise."""
+    no cluster environment -> False, no retry storm, no raise. jax checks
+    "backend already initialized" BEFORE it looks for a cluster, and by
+    this point of the suite the backend is up, so the no-cluster outcome
+    (the ValueError jax raises when auto-detection finds nothing) is
+    stubbed in: the contract under test is the wrapper's classification."""
+    import jax
+
     from neuroimagedisttraining_tpu.parallel import initialize_distributed
 
-    assert initialize_distributed(timeout_s=5, max_retries=2) is False
+    calls = []
+
+    def no_cluster(**kw):
+        calls.append(kw)
+        raise ValueError("coordinator_address should be defined.")
+
+    monkeypatch.setattr(jax.distributed, "initialize", no_cluster)
+    assert initialize_distributed(timeout_s=4.2, max_retries=2) is False
+    assert calls == [{"initialization_timeout": 5}]  # ceil, one attempt

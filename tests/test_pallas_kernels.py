@@ -179,12 +179,16 @@ def test_select_threshold_routing_and_validation():
     assert outs[0].tobytes() == outs[1].tobytes() == outs[2].tobytes()
     with pytest.raises(ValueError, match="agg_kernels"):
         ts.check_kernels("cuda")
-    # VMEM-oversized rows fall back to the XLA search (same bits)
+    # a row VMEM cannot hold is refused with the reason — a pallas
+    # request never runs the XLA search in silence
     from neuroimagedisttraining_tpu.ops.pallas_kernels import (
-        threshold_supported,
+        THRESHOLD_MAX_N,
     )
 
-    assert not threshold_supported(1 << 21)
+    big = jax.ShapeDtypeStruct((1, THRESHOLD_MAX_N + 1), jnp.float32)
+    with pytest.raises(ValueError, match="THRESHOLD_MAX_N"):
+        jax.eval_shape(
+            lambda a: ts.select_threshold(a, 5, kernels="pallas"), big)
 
 
 def test_topk_sparsify_backends_select_identical_sets():
@@ -267,17 +271,12 @@ def test_fused_quantize_reduce_bitwise_vs_xla_chain():
         got, ref.reshape(-1)[:mat.shape[1]], rtol=1e-5, atol=1e-7)
 
 
-def test_quantize_reduce_unsupported_bucket_falls_back():
-    """Buckets that don't tile the kernel's 1024-element panel keep the
-    XLA chain (same results as kernels='xla' trivially)."""
-    from neuroimagedisttraining_tpu.ops.pallas_kernels import (
-        quantize_reduce_supported,
-    )
+def test_quantize_reduce_any_bucket_runs_the_kernel():
+    """Buckets that don't tile the kernel's 1024-element panel are
+    zero-padded inside the kernel wrapper — still the pallas kernel,
+    still bit-identical to the XLA chain."""
     from neuroimagedisttraining_tpu.parallel import collectives as C
 
-    assert quantize_reduce_supported(1024)
-    assert quantize_reduce_supported(1 << 18)
-    assert not quantize_reduce_supported(16)
     tree = {"x": jax.random.normal(jax.random.PRNGKey(0), (3, 40))}
     w = jnp.asarray([0.5, 0.25, 0.25], jnp.float32)
     rng = jax.random.PRNGKey(1)
@@ -425,33 +424,167 @@ def test_runner_agg_kernels_twin_identical(tmp_path):
         assert pd["identical"], (impl, pd["diverged"][:3])
 
 
+# ---------------------------------------------------------------------------
+# real-TPU tier: every kernel compiled through Mosaic (non-interpret) at the
+# flagship's shapes, each against its jax.numpy spelling. Run on a TPU host:
+#     JAX_PLATFORMS=tpu python -m pytest -m tpu tests/test_pallas_kernels.py
+# (tests/conftest.py deselects the tier on the CPU platform). The CPU tier
+# above pins bit-identity in interpret mode; across two compilers (Mosaic
+# and XLA:TPU) the float contracts are tolerances, the integer fixed point
+# of the threshold search stays bitwise.
+# ---------------------------------------------------------------------------
+
+ABCD_VOLUME = (121, 145, 121)
+
+
+def _alexnet_tree(key, lead=()):
+    """Random f32 arrays in the shape of the full-volume AlexNet3D-s2d
+    parameter tree (2.57 M parameters), optionally client-stacked."""
+    from neuroimagedisttraining_tpu.models import create_model, init_params
+    from neuroimagedisttraining_tpu.ops.s2d import phased_sample_shape
+
+    model = create_model("3dcnn_s2d", num_classes=1)
+    shapes = jax.eval_shape(
+        lambda k: init_params(model, k, phased_sample_shape(ABCD_VOLUME)),
+        jax.random.PRNGKey(0))
+    leaves, treedef = jax.tree_util.tree_flatten(shapes)
+    return jax.tree_util.tree_unflatten(treedef, [
+        jax.random.normal(jax.random.fold_in(key, i),
+                          tuple(lead) + l.shape, jnp.float32) * 0.01
+        for i, l in enumerate(leaves)])
+
+
+def _flat(tree):
+    return np.concatenate([np.asarray(x).ravel()
+                           for x in jax.tree_util.tree_leaves(tree)])
+
+
+def _assert_trees_close(got, want, rtol, atol):
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=rtol, atol=atol)
+
+
 @pytest.mark.tpu
-def test_kernel_leg_compiles_non_interpret():
-    """Real-TPU tier (pytest -m tpu on a TPU host): the three kernel
-    families compile NON-interpret and keep the bit contracts the CPU
-    interpret tier pins."""
-    if jax.default_backend() != "tpu":  # pragma: no cover - TPU only
-        pytest.skip("requires a real TPU backend")
-    from neuroimagedisttraining_tpu.ops.pallas_kernels import (
-        fused_mask_apply,
-        threshold_topk,
-    )
+def test_tpu_fused_masked_sgd_step_alexnet_tree():
+    """--fused_kernels 1: the optimizer kernel on the whole tree, and
+    under the client vmap the round applies it in."""
+    from neuroimagedisttraining_tpu.core.optim import sgd_momentum_step
+
+    key = jax.random.PRNGKey(0)
+    p, m, g = (_alexnet_tree(jax.random.fold_in(key, i), lead=(2,))
+               for i in range(3))
+    mask = jax.tree_util.tree_map(
+        lambda x: (x > 0).astype(jnp.float32), _alexnet_tree(key, (2,)))
+    lr = jnp.float32(0.05)
+
+    def ref(p, m, g, k):
+        p2, m2 = sgd_momentum_step(p, m, g, lr, 0.9, 5e-4)
+        return jax.tree_util.tree_map(lambda a, b: a * b, p2, k), m2
+
+    def fused(p, m, g, k):
+        return fused_masked_sgd_step(p, m, g, k, lr, momentum=0.9,
+                                     wd=5e-4)
+
+    got = jax.jit(jax.vmap(fused))(p, m, g, mask)
+    want = jax.jit(jax.vmap(ref))(p, m, g, mask)
+    _assert_trees_close(got, want, rtol=1e-6, atol=1e-8)
+
+
+@pytest.mark.tpu
+@pytest.mark.parametrize("c", [8, 32])
+def test_tpu_weighted_sums_are_f32_exact(c):
+    """The pallas weighted sum AND the jnp spelling the round ships
+    (core.state.weighted_tree_sum, a tensordot) against an f64 host
+    reference: on the chip an f32 contraction must not be rounded
+    through bf16. Terms are ~1e-2 * w; atol 2e-8 admits the f32 rounding
+    of a cancelling c-term sum and sits 50x under one bf16 rounding of a
+    term (1e-2 * 2^-9 * w ~ 1e-6 at c=8)."""
+    from neuroimagedisttraining_tpu.core.state import weighted_tree_sum
+
+    tree = _alexnet_tree(jax.random.PRNGKey(1), lead=(c,))
+    w = np.random.RandomState(c).rand(c).astype(np.float32)
+    w = jnp.asarray(w / w.sum())
+    w64 = np.asarray(w, np.float64)
+    want = jax.tree_util.tree_map(
+        lambda x: np.tensordot(w64, np.asarray(x, np.float64),
+                               axes=1).astype(np.float32), tree)
+    _assert_trees_close(jax.jit(fused_weighted_sum)(tree, w), want,
+                        rtol=2e-6, atol=2e-8)
+    _assert_trees_close(jax.jit(weighted_tree_sum)(tree, w), want,
+                        rtol=2e-6, atol=2e-8)
+
+
+@pytest.mark.tpu
+@pytest.mark.parametrize("c,n,k", [
+    (1, 2_570_000, 1_285_000),   # the SNIP score row: whole model, half
+    (8, 1 << 20, 104_857),       # topk wire group, 8 / 32 clients
+    (32, 1 << 20, 104_857),
+    (4, 4096, 50),
+])
+def test_tpu_threshold_topk_bitwise(c, n, k):
+    from neuroimagedisttraining_tpu.ops.pallas_kernels import threshold_topk
     from neuroimagedisttraining_tpu.ops.topk_select import exact_threshold
+
+    av = jnp.abs(jax.random.normal(jax.random.PRNGKey(n % 97), (c, n)))
+    got = np.asarray(threshold_topk(av, k))
+    assert got.tobytes() == np.asarray(exact_threshold(av, k)).tobytes()
+    assert (np.sum(np.asarray(av) >= got, axis=1) >= k).all()
+
+
+@pytest.mark.tpu
+@pytest.mark.parametrize("c", [8, 32])
+@pytest.mark.parametrize("bucket", [1024, 1 << 18])
+def test_tpu_fused_quantize_reduce_alexnet_tree(c, bucket):
+    """int8 wire, pallas vs the XLA chain on the same draw. The two
+    compilers may round x/scale differently in the last bit, which flips
+    a stochastic rounding only where the draw lands within an ulp of the
+    fraction: nearly every element agrees to f32 tolerance, and no
+    element is off by more than one quantum of its bucket."""
     from neuroimagedisttraining_tpu.parallel import collectives as C
 
-    av = jnp.abs(jax.random.normal(jax.random.PRNGKey(0), (4, 4096)))
-    assert np.asarray(threshold_topk(av, 50)).tobytes() == \
-        np.asarray(exact_threshold(av, 50)).tobytes()
-    tree = {"x": jax.random.normal(jax.random.PRNGKey(1), (4, 4096))}
-    w = jnp.asarray([0.25] * 4, jnp.float32)
-    rng = jax.random.PRNGKey(2)
-    a = C.weighted_mean(tree, w, wire="int8", rng=rng, bucket_size=1024,
-                        kernels="pallas")
-    b = C.weighted_mean(tree, w, wire="int8", rng=rng, bucket_size=1024,
-                        kernels="xla")
-    np.testing.assert_allclose(np.asarray(a["x"]), np.asarray(b["x"]),
-                               rtol=1e-5, atol=1e-7)
-    m = {"x": jnp.ones((4, 4096), jnp.float32)}
-    got = fused_mask_apply(tree, m)
-    assert np.asarray(got["x"]).tobytes() == \
-        np.asarray(tree["x"]).tobytes()
+    tree = _alexnet_tree(jax.random.PRNGKey(2), lead=(c,))
+    w = jnp.full((c,), 1.0 / c, jnp.float32)
+    rng = jax.random.PRNGKey(3)
+    run = {kb: jax.jit(lambda st, wv, _kb=kb: C.weighted_mean(
+        st, wv, wire="int8", rng=rng, bucket_size=bucket,
+        kernels=_kb))(tree, w) for kb in ("xla", "pallas")}
+    a, b = _flat(run["xla"]), _flat(run["pallas"])
+    amax = float(np.max(np.abs(_flat(tree))))
+    quantum = amax / 127.0 / c          # one int8 step of one client
+    diff = np.abs(a - b)
+    assert diff.max() <= quantum * 1.01, (diff.max(), quantum)
+    assert np.mean(diff > 1e-6 * amax) < 1e-4, np.mean(diff > 1e-6 * amax)
+
+
+@pytest.mark.tpu
+def test_tpu_mask_kernels_alexnet_tree():
+    """fused_mask_apply (bitwise: one f32 multiply), fused_score_mask_leaf
+    and the whole pallas SNIP mask build at the flagship's 2.57 M scores:
+    masks agree with the XLA spelling except where a score sits within an
+    ulp of the threshold, and keep exactly the requested density."""
+    from neuroimagedisttraining_tpu.ops.pallas_kernels import (
+        fused_mask_apply,
+    )
+    from neuroimagedisttraining_tpu.ops.sparsity import (
+        mask_density,
+        mask_from_scores,
+    )
+
+    tree = _alexnet_tree(jax.random.PRNGKey(4))
+    mask = jax.tree_util.tree_map(
+        lambda x: (x > 0).astype(jnp.float32), tree)
+    got = jax.jit(fused_mask_apply)(tree, mask)
+    want = jax.tree_util.tree_map(lambda p, m: p * m, tree, mask)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+    scores = jax.tree_util.tree_map(jnp.abs, tree)
+    masks = {kb: jax.jit(lambda s, _kb=kb: mask_from_scores(
+        s, 0.5, kernels=_kb))(scores) for kb in ("xla", "pallas")}
+    a, b = _flat(masks["xla"]), _flat(masks["pallas"])
+    assert np.mean(a != b) < 1e-5, np.mean(a != b)
+    assert abs(float(mask_density(masks["pallas"])) - 0.5) < 1e-3

@@ -11,11 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import lax
-try:  # jax >= 0.7 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax ships it in experimental
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from neuroimagedisttraining_tpu.parallel.mesh import make_mesh
@@ -36,7 +32,7 @@ def test_halo_exchange_matches_zero_padding(eight_devices):
         mesh=mesh,
         in_specs=P(None, sp.SPACE_AXIS),
         out_specs=P(None, sp.SPACE_AXIS),
-        **sp.NOCHECK_KW,
+        check_vma=False,
     )
     out = jax.jit(f)(x)
     # each local block (depth 4) grows to 8; global result is the blocks'
