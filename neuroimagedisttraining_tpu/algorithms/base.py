@@ -945,9 +945,10 @@ class FedAlgorithm(abc.ABC):
             # line of the clients32 cell). Statically skip them.
             n_sel, x_sel, y_sel = n_train, x_train, y_train
         else:
-            n_sel = jnp.take(n_train, sel_idx)
-            x_sel = jnp.take(x_train, sel_idx, axis=0)
-            y_sel = jnp.take(y_train, sel_idx, axis=0)
+            with jax.named_scope("cohort_gather"):
+                n_sel = jnp.take(n_train, sel_idx)
+                x_sel = jnp.take(x_train, sel_idx, axis=0)
+                y_sel = jnp.take(y_train, sel_idx, axis=0)
         if self.labelflip_fn is not None:
             # label-flip poisons the DATA PATH (before training — the
             # other fault kinds corrupt what leaves the client, this one
@@ -962,8 +963,18 @@ class FedAlgorithm(abc.ABC):
         mom0 = zeros_like_tree(params0)
         keys = jax.random.split(round_key, s + 1)
         # named_scope: trace-time HLO metadata only (zero runtime cost,
-        # numerics untouched) — labels the round's phases on the XLA
-        # device trace so they line up with the obs host spans
+        # numerics untouched). The round's scopes, all string literals at
+        # their call sites: cohort_gather, local_train, guard, aggregate,
+        # robust_aggregate, personal_update, numerics, eval_cache (this
+        # file); batch_gather, optimizer (core/trainer.py, inside
+        # local_train); stem with conv, norm, pool inside it
+        # (models/alexnet3d.py:phased_stem_stage, inside the model's
+        # module). The forward/backward pass needs none: JAX prints it as
+        # the jvp()/transpose(jvp()) wrapper of the op_name.
+        # benchmarks/metrics/*.json read these scopes BY NAME from the
+        # device trace: a rename is an edit to both (and to PERF.md
+        # section 3), and shows only after the persistent compile cache
+        # is emptied (its key leaves HLO metadata out).
         with jax.named_scope("local_train"):
             params_out, _, losses = self._vmap_clients(
                 client_update, in_axes=(0, 0, 0, 0, 0, 0, 0, None, 0)
@@ -1165,13 +1176,14 @@ class FedAlgorithm(abc.ABC):
             return None
         from ..core.state import tree_scatter_update
 
-        upd = locals_
-        if fstats is not None:
-            from ..robust import guard as _guard
+        with jax.named_scope("personal_update"):
+            upd = locals_
+            if fstats is not None:
+                from ..robust import guard as _guard
 
-            upd = _guard.merge_updates(
-                fstats["ok"], locals_, personal, sel_idx)
-        return tree_scatter_update(personal, sel_idx, upd)
+                upd = _guard.merge_updates(
+                    fstats["ok"], locals_, personal, sel_idx)
+            return tree_scatter_update(personal, sel_idx, upd)
 
     def _numerics_outputs(self, old_global, new_global, locals_,
                           mask=None):

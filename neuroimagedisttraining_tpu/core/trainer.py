@@ -121,6 +121,7 @@ def make_client_update(
             batch_loss, policy=jax.checkpoint_policies.nothing_saveable)
     grad_fn = jax.value_and_grad(batch_loss)
 
+    @jax.named_scope("optimizer")
     def apply_update(params, momentum, grads, mask, prox_target, lr):
         """One optimizer step: clip + (masked) SGD + prox pull + re-mask."""
         grads = clip_by_global_norm(grads, hp.grad_clip)
@@ -169,8 +170,9 @@ def make_client_update(
                 # spe*bs > n_rows; clamp (their loss terms are masked by wb
                 # anyway, but jnp.take's default OOB fill is NaN)
                 idx = jnp.minimum(idx, x.shape[0] - 1)
-                xb = jnp.take(x, idx, axis=0)
-                yb = jnp.take(y, idx, axis=0)
+                with jax.named_scope("batch_gather"):
+                    xb = jnp.take(x, idx, axis=0)
+                    yb = jnp.take(y, idx, axis=0)
                 if augment_fn is not None:
                     k_aug, k_drop = jax.random.split(k_drop)
                     xb = augment_fn(k_aug, xb)
@@ -207,8 +209,9 @@ def make_client_update(
             k_idx, k_drop = jax.random.split(key)
             idx = jax.random.randint(k_idx, (hp.batch_size,), 0,
                                      jnp.maximum(n_valid, 1))
-            xb = jnp.take(x, idx, axis=0)
-            yb = jnp.take(y, idx, axis=0)
+            with jax.named_scope("batch_gather"):
+                xb = jnp.take(x, idx, axis=0)
+                yb = jnp.take(y, idx, axis=0)
             if augment_fn is not None:
                 k_aug, k_drop = jax.random.split(k_drop)
                 xb = augment_fn(k_aug, xb)

@@ -18,6 +18,7 @@ identical to the reference's Linear input sizes.
 from __future__ import annotations
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -124,39 +125,51 @@ def phased_stem_stage(mdl: nn.Module, x, *, stem_kernel: int, features: int,
     dn_args = ("NDHCW", "DHWIO", "NDHWC")
     pk, ps, pp = pool
 
+    # scopes stem/{conv,norm,pool}: the device trace's names for this
+    # stage's three layers in both models (benchmarks/metrics/stem_*.json
+    # read them; the list of the round's scopes is in algorithms/base.py)
     if not pool_first:
-        dn = lax.conv_dimension_numbers(x.shape, w.shape, dn_args)
-        z = lax.conv_general_dilated(
-            x, w * mask, (1, 1, 1), "VALID", dimension_numbers=dn)
-        if b is not None:
-            z = z + b
-        mdl.sow("intermediates", "conv_out", z)
-        # normalize explicitly with this module's own affine params
-        zf = z.astype(jnp.float32)
-        mu_c, sig_c = _group_stats(zf, g, eps)
-        y = (zf - mu_c) / sig_c * gamma + beta
-        y = nn.relu(y).astype(z.dtype)
-        return max_pool3d(y, kernel=pk, strides=ps, padding=pp)
+        with jax.named_scope("stem"):
+            with jax.named_scope("conv"):
+                dn = lax.conv_dimension_numbers(x.shape, w.shape, dn_args)
+                z = lax.conv_general_dilated(
+                    x, w * mask, (1, 1, 1), "VALID", dimension_numbers=dn)
+                if b is not None:
+                    z = z + b
+            mdl.sow("intermediates", "conv_out", z)
+            with jax.named_scope("norm"):
+                # normalize explicitly with this module's own affine params
+                zf = z.astype(jnp.float32)
+                mu_c, sig_c = _group_stats(zf, g, eps)
+                y = (zf - mu_c) / sig_c * gamma + beta
+                y = nn.relu(y).astype(z.dtype)
+            with jax.named_scope("pool"):
+                return max_pool3d(y, kernel=pk, strides=ps, padding=pp)
 
-    sign = jnp.where(gamma >= 0, 1.0, -1.0).astype(w.dtype)
-    ws = (w * mask) * sign
-    dn = lax.conv_dimension_numbers(x.shape, ws.shape, dn_args)
-    zs = lax.conv_general_dilated(
-        x, ws, (1, 1, 1), "VALID", dimension_numbers=dn)
-    if b is not None:
-        zs = zs + (b * sign.astype(b.dtype))
-    mdl.sow("intermediates", "conv_out", zs)
-    # group stats of z = zs * sign, in f32
-    sf = sign.astype(jnp.float32)
-    zf = zs.astype(jnp.float32) * sf
-    mu_c, sig_c = _group_stats(zf, g, eps)
-    # ONE pool on zs = max over window of z for scale>=0 channels,
-    # -min for scale<0 channels (flax pads max-pool with -inf, so a
-    # padded pool ring never wins the selection)
-    m = max_pool3d(zs, kernel=pk, strides=ps, padding=pp)
-    sel = m.astype(jnp.float32) * sf
-    y = (sel - mu_c) / sig_c * gamma + beta
-    return nn.relu(y).astype(zs.dtype)
+    with jax.named_scope("stem"):
+        sign = jnp.where(gamma >= 0, 1.0, -1.0).astype(w.dtype)
+        with jax.named_scope("conv"):
+            ws = (w * mask) * sign
+            dn = lax.conv_dimension_numbers(x.shape, ws.shape, dn_args)
+            zs = lax.conv_general_dilated(
+                x, ws, (1, 1, 1), "VALID", dimension_numbers=dn)
+            if b is not None:
+                zs = zs + (b * sign.astype(b.dtype))
+        mdl.sow("intermediates", "conv_out", zs)
+        with jax.named_scope("norm"):
+            # group stats of z = zs * sign, in f32
+            sf = sign.astype(jnp.float32)
+            zf = zs.astype(jnp.float32) * sf
+            mu_c, sig_c = _group_stats(zf, g, eps)
+        # ONE pool on zs = max over window of z for scale>=0 channels,
+        # -min for scale<0 channels (flax pads max-pool with -inf, so a
+        # padded pool ring never wins the selection)
+        with jax.named_scope("pool"):
+            m = max_pool3d(zs, kernel=pk, strides=ps, padding=pp)
+        with jax.named_scope("norm"):
+            sel = m.astype(jnp.float32) * sf
+            y = (sel - mu_c) / sig_c * gamma + beta
+            return nn.relu(y).astype(zs.dtype)
 
 
 class S2DStemStage(nn.Module):

@@ -1,0 +1,148 @@
+"""The round program's ``jax.named_scope`` labels, as the benchmark reads them.
+
+``benchmarks/metrics/*.json`` find the round's phases, the stem's layers and
+the pass BY NAME in the ``op_name`` metadata of the compiled round
+(``benchmarks/lib/scopes.py``; the list of scopes is the comment above
+``named_scope("local_train")`` in ``algorithms/base.py``). A scope that is
+renamed, dropped or no longer reaches the compiled program empties a metric
+in silence on the chip; here it fails on the CPU, in seconds, at 8^3.
+
+The persistent compile cache's key leaves HLO metadata out
+(``jax_compilation_cache_include_metadata_in_key``, default off), so an
+executable cached before a scope was edited comes back with the OLD names.
+These tests read names out of compiled programs, so they put the metadata
+into the key while they run; a traced benchmark run after such an edit needs
+an emptied cache directory instead.
+"""
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmarks.lib import scopes
+from neuroimagedisttraining_tpu.algorithms import FedAvg, SalientGrads
+from neuroimagedisttraining_tpu.core.state import HyperParams
+from neuroimagedisttraining_tpu.core.trainer import make_client_update
+from neuroimagedisttraining_tpu.data import make_synthetic_federated
+from neuroimagedisttraining_tpu.models import (
+    create_model,
+    init_params,
+    make_apply_fn,
+)
+from neuroimagedisttraining_tpu.ops.s2d import phased_sample_shape
+
+ALGOS = {"salientgrads": SalientGrads, "fedavg": FedAvg}
+ROUND_SCOPES = ("batch_gather", "optimizer", "personal_update",
+                "local_train", "aggregate")
+
+
+@contextlib.contextmanager
+def metadata_in_cache_key():
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        yield
+    finally:
+        jax.config.update(flag, before)
+
+
+@pytest.fixture(autouse=True)
+def fresh_names():
+    with metadata_in_cache_key():
+        yield
+
+
+def op_names(text):
+    """Every ``op_name`` of a compiled module's text or of a lowered
+    module's text with debug info (there a ``loc("...")``)."""
+    return set(re.findall(r'op_name="([^"]*)"', text)) or set(
+        re.findall(r'loc\("([^"]*)"', text))
+
+
+def count(names, scope=None, direction=None):
+    return sum((scope is None or scopes.under(n, scope))
+               and (direction is None or scopes.direction(n) == direction)
+               for n in names)
+
+
+def compiled_round_names(algo_name, frac, client_chunk=None, **algo_kw):
+    """The ``op_name``s of the compiled tiny round (4 sites, 8^3)."""
+    data = make_synthetic_federated(
+        n_clients=4, samples_per_client=8, test_per_client=4,
+        sample_shape=(8, 8, 8, 1))
+    hp = HyperParams(lr=0.05, local_epochs=1, steps_per_epoch=2,
+                     batch_size=4)
+    algo = ALGOS[algo_name](
+        create_model("small3dcnn", num_classes=1), data, hp,
+        loss_type="bce", frac=frac, seed=3, client_chunk=client_chunk,
+        **algo_kw)
+    state = algo.init_state(jax.random.PRNGKey(3))
+    extra = (data.x_test, data.y_test, data.n_test) \
+        if getattr(algo, "eval_cache", False) else ()
+    sel = jnp.arange(algo.clients_per_round, dtype=jnp.int32)
+    compiled = algo._round_jit.lower(
+        state, sel, jnp.asarray(0, jnp.float32), data.x_train, data.y_train,
+        data.n_train, *extra).compile()
+    return op_names(compiled.as_text())
+
+
+@pytest.mark.parametrize("client_chunk", [None, 1],
+                         ids=["vmapped", "client_chunk1"])
+@pytest.mark.parametrize("algo_name", sorted(ALGOS))
+def test_compiled_round_holds_every_round_scope(algo_name, client_chunk):
+    names = compiled_round_names(algo_name, 0.5, client_chunk)
+    for scope in ROUND_SCOPES + ("cohort_gather",):
+        assert count(names, scope) > 0, (scope, "no instruction under it")
+    assert count(names, direction="fwd") > 0
+    assert count(names, direction="bwd") > 0
+    # the step's scopes sit inside local_train, and a gather is not a pass
+    assert count(names, "batch_gather", "fwd") == 0
+    assert count(names, "batch_gather", "bwd") == 0
+
+
+@pytest.mark.parametrize("algo_name", sorted(ALGOS))
+def test_full_participation_has_no_cohort_gather(algo_name):
+    names = compiled_round_names(algo_name, 1.0, None)
+    assert count(names, "cohort_gather") == 0
+    for scope in ROUND_SCOPES:
+        assert count(names, scope) > 0, scope
+
+
+# (dense volume, stem kernel, stem pad): about the smallest volumes the two
+# models' pools admit (AlexNet3D: 69^3; ResNet_l3: as tests/test_s2d.py)
+STEM_VOLUMES = {"3dcnn_s2d": ((69, 69, 69), 5, 0),
+                "3dresnet_s2d": ((29, 33, 29), 3, 3)}
+
+
+def lowered_step_names(model_name, pool_first=True):
+    """The ``op_name``s of a full model's lowered training step. Lowering
+    with debug info is enough (no full-size compile): the names are set at
+    trace time."""
+    model = create_model(model_name, num_classes=1, pool_first=pool_first)
+    shape = (2,) + phased_sample_shape(*STEM_VOLUMES[model_name])
+    params = init_params(model, jax.random.PRNGKey(0), shape[1:])
+    hp = HyperParams(lr=0.05, local_epochs=1, steps_per_epoch=1,
+                     batch_size=2)
+    update = make_client_update(make_apply_fn(model), "bce", hp)
+    lowered = jax.jit(update).lower(
+        params, params, params, jax.random.PRNGKey(1),
+        jnp.zeros(shape, jnp.float32), jnp.zeros(shape[:1], jnp.int32),
+        jnp.asarray(2, jnp.int32), jnp.asarray(0, jnp.float32), params)
+    return op_names(lowered.as_text(debug_info=True))
+
+
+@pytest.mark.parametrize("pool_first", [True, False],
+                         ids=["pool_first", "textbook_order"])
+@pytest.mark.parametrize("model_name", sorted(STEM_VOLUMES))
+def test_lowered_step_holds_stem_scopes_in_both_passes(model_name,
+                                                       pool_first):
+    names = lowered_step_names(model_name, pool_first)
+    for layer in ("conv", "norm", "pool"):
+        for direction in ("fwd", "bwd"):
+            assert count(names, f"stem/{layer}", direction) > 0, (
+                layer, direction)
+    for scope in ("batch_gather", "optimizer"):
+        assert count(names, scope) > 0, scope
