@@ -144,9 +144,21 @@ def make_apply_fn(model, compute_dtype=None, channel_inject=False) -> ApplyFn:
 
 def init_params(model, rng: jax.Array, sample_shape: Tuple[int, ...]):
     """Initialize parameters for input volumes/images of ``sample_shape``
-    (without batch axis)."""
+    (without batch axis).
+
+    One jitted program: ``model.init`` runs the model's forward pass to learn
+    the shapes, and eagerly that is every op of it at the sample's full size
+    (125 programs and five live copies of the stem's conv output for
+    AlexNet3D on a 121x145x121 volume: ~2 s of every start and the run's
+    peak of device memory; my chip runs, PR 26). Under ``jit`` XLA drops the
+    forward and keeps the initialisers; the parameters are the same bit for
+    bit."""
     import jax.numpy as jnp
 
-    x = jnp.zeros((1,) + tuple(sample_shape), jnp.float32)
-    variables = model.init({"params": rng, "dropout": rng}, x, train=False)
-    return variables["params"]
+    def init(rng):
+        x = jnp.zeros((1,) + tuple(sample_shape), jnp.float32)
+        variables = model.init({"params": rng, "dropout": rng}, x,
+                               train=False)
+        return variables["params"]
+
+    return jax.jit(init)(rng)

@@ -153,8 +153,10 @@ def phased_stem_stage(mdl: nn.Module, x, *, stem_kernel: int, features: int,
             dn = lax.conv_dimension_numbers(x.shape, ws.shape, dn_args)
             zs = lax.conv_general_dilated(
                 x, ws, (1, 1, 1), "VALID", dimension_numbers=dn)
+            summands = None
             if b is not None:
-                zs = zs + (b * sign.astype(b.dtype))
+                conv, shift = zs, b * sign.astype(b.dtype)
+                summands, zs = (conv, shift), conv + shift
         mdl.sow("intermediates", "conv_out", zs)
         with jax.named_scope("norm"):
             # group stats of z = zs * sign, in f32
@@ -163,9 +165,12 @@ def phased_stem_stage(mdl: nn.Module, x, *, stem_kernel: int, features: int,
             mu_c, sig_c = _group_stats(zf, g, eps)
         # ONE pool on zs = max over window of z for scale>=0 channels,
         # -min for scale<0 channels (flax pads max-pool with -inf, so a
-        # padded pool ring never wins the selection)
+        # padded pool ring never wins the selection). The pool is told that
+        # zs is conv + bias: its backward then reads the conv output the
+        # conv fusion wrote, not a second copy with the bias added
         with jax.named_scope("pool"):
-            m = max_pool3d(zs, kernel=pk, strides=ps, padding=pp)
+            m = max_pool3d(zs, kernel=pk, strides=ps, padding=pp,
+                           summands=summands)
         with jax.named_scope("norm"):
             sel = m.astype(jnp.float32) * sf
             y = (sel - mu_c) / sig_c * gamma + beta

@@ -15,6 +15,8 @@ from typing import Optional, Sequence, Tuple, Union
 import flax.linen as nn
 import jax.numpy as jnp
 
+from ..ops.pool_vjp import max_pool3d_of_sum
+
 Ints3 = Union[int, Tuple[int, int, int]]
 
 
@@ -57,11 +59,23 @@ class Conv3d(nn.Module):
         )(x)
 
 
-def max_pool3d(x, kernel: Ints3, strides: Ints3, padding: Ints3 = 0):
-    """torch MaxPool3d semantics (floor mode) on (N, D, H, W, C)."""
+def max_pool3d(x, kernel: Ints3, strides: Ints3, padding: Ints3 = 0, *,
+               summands=None):
+    """torch MaxPool3d semantics (floor mode) on (N, D, H, W, C).
+
+    ``summands=(c, bias)`` says that the caller computed ``x`` as ``c + bias``
+    (``bias`` per channel). Where the windows do not overlap (``kernel ==
+    strides``, no padding) the backward then needs no ``select-and-scatter``
+    and no copy of ``x`` (ops/pool_vjp.py); the values and the gradient are
+    the same bit for bit. Any other geometry keeps ``lax.reduce_window``'s
+    own VJP."""
     k = _triple(kernel)
     s = _triple(strides)
     p = _triple(padding)
+    if summands is not None and k == s and p == (0, 0, 0):
+        c, bias = summands
+        if c.dtype == bias.dtype == x.dtype:
+            return max_pool3d_of_sum(x, c, bias, k)
     return nn.max_pool(
         x, window_shape=k, strides=s, padding=[(pi, pi) for pi in p]
     )

@@ -4,6 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from neuroimagedisttraining_tpu.ops.s2d import phased_sample_shape
+
 from neuroimagedisttraining_tpu.models import (
     create_model,
     init_params,
@@ -241,3 +243,26 @@ def test_original_resnet18_bn_forward():
                           "batch_stats": updated["batch_stats"]},
                          x, train=False)
     assert np.all(np.isfinite(np.asarray(y_eval)))
+
+
+@pytest.mark.parametrize("name,shape", [
+    ("small3dcnn", (8, 8, 8, 1)),
+    ("3dcnn_s2d", phased_sample_shape((69, 69, 69), 5, 0)),
+    ("3dresnet_s2d", phased_sample_shape((29, 33, 29), 3, 3)),
+], ids=["small3dcnn", "3dcnn_s2d", "3dresnet_s2d"])
+def test_init_params_is_jitted_and_bit_equal_to_eager_init(name, shape):
+    """``init_params`` runs ``model.init`` as one program (an eager init
+    runs the forward pass op by op at the sample's size): the parameters
+    must be the ones the eager init made, bit for bit."""
+    model = create_model(name, num_classes=1)
+    rng = jax.random.PRNGKey(3)
+    x = jnp.zeros((1,) + tuple(shape), jnp.float32)
+    eager = model.init({"params": rng, "dropout": rng}, x,
+                       train=False)["params"]
+    got = init_params(model, rng, shape)
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(eager)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(eager)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -117,20 +117,21 @@ STEM_VOLUMES = {"3dcnn_s2d": ((69, 69, 69), 5, 0),
                 "3dresnet_s2d": ((29, 33, 29), 3, 3)}
 
 
-def lowered_step_names(model_name, pool_first=True):
-    """The ``op_name``s of a full model's lowered training step. Lowering
-    with debug info is enough (no full-size compile): the names are set at
-    trace time."""
+def lowered_step_names(model_name, pool_first=True, platform="cpu"):
+    """The ``op_name``s of a full model's training step lowered for
+    ``platform``. Lowering with debug info is enough (no full-size compile):
+    the names are set at trace time."""
     model = create_model(model_name, num_classes=1, pool_first=pool_first)
     shape = (2,) + phased_sample_shape(*STEM_VOLUMES[model_name])
     params = init_params(model, jax.random.PRNGKey(0), shape[1:])
     hp = HyperParams(lr=0.05, local_epochs=1, steps_per_epoch=1,
                      batch_size=2)
     update = make_client_update(make_apply_fn(model), "bce", hp)
-    lowered = jax.jit(update).lower(
+    lowered = jax.jit(update).trace(
         params, params, params, jax.random.PRNGKey(1),
         jnp.zeros(shape, jnp.float32), jnp.zeros(shape[:1], jnp.int32),
-        jnp.asarray(2, jnp.int32), jnp.asarray(0, jnp.float32), params)
+        jnp.asarray(2, jnp.int32), jnp.asarray(0, jnp.float32), params
+    ).lower(lowering_platforms=(platform,))
     return op_names(lowered.as_text(debug_info=True))
 
 
@@ -146,3 +147,19 @@ def test_lowered_step_holds_stem_scopes_in_both_passes(model_name,
                 layer, direction)
     for scope in ("batch_gather", "optimizer"):
         assert count(names, scope) > 0, scope
+
+
+@pytest.mark.parametrize("platform,backward", [
+    ("cpu", "select_and_scatter_add"), ("tpu", "pallas_call")])
+def test_alexnet_pool_keeps_its_names_whatever_its_backward(platform,
+                                                            backward):
+    """``stem_pool_{fwd,bwd}_ms_per_round`` read ``stem/pool`` under
+    ``jvp(...)`` and ``transpose(jvp(...))``. The pool's backward is a
+    ``custom_vjp`` over one primitive with a lowering per target
+    (ops/pool_vjp.py): each must leave its ops under those names."""
+    names = lowered_step_names("3dcnn_s2d", platform=platform)
+    pool = {d: {n.rsplit("/", 1)[-1] for n in names
+                if scopes.under(n, "stem/pool") and scopes.direction(n) == d}
+            for d in ("fwd", "bwd")}
+    assert "reduce_window_max" in pool["fwd"], pool
+    assert backward in pool["bwd"], pool
