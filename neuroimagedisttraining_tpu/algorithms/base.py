@@ -49,6 +49,12 @@ def _personal_metrics(correct, loss_sum, total):
     }
 
 
+def _device_memory_limit() -> int:
+    """Bytes the first device may hold, 0 where the backend keeps no such
+    statistic (the CPU)."""
+    return (jax.devices()[0].memory_stats() or {}).get("bytes_limit", 0)
+
+
 def sample_client_indexes(
     round_idx: int, client_num_in_total: int, client_num_per_round: int,
     retry: int = 0,
@@ -320,6 +326,12 @@ class FedAlgorithm(abc.ABC):
         # injected channel axis
         self.init_sample_shape = tuple(data.sample_shape) + (
             (1,) if channel_inject else ())
+        # ... and its dtype: integer inputs (token ids) as stored, anything
+        # floating as float32 (make_apply_fn casts it to the compute type)
+        self.init_sample_dtype = (
+            data.x_train.dtype
+            if jnp.issubdtype(data.x_train.dtype, jnp.integer)
+            else jnp.dtype(jnp.float32))
         # obs_numerics: in-jit training-dynamics telemetry
         # (obs/numerics.py) — per-layer-group update/grad norms,
         # non-finite precursor gauges, per-client drift/cosine, mask
@@ -331,14 +343,10 @@ class FedAlgorithm(abc.ABC):
         # enters run/checkpoint identity.
         self._numerics_plan = None
         if obs_numerics and self.numerics_supported:
-            from ..models import init_params
             from ..obs.numerics import NumericsPlan
 
-            template = jax.eval_shape(lambda: init_params(
-                self.model, jax.random.PRNGKey(0),
-                self.init_sample_shape))
             self._numerics_plan = NumericsPlan.from_params(
-                template, slots=self.clients_per_round,
+                self.params_template(), slots=self.clients_per_round,
                 with_mask=self.numerics_with_mask)
             self._round_metric_names = tuple(self._round_metric_names) \
                 + self._numerics_plan.metric_names
@@ -478,7 +486,27 @@ class FedAlgorithm(abc.ABC):
                 self.data)
         self._fused_cache: Dict[Any, Any] = {}  # (block, eval_every) -> jit
         self._personal_cache_reset()
+        self._check_stack_fits()
+        if self._folds():
+            # the folding round keeps the global model until the last
+            # client has started from it, so a donated state aliases
+            # nothing in place and costs one more copy of the model
+            # (2.1 GiB of temporaries at 568 M parameters, compiled for a
+            # described v5e, PR 28): it borrows its state
+            self._donate = False
         self._build()
+
+    def init_model_params(self, rng: jax.Array):
+        """The model's parameters for this cohort's sample shape and dtype."""
+        from ..models import init_params
+
+        return init_params(self.model, rng, self.init_sample_shape,
+                           self.init_sample_dtype)
+
+    def params_template(self):
+        """The parameters' shapes and dtypes, nothing computed."""
+        return jax.eval_shape(
+            lambda: self.init_model_params(jax.random.PRNGKey(0)))
 
     # -- per-algorithm pieces -------------------------------------------------
     @abc.abstractmethod
@@ -937,6 +965,10 @@ class FedAlgorithm(abc.ABC):
         by construction — see :meth:`_topk_aggregate`."""
         from ..core.state import broadcast_tree, zeros_like_tree
 
+        if self._folds():
+            return self._train_selected_folded(
+                client_update, global_params, mask, sel_idx, round_idx,
+                round_key, x_train, y_train, n_train) + (residual,)
         if self.clients_per_round == self.num_clients:
             # full participation: sample_client_indexes always returns
             # arange (base.py early return), so the gathers are identity
@@ -966,10 +998,14 @@ class FedAlgorithm(abc.ABC):
         # numerics untouched). The round's scopes, all string literals at
         # their call sites: cohort_gather, local_train, guard, aggregate,
         # robust_aggregate, personal_update, numerics, eval_cache (this
-        # file); batch_gather, optimizer (core/trainer.py, inside
+        # file; the first three also in _train_selected_folded);
+        # batch_gather, optimizer (core/trainer.py, inside
         # local_train); stem with conv, norm, pool inside it
         # (models/alexnet3d.py:phased_stem_stage, inside the model's
-        # module). The forward/backward pass needs none: JAX prints it as
+        # module); embed, attention with full or window inside it, router,
+        # experts, shared_expert, dense_mlp, lm_head (models/decoder.py;
+        # lm_head also around the per-token CE in core/losses.py).
+        # The forward/backward pass needs none: JAX prints it as
         # the jvp()/transpose(jvp()) wrapper of the op_name.
         # benchmarks/metrics/*.json read these scopes BY NAME from the
         # device trace: a rename is an edit to both (and to PERF.md
@@ -1054,6 +1090,94 @@ class FedAlgorithm(abc.ABC):
             new_residual = residual
         return (new_global, params_out, jnp.mean(losses), fstats,
                 new_residual)
+
+    def _stack_readers(self) -> List[str]:
+        """The options of this run that read the selected clients' stacked
+        local models ``[S, model]`` after training, by the flag that sets
+        each; empty when the round needs the weighted mean and the loss
+        alone. Algorithms that do not say so (``track_personal``) are taken
+        to read the stack."""
+        asked = {
+            "--track_personal 1 (the personal models are the trained "
+            "clients' local ones)": getattr(self, "track_personal", True),
+            "--defense_type": getattr(self, "defense", None) is not None,
+            "--fault_spec": (self.fault_fn is not None
+                             or self.labelflip_fn is not None),
+            "--guard": self.guard_enabled,
+            f"--robust_agg {self.robust_agg}": self.robust_agg != "none",
+            f"--agg_impl {self.agg_impl}": self.agg_impl != "dense",
+            "--obs_numerics": self._numerics_plan is not None,
+            f"--client_store {self.client_store}": self._store is not None,
+        }
+        return [flag for flag, on in asked.items() if on]
+
+    def _folds(self) -> bool:
+        """Whether the round folds each client into the weighted sum as it
+        finishes (:meth:`_train_selected_folded`): the clients train one at
+        a time and nothing reads their stacked local models."""
+        return self.client_chunk == 1 and not self._stack_readers()
+
+    def _check_stack_fits(self) -> None:
+        """Refuse at build a round that must stack the local models on a
+        device that cannot hold the stack: with the clients trained one at
+        a time on one memory-limited device (``client_chunk`` 1) the stacked
+        body keeps the global model, the broadcast start and the trained
+        stack, ``(2 S + 1)`` models; the folding body keeps two."""
+        readers = self._stack_readers()
+        limit = _device_memory_limit()
+        if not readers or not limit or self.client_chunk != 1 \
+                or not hasattr(self, "track_personal"):
+            return
+        model_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in
+                          jax.tree_util.tree_leaves(self.params_template()))
+        need = (2 * self.clients_per_round + 1) * model_bytes
+        if need > limit:
+            raise ValueError(
+                f"{self.name}: {', '.join(readers)} read(s) the stacked "
+                f"local models of the {self.clients_per_round} clients of "
+                f"a round: {need / 2**30:.1f} GiB with the global model, "
+                f"and the device holds {limit / 2**30:.1f} GiB. Without "
+                "them the round folds each client into the weighted sum "
+                "as it finishes and keeps two models")
+
+    def _train_selected_folded(self, client_update, global_params, mask,
+                               sel_idx, round_idx, round_key, x_train,
+                               y_train, n_train):
+        """The round body of :meth:`_train_selected_weighted` where nothing
+        reads the stacked locals and the clients train one at a time: a
+        ``lax.scan`` over the selected clients that carries the running
+        weighted sum, under the same scopes. Same selection, keys and
+        weights; the new global differs from the stacked body's by float32
+        summation order, the loss not at all. A client carries no momentum
+        buffer when ``hp.momentum == 0``. Returns ``(new_global, None,
+        mean_loss, None)``: no locals, no guard statistics."""
+        from ..core.state import zeros_like_tree
+
+        s = sel_idx.shape[0]
+        keys = jax.random.split(round_key, s + 1)[:s]
+        weights = jnp.take(n_train, sel_idx).astype(jnp.float32)
+        weights = weights / jnp.maximum(jnp.sum(weights), 1.0)
+        mom0 = zeros_like_tree(global_params) if self.hp.momentum else None
+
+        def one_client(total, client):
+            idx, key, weight = client
+            with jax.named_scope("cohort_gather"):
+                x, y, n = (jax.lax.dynamic_index_in_dim(a, idx, 0, False)
+                           for a in (x_train, y_train, n_train))
+            with jax.named_scope("local_train"):
+                params, _, loss = client_update(
+                    global_params, mom0, mask, key, x, y, n, round_idx,
+                    global_params)
+            with jax.named_scope("aggregate"):
+                total = jax.tree_util.tree_map(
+                    lambda t, p: t + weight.astype(p.dtype) * p, total,
+                    params)
+            return total, loss
+
+        new_global, losses = jax.lax.scan(
+            one_client, zeros_like_tree(global_params),
+            (sel_idx, keys, weights))
+        return new_global, None, jnp.mean(losses), None
 
     def _topk_aggregate(self, locals_, global_params, residual, sel_idx,
                         weights, ok):

@@ -31,7 +31,6 @@ from flax import struct
 from ..core.losses import make_loss_fn
 from ..core.state import broadcast_tree, mix_over_clients
 from ..core.trainer import make_client_update
-from ..models import init_params
 from ..ops.sparsity import (
     cosine_annealing,
     erk_sparsities,
@@ -264,7 +263,7 @@ class DisPFL(FedAlgorithm):
 
     def init_state(self, rng: jax.Array) -> DisPFLState:
         p_rng, m_rng, s_rng = jax.random.split(rng, 3)
-        params = init_params(self.model, p_rng, self.init_sample_shape)
+        params = self.init_model_params(p_rng)
         shapes = param_shapes(params)
         if self.different_initial:
             mask_keys = jax.random.split(m_rng, self.num_clients)

@@ -24,7 +24,6 @@ from ..core.state import (
 )
 from ..core.trainer import make_client_update
 from ..core.state import HyperParams
-from ..models import init_params
 from .base import FedAlgorithm
 
 
@@ -108,7 +107,7 @@ class Ditto(FedAlgorithm):
 
     def init_state(self, rng: jax.Array) -> DittoState:
         p_rng, s_rng = jax.random.split(rng)
-        params = init_params(self.model, p_rng, self.init_sample_shape)
+        params = self.init_model_params(p_rng)
         if self._store is not None:
             # store mode: the personal stack lives in the client store
             # (lazy init-params default rows); state holds None between

@@ -22,7 +22,6 @@ from flax import struct
 
 from ..core.state import broadcast_tree, mix_over_clients
 from ..core.trainer import make_client_update
-from ..models import init_params
 from ..parallel.topology import neighbor_adjacency
 from .base import FedAlgorithm
 
@@ -77,7 +76,7 @@ class DPSGD(FedAlgorithm):
 
     def init_state(self, rng: jax.Array) -> DPSGDState:
         p_rng, s_rng = jax.random.split(rng)
-        params = init_params(self.model, p_rng, self.init_sample_shape)
+        params = self.init_model_params(p_rng)
         return DPSGDState(
             personal_params=broadcast_tree(params, self.num_clients),
             rng=s_rng,

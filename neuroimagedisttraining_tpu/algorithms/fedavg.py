@@ -29,7 +29,6 @@ from ..core.state import (
     zeros_like_tree,
 )
 from ..core.trainer import make_client_update
-from ..models import init_params
 from ..obs import trace as obs_trace
 from .base import FedAlgorithm
 
@@ -149,7 +148,7 @@ class FedAvg(FedAlgorithm):
 
     def init_state(self, rng: jax.Array) -> FedAvgState:
         p_rng, s_rng = jax.random.split(rng)
-        params = init_params(self.model, p_rng, self.init_sample_shape)
+        params = self.init_model_params(p_rng)
         if self._store is not None:
             # store mode: the per-client rows live in the client store
             # (lazy defaults — init params / zero residual; nothing

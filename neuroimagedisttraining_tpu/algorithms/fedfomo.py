@@ -29,7 +29,6 @@ from flax import struct
 
 from ..core.state import broadcast_tree, tree_index
 from ..core.trainer import make_client_update
-from ..models import init_params
 from .base import FedAlgorithm
 
 
@@ -153,7 +152,7 @@ class FedFomo(FedAlgorithm):
 
     def init_state(self, rng: jax.Array) -> FedFomoState:
         p_rng, s_rng = jax.random.split(rng)
-        params = init_params(self.model, p_rng, self.init_sample_shape)
+        params = self.init_model_params(p_rng)
         return FedFomoState(
             personal_params=broadcast_tree(params, self.num_clients),
             p_choose=jnp.ones((self.num_clients, self.num_clients)),

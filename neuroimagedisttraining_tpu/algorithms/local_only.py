@@ -13,7 +13,6 @@ from flax import struct
 
 from ..core.state import broadcast_tree, tree_index, tree_scatter_update
 from ..core.trainer import make_client_update
-from ..models import init_params
 from .base import FedAlgorithm, sample_client_indexes
 
 
@@ -56,7 +55,7 @@ class LocalOnly(FedAlgorithm):
 
     def init_state(self, rng: jax.Array) -> LocalOnlyState:
         p_rng, s_rng = jax.random.split(rng)
-        params = init_params(self.model, p_rng, self.init_sample_shape)
+        params = self.init_model_params(p_rng)
         return LocalOnlyState(
             personal_params=broadcast_tree(params, self.num_clients),
             rng=s_rng,

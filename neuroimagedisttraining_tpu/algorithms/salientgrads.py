@@ -34,7 +34,6 @@ from flax import struct
 
 from ..core.state import broadcast_tree
 from ..core.trainer import make_client_update
-from ..models import init_params
 from ..obs import trace as obs_trace
 from ..ops.sparsity import make_snip_score_fn, mask_density, mask_from_scores
 from .base import FedAlgorithm
@@ -235,7 +234,7 @@ class SalientGrads(FedAlgorithm):
 
     def init_state(self, rng: jax.Array) -> SalientGradsState:
         p_rng, m_rng, s_rng = jax.random.split(rng, 3)
-        params = init_params(self.model, p_rng, self.init_sample_shape)
+        params = self.init_model_params(p_rng)
         if not self.snip_mask:
             # --snip_mask 0: dense-control mode, all-ones mask
             # (sailentgrads/client.py:95-103)

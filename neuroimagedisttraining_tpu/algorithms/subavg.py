@@ -30,7 +30,6 @@ from ..core.state import (
     tree_scatter_update,
 )
 from ..core.trainer import make_client_update
-from ..models import init_params
 from ..ops.sparsity import (
     magnitude_prune_mask,
     mask_density,
@@ -164,7 +163,7 @@ class SubAvg(FedAlgorithm):
 
     def init_state(self, rng: jax.Array) -> SubAvgState:
         p_rng, s_rng = jax.random.split(rng)
-        params = init_params(self.model, p_rng, self.init_sample_shape)
+        params = self.init_model_params(p_rng)
         # all clients start from the SAME all-ones mask (subavg_api.py:45-47)
         masks = broadcast_tree(
             jax.tree_util.tree_map(jnp.ones_like, params), self.num_clients

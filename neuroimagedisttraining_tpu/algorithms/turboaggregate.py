@@ -26,7 +26,6 @@ from flax import struct
 
 from ..core.state import broadcast_tree, zeros_like_tree
 from ..core.trainer import make_client_update
-from ..models import init_params
 from ..ops import mpc
 from .base import FedAlgorithm, sample_client_indexes
 
@@ -98,7 +97,7 @@ class TurboAggregate(FedAlgorithm):
 
     def init_state(self, rng: jax.Array) -> TurboAggregateState:
         p_rng, s_rng = jax.random.split(rng)
-        params = init_params(self.model, p_rng, self.init_sample_shape)
+        params = self.init_model_params(p_rng)
         return TurboAggregateState(global_params=params, rng=s_rng)
 
     def run_round(self, state: TurboAggregateState, round_idx: int):

@@ -102,6 +102,11 @@ FLAG_CLASSES: Dict[str, Tuple[str, str]] = {
     "track_personal": ("identity", "'nopers' state-structure split"),
     "eval_cache": ("identity", "'evcache' state-structure + eval-"
                                "protocol split (r5/topk pattern)"),
+    "lm_layers": ("identity", "lm<L>e<E>t<T>: a chip's share of a "
+                              "decoder model is another model"),
+    "lm_expert_shards": ("identity", "lm...e<E>: experts held"),
+    "lm_tensor_shards": ("identity", "lm...t<T>: heads and vocabulary "
+                                     "rows held"),
     "global_test": ("identity", "'-g' reference-parity tag"),
     "tag": ("identity", "explicit identity suffix"),
     # -- inert (telemetry / logging / placement / scheduling-only) ---------

@@ -49,10 +49,29 @@ def mse_per_example(preds: jax.Array, targets: jax.Array) -> jax.Array:
     return jnp.square(preds - targets.astype(preds.dtype))
 
 
+def token_ce_per_example(logits: jax.Array, labels: jax.Array) -> jax.Array:
+    """Per-sequence cross-entropy of a language model: ``logits [B, S, V]``
+    over the vocabulary rows held, ``labels [B, S]`` the next ids. A label
+    below 0 carries no target (the last position of a shard's sequence) and
+    has weight 0; the value is the float32 mean over a sequence's positions
+    that have one. The head's product (``models/decoder.py``) and this
+    reduction share the scope ``lm_head``."""
+    logits = _first_output(logits)
+    with jax.named_scope("lm_head"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        picked = jnp.take_along_axis(
+            logp, jnp.maximum(labels, 0)[..., None].astype(jnp.int32),
+            axis=-1)[..., 0]
+        w = (labels >= 0).astype(jnp.float32)
+        return -jnp.sum(picked * w, axis=-1) / jnp.maximum(
+            jnp.sum(w, axis=-1), 1.0)
+
+
 PER_EXAMPLE_LOSSES = {
     "bce": bce_with_logits_per_example,
     "ce": softmax_ce_per_example,
     "mse": mse_per_example,
+    "token_ce": token_ce_per_example,
 }
 
 
@@ -71,7 +90,8 @@ def mse_loss(preds: jax.Array, targets: jax.Array) -> jax.Array:
 def predictions(logits: jax.Array, loss_type: str) -> jax.Array:
     """Hard predictions matching the reference's eval rules.
 
-    BCE: sigmoid >= 0.5 (``my_model_trainer.py:243-248``); CE: argmax.
+    BCE: sigmoid >= 0.5 (``my_model_trainer.py:243-248``); CE: argmax
+    (``token_ce``: per token, ``[B, S]``).
     """
     logits = _first_output(logits)
     if loss_type == "bce":

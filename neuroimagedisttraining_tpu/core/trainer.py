@@ -98,7 +98,10 @@ def make_client_update(
     Returns ``client_update(params, momentum, mask, rng, x, y, n_valid,
     round_idx, prox_target) -> (params, momentum, mean_loss)``; vmap over a
     leading client axis on everything except ``round_idx``. ``prox_target``
-    is ignored (and DCE'd) unless ``prox_lambda > 0``.
+    is ignored (and DCE'd) unless ``prox_lambda > 0``. ``momentum`` may be
+    None where ``hp.momentum == 0``: no buffer rides the step loop then
+    (a model's worth of memory; the folding round of ``algorithms/base.py``
+    passes none).
     """
     per_example = PER_EXAMPLE_LOSSES[loss_type]
     epoch_mode = hp.batching == "epoch"
@@ -123,17 +126,23 @@ def make_client_update(
 
     @jax.named_scope("optimizer")
     def apply_update(params, momentum, grads, mask, prox_target, lr):
-        """One optimizer step: clip + (masked) SGD + prox pull + re-mask."""
+        """One optimizer step: clip + (masked) SGD + prox pull + re-mask.
+        ``momentum`` None: the caller carries no buffer (sound at
+        ``hp.momentum == 0`` only, where the step never reads it)."""
         grads = clip_by_global_norm(grads, hp.grad_clip)
+        carried = momentum is not None
+        if not carried:
+            momentum = grads    # a stand-in of the right shape, never read
         if fused_kernels and not prox_lambda:
             from ..ops.pallas_kernels import fused_masked_sgd_step
 
             ones = mask if (mask_grads or mask_params_post_step) \
                 else jax.tree_util.tree_map(jnp.ones_like, params)
-            return fused_masked_sgd_step(
+            params, momentum = fused_masked_sgd_step(
                 params, momentum, grads, ones, lr,
                 momentum=hp.momentum, wd=hp.weight_decay,
                 mask_grads=mask_grads)
+            return params, (momentum if carried else None)
         if mask_grads:
             grads = jax.tree_util.tree_map(lambda g, m: g * m, grads, mask)
         params, momentum = sgd_momentum_step(
@@ -146,10 +155,12 @@ def make_client_update(
             )
         if mask_params_post_step:
             params = jax.tree_util.tree_map(lambda p, m: p * m, params, mask)
-        return params, momentum
+        return params, (momentum if carried else None)
 
     def client_update(params, momentum, mask, rng, x, y, n_valid, round_idx,
                       prox_target):
+        if momentum is None and hp.momentum:
+            raise ValueError("momentum=None needs hp.momentum == 0")
         lr = hp.lr * jnp.power(hp.lr_decay, round_idx.astype(jnp.float32))
 
         if epoch_mode:

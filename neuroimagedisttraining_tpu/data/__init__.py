@@ -75,6 +75,12 @@ def load_federated_data(
         return make_synthetic_federated(
             seed=seed, n_clients=client_number,
             val_per_client=val_per_client, **kwargs)
+    if name == "token_shards":
+        from .tokens import make_token_shards
+
+        return make_token_shards(
+            seed=seed, n_clients=client_number,
+            train_per_client=kwargs.pop("samples_per_client", 4), **kwargs)
     raise ValueError(f"unknown dataset {dataset!r}")
 
 

@@ -44,7 +44,17 @@ def build_parser(algo: Optional[str] = None) -> argparse.ArgumentParser:
                    help="model key in the zoo registry (3dcnn, resnet18, ...)")
     p.add_argument("--dataset", type=str, default="synthetic",
                    help="abcd | abcd_site | cifar10 | cifar100 | "
-                        "tiny_imagenet | synthetic")
+                        "tiny_imagenet | synthetic | token_shards")
+    # a chip's share of a language model (models/decoder.py:Share)
+    p.add_argument("--lm_layers", type=int, default=0,
+                   help="decoder models: leading layers kept on this chip "
+                        "(0 = all; the rest are further pipeline stages)")
+    p.add_argument("--lm_expert_shards", type=int, default=1,
+                   help="decoder models: chips that share a layer's routed "
+                        "experts; this chip holds published / N of them")
+    p.add_argument("--lm_tensor_shards", type=int, default=1,
+                   help="decoder models: chips that share a layer's heads "
+                        "and the vocabulary rows")
     p.add_argument("--data_dir", type=str, default="",
                    help="dataset root (ABCD .h5 path or CIFAR batches dir)")
     p.add_argument("--partition_method", type=str, default="dir",
@@ -955,6 +965,13 @@ def run_identity(args: argparse.Namespace, algo: Optional[str] = None,
         if v is not None:
             parts.append(f"{extra.replace('_', '')}{v:g}"
                          if isinstance(v, float) else f"{extra[:4]}{v}")
+    share = (getattr(args, "lm_layers", 0),
+             getattr(args, "lm_expert_shards", 1),
+             getattr(args, "lm_tensor_shards", 1))
+    if share != (0, 1, 1):
+        # a chip's share of a decoder model is another model: its depth,
+        # its experts and its shapes all change the state's structure
+        parts.append("lm{}e{}t{}".format(*share))
     # defense and fine-tune knobs change training behavior — they must
     # split checkpoint/log/stat_info lineages (unlike inert identity tags)
     if algo == "salientgrads" and getattr(args, "stratified_sampling", 0):

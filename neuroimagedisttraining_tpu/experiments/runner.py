@@ -46,6 +46,10 @@ def build_data(args: argparse.Namespace, client_filter=None):
         # CI-scale default; real ABCD shapes come from the .h5 itself
         kwargs["sample_shape"] = (8, 8, 8, 1)
         kwargs["samples_per_client"] = max(args.batch_size, 16)
+    elif args.dataset.lower() == "token_shards":
+        kwargs["vocab"] = _decoder_share(args)[1]["vocab_size"]
+        kwargs["sequence_length"] = 128     # the synthetic stand-in's
+        kwargs["samples_per_client"] = max(args.batch_size, 2)
     elif _is_abcd_h5(args.dataset):
         kwargs["layout"] = getattr(args, "layout", "channels")
         if kwargs["layout"] == "s2d":
@@ -64,6 +68,20 @@ def build_data(args: argparse.Namespace, client_filter=None):
         seed=42,  # the reference's fixed split seed (data_loader.py:67-102)
         **kwargs,
     )
+
+
+def _decoder_share(args):
+    """``(Share kwargs, held configuration)`` of a decoder model
+    (``models/decoder.py``) from the ``--lm_*`` flags; ``(None, None)`` for
+    any other model."""
+    from ..models import decoder
+
+    if args.model.lower() not in decoder.CONFIGS:
+        return None, None
+    share = dict(layers=args.lm_layers, expert_shards=args.lm_expert_shards,
+                 tensor_shards=args.lm_tensor_shards)
+    return share, decoder.held_config(args.model.lower(),
+                                      decoder.Share(**share))
 
 
 def _is_abcd_h5(dataset: str) -> bool:
@@ -184,9 +202,13 @@ def _resolve_lineage_semantics(args, meta: dict, last: int,
             "a different --checkpoint_dir) for the other mode.")
 
 
-def infer_loss_type(args: argparse.Namespace, class_num: int) -> str:
+def infer_loss_type(args: argparse.Namespace, class_num: int,
+                    x_dtype=None) -> str:
     """ABCD/3D path uses BCE-with-logits (my_model_trainer.py:191-206);
-    CIFAR path uses CE (fedavg/my_model_trainer.py:38-67)."""
+    CIFAR path uses CE (fedavg/my_model_trainer.py:38-67); integer inputs
+    are token shards (data/tokens.py): per-token CE over the vocabulary."""
+    if x_dtype is not None and np.issubdtype(x_dtype, np.integer):
+        return "token_ce"
     if args.model.startswith("3d") and class_num == 2:
         return "bce"
     if args.dataset.lower().startswith(("abcd", "synthetic")) and class_num == 2:
@@ -255,9 +277,19 @@ def build_algorithm(args: argparse.Namespace, algo_name: str, data=None):
         data = data.replace(x_train=cast(data.x_train),
                             x_test=cast(data.x_test),
                             x_val=cast(data.x_val))
-    loss_type = infer_loss_type(args, data.class_num)
+    loss_type = infer_loss_type(args, data.class_num, data.x_train.dtype)
     num_outputs = 1 if loss_type == "bce" else data.class_num
-    model = create_model(model_key, num_classes=num_outputs)
+    share, _ = _decoder_share(args)
+    if (share is None) != (loss_type != "token_ce"):
+        raise SystemExit(
+            f"--model {args.model} with --dataset {args.dataset}: token "
+            "shards (integer inputs) train the decoder models, and only "
+            "them")
+    if share is not None and args.frequency_of_the_test:
+        raise SystemExit(
+            "evaluation of a token cohort (perplexity each round) is not "
+            "implemented; pass --frequency_of_the_test 0")
+    model = create_model(model_key, num_classes=num_outputs, **(share or {}))
 
     from ..parallel.multihost import host_client_counts
 
@@ -281,7 +313,10 @@ def build_algorithm(args: argparse.Namespace, algo_name: str, data=None):
 
     common = dict(
         loss_type=loss_type, frac=args.frac, seed=args.seed,
+        # a decoder's grouped expert product cannot be vmapped over
+        # clients: they train one at a time, whatever the device
         client_chunk=(args.client_chunk
+                      or (1 if share is not None else None)
                       or _auto_client_chunk(args, data.num_clients)),
         compute_dtype=getattr(args, "compute_dtype", "") or None,
         channel_inject=(layout == "flat" and _is_abcd_h5(args.dataset)),
@@ -490,7 +525,18 @@ def build_algorithm(args: argparse.Namespace, algo_name: str, data=None):
         extra = dict(n_groups=args.n_groups)
 
     cls = ALGORITHMS[algo_name]
-    return cls(model, data, hp, **common, **extra), data
+    algo = cls(model, data, hp, **common, **extra)
+    if share is not None and (algo.client_chunk != 1
+                              or algo._stack_readers()
+                              or algo_name not in ("fedavg", "salientgrads")):
+        raise SystemExit(
+            f"--model {args.model} trains in the round that folds each "
+            "client into the weighted sum (fedavg or salientgrads, "
+            "--client_chunk 1, --track_personal 0): its expert layer's "
+            "grouped product runs one client at a time. Asked for instead: "
+            f"--algo {algo_name} --client_chunk {algo.client_chunk} "
+            + " ".join(algo._stack_readers()))
+    return algo, data
 
 
 def build_multihost_data(args: argparse.Namespace):
@@ -1036,6 +1082,17 @@ def run_experiment(args: argparse.Namespace,
         if state is None:
             with obs_trace.span("init_state"):
                 state = algo.init_state(jax.random.PRNGKey(args.seed))
+        if _decoder_share(args)[0] is not None:
+            # how the first batch's routed slots fall on the held experts
+            # (two gauges; a model without experts sets none)
+            from ..obs import metrics as obs_metrics
+            from ..obs.expert_load import record_expert_load
+
+            with obs_trace.span("expert_load"):
+                record_expert_load(
+                    algo, state.global_params,
+                    obs_session.registry if obs_session is not None
+                    else obs_metrics.get_registry())
 
         # comm telemetry (--obs_comm): price the aggregation wire ONCE —
         # the analytical model from the params template + live mask
@@ -1108,7 +1165,8 @@ def run_experiment(args: argparse.Namespace,
         from ..utils.flops import CostTracker
 
         cost = CostTracker(model=algo.model,
-                           sample_shape=algo.init_sample_shape)
+                           sample_shape=algo.init_sample_shape,
+                           sample_dtype=algo.init_sample_dtype)
         samples_per_client = algo.hp.local_steps * algo.hp.batch_size
         if getattr(args, "batching", "epoch") == "epoch":
             # epoch batching: each client consumes its own n_i samples per
@@ -1399,7 +1457,9 @@ def run_experiment(args: argparse.Namespace,
             final_eval = {k: v for k, v in fin_rec.items()
                           if k not in ("round", "finetune")}
         if final_eval is None:  # last round wasn't an eval round
-            final_eval = algo.evaluate(state)
+            # (a token cohort has no evaluation yet: build_algorithm)
+            final_eval = {} if algo.loss_type == "token_ce" \
+                else algo.evaluate(state)
         extras = {}
         if getattr(args, "save_masks", False) and hasattr(state, "masks"):
             # dispfl_api.py:177-183: final boolean masks in stat_info

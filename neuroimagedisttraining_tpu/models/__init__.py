@@ -42,8 +42,22 @@ def _registry():
     from .resnet_gn import resnet18_gn, resnet34_gn, resnet50_gn
     from .resnet2d import OriginalResNet18
     from .resnet_ip import ResNetIP
+    from . import decoder
+
+    def language_model(name):
+        def build(num_classes, **kw):
+            model = decoder.decoder(name, decoder.Share(**kw))
+            if num_classes != model.num_classes:
+                raise ValueError(
+                    f"{name}: this share holds {model.num_classes} "
+                    f"vocabulary rows, the data has {num_classes} ids")
+            return model
+        return build
 
     return {
+        # the decoder family (models/decoder.py): a chip's share of a
+        # published language model; kwargs are decoder.Share's fields
+        **{name: language_model(name) for name in decoder.CONFIGS},
         # reference names (main_*.py --model flags)
         "3dcnn": lambda num_classes, **kw: AlexNet3D(num_classes=num_classes, **kw),
         # TPU-fast AlexNet3D over phase-decomposed input (ops/s2d.py);
@@ -125,26 +139,34 @@ def make_apply_fn(model, compute_dtype=None, channel_inject=False) -> ApplyFn:
             tree,
         )
 
-    def apply_fn(params, x, train: bool, rng):
+    def apply_fn(params, x, train: bool, rng, mutable=False):
+        """``mutable`` names flax collections to open for this call (what
+        the model sows: the decoder's expert statistics); the result is then
+        ``(out, collections)``."""
         if channel_inject:
             x = x[..., None]
         if compute_dtype is not None:
             params = _cast_in(params)
-            x = x.astype(compute_dtype)
-        if train:
-            out = model.apply(
-                {"params": params}, x, train=True, rngs={"dropout": rng}
-            )
-        else:
-            out = model.apply({"params": params}, x, train=False)
-        return _cast_out(out) if compute_dtype is not None else out
+            x = _cast_in(x)     # floating inputs only: token ids stay ints
+        out = model.apply(
+            {"params": params}, x, train=train, mutable=mutable,
+            **({"rngs": {"dropout": rng}} if train else {}))
+        sown = None
+        if mutable:
+            out, sown = out
+        if compute_dtype is not None:
+            out = _cast_out(out)
+        return (out, sown) if mutable else out
 
     return apply_fn
 
 
-def init_params(model, rng: jax.Array, sample_shape: Tuple[int, ...]):
-    """Initialize parameters for input volumes/images of ``sample_shape``
-    (without batch axis).
+def init_params(model, rng: jax.Array, sample_shape: Tuple[int, ...],
+                dtype="float32"):
+    """Initialize parameters for inputs of ``sample_shape`` (without batch
+    axis) and ``dtype``: float32 for volumes and images whatever they are
+    stored as (the apply closure casts them), the data's own for integer
+    inputs (token ids index an embedding).
 
     One jitted program: ``model.init`` runs the model's forward pass to learn
     the shapes, and eagerly that is every op of it at the sample's full size
@@ -156,7 +178,7 @@ def init_params(model, rng: jax.Array, sample_shape: Tuple[int, ...]):
     import jax.numpy as jnp
 
     def init(rng):
-        x = jnp.zeros((1,) + tuple(sample_shape), jnp.float32)
+        x = jnp.zeros((1,) + tuple(sample_shape), dtype)
         variables = model.init({"params": rng, "dropout": rng}, x,
                                train=False)
         return variables["params"]

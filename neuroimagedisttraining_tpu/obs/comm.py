@@ -270,12 +270,9 @@ class WireCostModel:
         on."""
         import jax
 
-        from ..models import init_params
         from ..parallel.collectives import build_sparse_plan
 
-        template = jax.eval_shape(
-            lambda: init_params(algo.model, jax.random.PRNGKey(0),
-                                algo.init_sample_shape))
+        template = algo.params_template()
         _ensure_agg_plan(algo, state)
         plan = getattr(algo, "_agg_sparse_plan", None)
         if plan is None and state is not None:
@@ -397,11 +394,7 @@ def _synthetic_cohort(algo):
     import jax
     import jax.numpy as jnp
 
-    from ..models import init_params
-
-    template = jax.eval_shape(
-        lambda: init_params(algo.model, jax.random.PRNGKey(0),
-                            algo.init_sample_shape))
+    template = algo.params_template()
     leaves, treedef = jax.tree_util.tree_flatten(template)
     s = algo.clients_per_round
     key = jax.random.PRNGKey(0)

@@ -68,11 +68,13 @@ def _lookup(tree, path):
     return tree
 
 
-def per_layer_flops(model, params, sample_shape: Tuple[int, ...]
+def per_layer_flops(model, params, sample_shape: Tuple[int, ...],
+                    sample_dtype=jnp.float32
                     ) -> Dict[Tuple[str, ...], float]:
     """Per-sample dense FLOPs for every parametric layer (conv of any
-    rank + dense), keyed by the layer's param-tree path."""
-    x = jax.ShapeDtypeStruct((1,) + tuple(sample_shape), jnp.float32)
+    rank + dense: the leaves flax names ``kernel``), keyed by the layer's
+    param-tree path."""
+    x = jax.ShapeDtypeStruct((1,) + tuple(sample_shape), sample_dtype)
 
     def fwd(p, xb):
         return model.apply({"params": p}, xb, train=False,
@@ -211,9 +213,12 @@ class CostTracker:
     ``sum_training_flops`` / ``sum_comm_params``
     (``sailentgrads_api.py:137-138,334-346``)."""
 
-    def __init__(self, model=None, sample_shape: Optional[Tuple[int, ...]] = None):
+    def __init__(self, model=None,
+                 sample_shape: Optional[Tuple[int, ...]] = None,
+                 sample_dtype=jnp.float32):
         self.model = model
         self.sample_shape = sample_shape
+        self.sample_dtype = sample_dtype
         self.sum_training_flops = 0.0
         self.sum_comm_params = 0
         self.per_round: list = []
@@ -222,7 +227,7 @@ class CostTracker:
     def _dense_per_layer(self, params) -> Dict[Tuple[str, ...], float]:
         if self._dense_flops is None:
             self._dense_flops = per_layer_flops(
-                self.model, params, self.sample_shape)
+                self.model, params, self.sample_shape, self.sample_dtype)
         return self._dense_flops
 
     def record_round(self, params, mask=None, n_clients: int = 1,

@@ -163,3 +163,60 @@ def test_alexnet_pool_keeps_its_names_whatever_its_backward(platform,
             for d in ("fwd", "bwd")}
     assert "reduce_window_max" in pool["fwd"], pool
     assert backward in pool["bwd"], pool
+
+
+DECODER_SCOPES = ("embed", "attention", "attention/full", "attention/window",
+                  "router", "experts", "shared_expert", "dense_mlp",
+                  "lm_head")
+
+
+def test_folding_round_of_the_decoder_holds_its_scopes():
+    """The tiny decoder's compiled round (the body that folds each client
+    into the sum): the round's scopes, the decoder's in both passes, and
+    every scope the new cell's metric files ask for by name."""
+    import glob
+    import json
+    import os
+
+    from neuroimagedisttraining_tpu.data.tokens import make_token_shards
+    from neuroimagedisttraining_tpu.models import decoder
+
+    share = decoder.Share(layers=5, expert_shards=4, tensor_shards=2)
+    data = make_token_shards(0, n_clients=4, vocab=32, sequence_length=32,
+                             train_per_client=2)
+    hp = HyperParams(lr=0.05, local_epochs=1, steps_per_epoch=2,
+                     batch_size=1)
+    algo = FedAvg(decoder.decoder("laguna_tiny", share), data, hp,
+                  loss_type="token_ce", frac=0.5, seed=3, client_chunk=1,
+                  track_personal=False)
+    state = algo.init_state(jax.random.PRNGKey(3))
+    compiled = algo._round_jit.lower(
+        state, jnp.arange(2, dtype=jnp.int32), jnp.asarray(0, jnp.float32),
+        data.x_train, data.y_train, data.n_train).compile()
+    names = op_names(compiled.as_text())
+    for scope in ("cohort_gather", "local_train", "batch_gather",
+                  "optimizer", "aggregate"):
+        assert count(names, scope) > 0, (scope, "no instruction under it")
+    assert count(names, "personal_update") == 0
+    for scope in DECODER_SCOPES:
+        assert count(names, scope, "fwd") > 0, (scope, "forward")
+        assert count(names, scope, "bwd") > 0, (scope, "backward")
+    # the scores and the band sit inside attention, the CE beside the head
+    assert count(names, "full") == count(names, "attention/full")
+    assert count(names, "window") == count(names, "attention/window")
+    metrics = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "metrics", "*.json")
+    asked = set()
+    for path in glob.glob(metrics):
+        with open(path) as f:
+            args = json.load(f).get("args", {})
+        if args.get("scope", "").split("/")[0] in DECODER_SCOPES:
+            asked.add(args["scope"])
+            layers = args.get("layers", {})
+            if isinstance(layers, dict):    # row -> the scope it is timed under
+                asked.update(layers.values())
+    assert asked == {"attention", "attention/full", "attention/window",
+                     "router", "experts", "lm_head", "dense_mlp",
+                     "shared_expert", "embed"}
+    for scope in asked:
+        assert count(names, scope) > 0, scope
