@@ -50,9 +50,10 @@ def _personal_metrics(correct, loss_sum, total):
 
 
 def _device_memory_limit() -> int:
-    """Bytes the first device may hold, 0 where the backend keeps no such
-    statistic (the CPU)."""
-    return (jax.devices()[0].memory_stats() or {}).get("bytes_limit", 0)
+    """Bytes this process's first device may hold, 0 where the backend keeps
+    no such statistic (the CPU)."""
+    return (jax.local_devices()[0].memory_stats() or {}).get(
+        "bytes_limit", 0)
 
 
 def sample_client_indexes(
@@ -721,6 +722,25 @@ class FedAlgorithm(abc.ABC):
             self._agg_mesh_known = True
         return self._agg_mesh_val
 
+    def place_state(self, state):
+        """``state`` with the leaves that sit uncommitted on one device (a
+        fresh PRNG key, a model initialised before the data was placed)
+        replicated on the data's mesh, which is how a round returns them.
+        Left where they are, the first round is lowered and compiled (or
+        loaded) for their shardings and the second for the round's own:
+        two programs for one, in every process (PERF.md section 6, PR 29:
+        4.7 s of ``mesh4``'s set-up). Nothing moves off a mesh, or in a
+        multi-process run, whose placement is ``parallel/multihost.py``'s."""
+        mesh = self._agg_mesh()
+        if mesh is None or jax.process_count() > 1:
+            return state
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        everywhere = NamedSharding(mesh, PartitionSpec())
+        return jax.tree_util.tree_map(
+            lambda a: jax.device_put(a, everywhere)
+            if isinstance(a, jax.Array) and not a.committed else a, state)
+
     def _require_plan(self, what: str):
         if self._agg_sparse_plan is None:
             raise ValueError(
@@ -843,8 +863,9 @@ class FedAlgorithm(abc.ABC):
         n = host_client_counts(self.data.n_train)
         return bool((n >= hp.steps_per_epoch * hp.batch_size).all())
 
-    def _vmap_clients(self, fn, in_axes):
-        """vmap ``fn`` over the leading client axis, optionally chunked.
+    def _vmap_clients(self, fn, in_axes, max_chunk=None):
+        """vmap ``fn`` over the leading client axis, optionally chunked
+        (``max_chunk`` clients at once; not given: ``client_chunk``).
 
         On a pod, the full vmap is the right thing: each client's work lands
         on its own device. On fewer devices than clients, the vmapped
@@ -854,7 +875,7 @@ class FedAlgorithm(abc.ABC):
         jitted program with zero host round-trips.
         """
         vfn = jax.vmap(fn, in_axes=in_axes)
-        max_chunk = self.client_chunk
+        max_chunk = max_chunk or self.client_chunk
         if not max_chunk:
             return vfn
 
@@ -926,6 +947,43 @@ class FedAlgorithm(abc.ABC):
             )
 
         return chunked
+
+    def _train_clients(self, client_update, n_clients: int):
+        """``client_update`` mapped over the leading client axis of all its
+        arguments but the round index: the local training of a round.
+
+        Where the cohort lies on a ``clients`` mesh whose devices divide
+        ``n_clients``, the mapping runs inside ``jax.shard_map`` over that
+        axis: each chip lowers the training of its own sites as one device's
+        program, which is what ``_vmap_clients`` was written for and what a
+        Mosaic kernel needs (GSPMD cannot partition one:
+        ``ops/pool_vjp.py``). Nothing in the local training talks across
+        clients, so the work is the parent's; what surrounds it (gathers,
+        faults, defenses, the aggregate's all-reduce) stays GSPMD's. A mesh
+        with another axis of more than one device (``space``: GSPMD
+        partitions each volume through the conv) and a client count the
+        devices do not divide keep the one vmapped program.
+
+        A chip that holds several of the round's sites is the one
+        memory-limited device holding several clients of
+        ``runner._auto_client_chunk``, and the same rule answers: it maps
+        them one at a time unless ``client_chunk`` says otherwise. On the
+        v5e the vmapped pair is also the slower program, by 22 % of the
+        round (PERF.md section 6, PR 29)."""
+        from jax.sharding import PartitionSpec as P
+
+        in_axes = (0, 0, 0, 0, 0, 0, 0, None, 0)
+        mesh = self._agg_mesh()
+        n_dev = mesh.shape.get("clients", 1) if mesh is not None else 1
+        if n_dev == 1 or mesh.size != n_dev or n_clients % n_dev:
+            return self._vmap_clients(client_update, in_axes)
+        auto = 1 if n_clients > n_dev and _device_memory_limit() else None
+        return jax.shard_map(
+            self._vmap_clients(client_update, in_axes,
+                               self.client_chunk or auto),
+            mesh=mesh, out_specs=P("clients"),
+            in_specs=tuple(P() if ax is None else P("clients")
+                           for ax in in_axes))
 
     def _train_selected_weighted(
         self, client_update, global_params, mask, sel_idx, round_idx,
@@ -1012,10 +1070,9 @@ class FedAlgorithm(abc.ABC):
         # section 3), and shows only after the persistent compile cache
         # is emptied (its key leaves HLO metadata out).
         with jax.named_scope("local_train"):
-            params_out, _, losses = self._vmap_clients(
-                client_update, in_axes=(0, 0, 0, 0, 0, 0, 0, None, 0)
-            )(params0, mom0, mask_b, keys[:s], x_sel, y_sel, n_sel,
-              round_idx, params0)
+            params_out, _, losses = self._train_clients(client_update, s)(
+                params0, mom0, mask_b, keys[:s], x_sel, y_sel, n_sel,
+                round_idx, params0)
         dropped = None
         if self.fault_fn is not None:
             # inject AFTER training: faults model what leaves the client
@@ -1346,10 +1403,9 @@ class FedAlgorithm(abc.ABC):
         mom0 = zeros_like_tree(params_stack)
         if prox_target is None:
             prox_target = params_stack
-        return self._vmap_clients(
-            client_update, in_axes=(0, 0, 0, 0, 0, 0, 0, None, 0)
-        )(params_stack, mom0, mask_stack, keys, x, y, n, round_idx,
-          prox_target)
+        return self._train_clients(client_update, c)(
+            params_stack, mom0, mask_stack, keys, x, y, n, round_idx,
+            prox_target)
 
     def _make_global_eval(self):
         eval_client = self.eval_client
@@ -2243,6 +2299,7 @@ class FedAlgorithm(abc.ABC):
                 comm_rounds, eval_every, state, finalize, fuse_rounds)
         if state is None:
             state = self.init_state(jax.random.PRNGKey(self.seed))
+        state = self.place_state(state)
         history: List[Dict[str, Any]] = []
         # metric host-fetches run one round late (utils/records.py): a
         # callback opts into immediate conversion since it observes

@@ -618,9 +618,13 @@ def _auto_client_chunk(args: argparse.Namespace,
     over a v5e's 15.75 GB once the cohort is resident). So where ONE
     device holds every client and reports a memory limit, clients map one
     at a time (``lax.map``, still one program). A ``clients`` mesh
-    spreads clients over devices and keeps the full vmap — a ``lax.map``
-    over a sharded client axis would visit the devices in turn; backends
-    that report no limit (CPU) keep it too."""
+    spreads clients over devices and keeps the full vmap for whatever GSPMD
+    partitions (the SNIP pass, the personal eval) — a ``lax.map`` over a
+    sharded client axis would visit the devices in turn; backends that
+    report no limit (CPU) keep it too. The mesh round's local training runs
+    inside a ``shard_map`` over ``clients``, where each chip is again one
+    device holding its own sites: ``FedAlgorithm._train_clients`` applies
+    this rule there, per chip (PR 29)."""
     import jax
 
     if jax.process_count() > 1 or n_clients < 2 \
@@ -1082,6 +1086,7 @@ def run_experiment(args: argparse.Namespace,
         if state is None:
             with obs_trace.span("init_state"):
                 state = algo.init_state(jax.random.PRNGKey(args.seed))
+        state = algo.place_state(state)
         if _decoder_share(args)[0] is not None:
             # how the first batch's routed slots fall on the held experts
             # (two gauges; a model without experts sets none)

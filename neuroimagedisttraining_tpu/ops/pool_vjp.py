@@ -24,11 +24,15 @@ tensor the conv fusion writes.
 The backward is one primitive with two lowerings, chosen where the program
 is lowered, by what the compiler can run there:
 
-* a Pallas kernel where the target is one TPU (Mosaic kernels cannot be
-  partitioned by GSPMD, and the mesh round is GSPMD over a vmapped client
-  axis) and its blocks fit VMEM;
-* ``lax.reduce_window``'s own VJP everywhere else (CPU, a clients mesh):
-  the program the parent compiled.
+* a Pallas kernel where the target is a TPU, no partitioner will touch the
+  op (GSPMD cannot partition a Mosaic kernel) and its blocks fit VMEM: a
+  program for one device, or the inside of a ``shard_map`` that is manual
+  over every mesh axis of more than one device, which is how the
+  clients-mesh round trains each chip's sites (``algorithms/base.py:
+  _train_clients``, ISSUE 29);
+* ``lax.reduce_window``'s own VJP everywhere else: the CPU, and whatever
+  GSPMD partitions (a vmapped client axis sharded over a mesh outside any
+  ``shard_map``, a ``space`` axis left automatic).
 
 Every spelling of the mask in XLA's own ops lost to ``select-and-scatter`` on
 the chip (PERF.md section 6 has each one's milliseconds): XLA fuses neither
@@ -47,7 +51,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax._src import dispatch, sharding_impls
-from jax.core import ShapedArray
 from jax.extend.core import Primitive
 from jax.interpreters import batching, mlir
 
@@ -156,9 +159,15 @@ def _kernel_vmem_bytes(shape, dtype, window) -> int:
     return 2 * 2 * window[0] * window[1] * w * tile
 
 
+def _vma(*avals):
+    """The manual mesh axes of an enclosing ``shard_map`` over which any of
+    ``avals`` varies: the result varies over them as the operands do."""
+    return frozenset().union(*(a.vma for a in avals))
+
+
 def _scatter_pallas(c, bias, m, g, *, window, interpret=False):
     # imported here: a second and a half that only a process which lowers
-    # the kernel should pay (no CPU run, no clients mesh does)
+    # the kernel should pay (no CPU run does)
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -180,7 +189,9 @@ def _scatter_pallas(c, bias, m, g, *, window, interpret=False):
         in_specs=[block, pl.BlockSpec((1, ch), lambda i, j: (0, 0)),
                   row, row],
         out_specs=block,
-        out_shape=jax.ShapeDtypeStruct((d, h, w, n, ch), c.dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (d, h, w, n, ch), c.dtype,
+            vma=_vma(*map(jax.typeof, (c, bias, m, g)))),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=min(_VMEM_BUDGET, max(
@@ -198,7 +209,8 @@ def _scatter_pallas(c, bias, m, g, *, window, interpret=False):
 _scatter_p = Primitive("max_pool_first_match_scatter")
 _scatter_p.def_impl(functools.partial(dispatch.apply_primitive, _scatter_p))
 _scatter_p.def_abstract_eval(
-    lambda c, bias, m, g, **_: ShapedArray(c.shape, c.dtype))
+    lambda c, bias, m, g, **_: c.update(weak_type=False,
+                                        vma=_vma(c, bias, m, g)))
 
 
 def first_match_scatter(c, bias, m, g, window):
@@ -227,14 +239,24 @@ def _lower_as(spelling):
     return rule
 
 
+def _unpartitioned(context) -> bool:
+    """Whether an op lowered under ``context`` reaches the compiler as it is:
+    the program is one device's, or every mesh axis of more than one device
+    is manual (the inside of a ``shard_map`` over all of them)."""
+    if isinstance(context, sharding_impls.ShardingContext):
+        return context.num_devices == 1
+    if isinstance(context, sharding_impls.SPMDAxisContext):
+        return all(size == 1 or name in context.manual_axes
+                   for name, size in context.mesh.shape.items())
+    return False
+
+
 def _lower_tpu(ctx, *args, window, batch_dims):
     c, _, m, _ = ctx.avals_in
-    context = ctx.module_context.axis_context
-    one_chip = (isinstance(context, sharding_impls.ShardingContext)
-                and context.num_devices == 1)
     fits = (min(m.shape) > 0 and 2 * _kernel_vmem_bytes(
         c.shape[batch_dims:], c.dtype, window) <= _VMEM_BUDGET)
-    spelling = _scatter_pallas if one_chip and fits else _scatter_xla
+    whole = _unpartitioned(ctx.module_context.axis_context)
+    spelling = _scatter_pallas if whole and fits else _scatter_xla
     return _lower_as(spelling)(ctx, *args, window=window,
                                batch_dims=batch_dims)
 

@@ -35,6 +35,7 @@ from neuroimagedisttraining_tpu.models.layers import max_pool3d
 from neuroimagedisttraining_tpu.models.resnet3d import S2DResNetStem
 from neuroimagedisttraining_tpu.ops import pool_vjp
 from neuroimagedisttraining_tpu.ops.s2d import phased_sample_shape
+from neuroimagedisttraining_tpu.parallel import make_mesh
 
 DTYPES = {"bf16": jnp.bfloat16, "f32": jnp.float32}
 # (D, H, W): with a floor-dropped remainder for window 3 / without one
@@ -253,6 +254,29 @@ def test_a_mesh_keeps_select_and_scatter_on_the_tpu():
         lambda a, b: jax.vmap(lambda ai, bi: ours(ai, bi, 3))(a, b),
         (c, bias), "tpu", shardings=(by_client, by_client))
     assert spelling(text) == "select_and_scatter"
+
+
+@pytest.mark.parametrize("space,want", [
+    (1, "tpu_custom_call"), (2, "select_and_scatter")],
+    ids=["clients_manual", "space_left_automatic"])
+def test_a_shard_map_over_every_sharded_axis_gives_the_kernel(space, want):
+    """The same vmapped use inside ``jax.shard_map`` over ``clients``: with
+    every mesh axis of more than one device manual no partitioner touches
+    the op, so the chip's own sites get the kernel (the clients-mesh round,
+    ``base.py:_train_clients``); a ``space`` axis of 2 that stays automatic
+    is GSPMD's to partition, and keeps XLA's op."""
+    mesh = make_mesh(2, space)
+    c = tied_input((2, 2, 11, 14, 11, 8), jnp.bfloat16)
+    bias = tied_input((2, 8), jnp.bfloat16, seed=3)
+    by_client = PartitionSpec("clients")
+    pooled = jax.shard_map(
+        jax.vmap(lambda ai, bi: ours(ai, bi, 3)), mesh=mesh,
+        in_specs=(by_client, by_client), out_specs=by_client,
+        axis_names={"clients"})
+    sharding = NamedSharding(mesh, by_client)
+    text = lowered_grad_text(pooled, (c, bias), "tpu",
+                             shardings=(sharding, sharding))
+    assert spelling(text) == want
 
 
 def test_blocks_too_large_for_vmem_keep_select_and_scatter():
