@@ -624,7 +624,7 @@ class FedAlgorithm(abc.ABC):
         copy of ``state``. Under ``donate_state`` every round/fused/
         finetune call CONSUMES its input state, so a caller that still
         needs the original afterwards — the watchdog's last-good, a
-        bench harness re-running from a saved state, an equivalence
+        harness re-running from a saved state, an equivalence
         gate replaying both spellings from one s0 — clones first and
         donates the clone (or donates the original and keeps the
         clone). A same-size copy when donation is off too, so caller
@@ -714,7 +714,7 @@ class FedAlgorithm(abc.ABC):
     def _agg_mesh(self):
         """The ``clients`` mesh the data lives on (None off-mesh), for the
         shard_map aggregation paths. Resolved once, lazily: the data is
-        placed before the algorithm is built (bench.py / the runner)."""
+        placed before the algorithm is built (the runner, the benchmark)."""
         if not self._agg_mesh_known:
             from ..parallel.mesh import mesh_of
 
@@ -802,7 +802,7 @@ class FedAlgorithm(abc.ABC):
     def _robust_wire(self) -> str:
         """The wire format whose decode the robust statistic must rank:
         the agg_impl's cross-chip payload format. f32 for the exact
-        impls (dense/bucketed/sparse are bit-equal contractions; topk
+        impls (dense/bucketed/sparse are the same f32 contraction; topk
         has its own sparsified-row path in :meth:`_topk_aggregate`)."""
         if self.agg_impl in ("bf16", "int8"):
             return self.agg_impl
@@ -1679,7 +1679,7 @@ class FedAlgorithm(abc.ABC):
         scan body lowers to a while-loop invariant that XLA must COPY
         into the loop's buffer space when the jit parameter cannot be
         aliased — the "second cohort copy" that OOMed the C=32 cell
-        (bench.py ``_try_fused``). As loop state returned unchanged, the
+        (RESULTS.md section 3). As loop state returned unchanged, the
         buffers alias in-place through the loop; with ``donate_state``
         the whole chain aliases — jit parameter -> loop state -> output
         (the program returns the threaded arrays, and

@@ -73,7 +73,7 @@ class SalientGrads(FedAlgorithm):
 
     def __init__(self, *args, dense_ratio: float = 0.5,
                  itersnip_iterations: int = 1, defense=None,
-                 fused_kernels: bool = False, snip_mask: bool = True,
+                 snip_mask: bool = True,
                  stratified_sampling: bool = False,
                  stratified_mode: str = "exact",
                  track_personal: bool = True,
@@ -82,7 +82,6 @@ class SalientGrads(FedAlgorithm):
         self.itersnip_iterations = itersnip_iterations
         # optional robust.RobustAggregator (fedml_core/robustness wiring)
         self.defense = defense
-        self.fused_kernels = fused_kernels
         # --snip_mask 0: all-ones mask, the reference's dense-control mode
         # (sailentgrads_api.py:91-103)
         self.snip_mask = snip_mask
@@ -112,7 +111,6 @@ class SalientGrads(FedAlgorithm):
             self.apply_fn, self.loss_type, self.hp,
             mask_grads=False, mask_params_post_step=True,
             remat=self.remat_local,
-            fused_kernels=self.fused_kernels,
             full_batches=self._full_batches(),
             augment_fn=self.augment_fn,
         )

@@ -12,7 +12,7 @@ that workflow-integrated analyzers with near-zero false positives are
 the ones that actually prevent regressions.
 
 Three analyzer families behind one ``scripts/lint_gate.py`` CLI
-(perf_gate-style exit codes: 0 clean / 1 findings / 2 config error):
+(exit codes: 0 clean / 1 findings / 2 config error):
 
 * :mod:`analysis.astlint` — AST trace-purity lint over the jit-path
   packages (host-sync and nondeterminism idioms inside traced code,

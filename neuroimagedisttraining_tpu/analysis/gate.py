@@ -1,5 +1,5 @@
 """Gate orchestration: run the analyzer families, apply the baseline,
-produce one verdict (perf_gate-style exit codes).
+produce one verdict (exit codes 0 clean / 1 findings / 2 config error).
 
 Exit codes: 0 clean (possibly via baseline suppressions), 1 findings,
 2 configuration error (unreadable baseline/ledger, unknown analyzer,

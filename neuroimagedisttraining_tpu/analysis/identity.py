@@ -278,8 +278,6 @@ FLAG_CLASSES: Dict[str, Tuple[str, str]] = {
                                  "(candidate for promotion)"),
     "snip_mask": ("unkeyed", "dense-control ablation, reference "
                              "identity omits it (use --tag)"),
-    "fused_kernels": ("unkeyed", "pallas kernel routing, measured "
-                                 "neutral; A/Bs use --tag"),
     "guard": ("unkeyed", "auto-follows fault_spec; bit-identical on "
                          "clean rounds — explicit --guard 0 chaos "
                          "ablations must use --tag (documented)"),

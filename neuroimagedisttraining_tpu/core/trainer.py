@@ -67,7 +67,6 @@ def make_client_update(
     mask_params_post_step: bool = True,
     prox_lambda: float = 0.0,
     remat: bool = False,
-    fused_kernels: bool = False,
     full_batches: bool = False,
     augment_fn: Callable = None,
 ):
@@ -82,8 +81,6 @@ def make_client_update(
     ``remat``: rematerialize the per-batch loss (activations recomputed in
     the backward pass) — trades FLOPs for HBM so more clients fit
     concurrently under the vmap (``client_chunk`` can rise).
-    ``fused_kernels``: route the optimizer update through the Pallas fused
-    masked-SGD kernel (ops/pallas_kernels.py) instead of the XLA chain.
     ``augment_fn``: jittable ``(rng, xb) -> xb`` training-time augmentation
     (e.g. :func:`data.cifar.random_crop_flip`), applied to every gathered
     training batch inside the scanned step — the device-side equivalent of
@@ -133,16 +130,6 @@ def make_client_update(
         carried = momentum is not None
         if not carried:
             momentum = grads    # a stand-in of the right shape, never read
-        if fused_kernels and not prox_lambda:
-            from ..ops.pallas_kernels import fused_masked_sgd_step
-
-            ones = mask if (mask_grads or mask_params_post_step) \
-                else jax.tree_util.tree_map(jnp.ones_like, params)
-            params, momentum = fused_masked_sgd_step(
-                params, momentum, grads, ones, lr,
-                momentum=hp.momentum, wd=hp.weight_decay,
-                mask_grads=mask_grads)
-            return params, (momentum if carried else None)
         if mask_grads:
             grads = jax.tree_util.tree_map(lambda g, m: g * m, grads, mask)
         params, momentum = sgd_momentum_step(

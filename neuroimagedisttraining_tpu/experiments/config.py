@@ -315,9 +315,8 @@ def build_parser(algo: Optional[str] = None) -> argparse.ArgumentParser:
                         "state (jit donate_argnums), so the [C, model] "
                         "personal stack (and topk residual / eval "
                         "cache) aliases in place instead of being "
-                        "re-allocated every call — the RESULTS.md "
-                        "Round-13 donation ledger's ~(1+C)-model/round "
-                        "rewrite drops to the trained slice. "
+                        "re-allocated every call — the ~(1+C)-model "
+                        "rewrite a round drops to the trained slice. "
                         "Bit-identical to 0 (aliasing only — never "
                         "enters run identity); drivers that re-run "
                         "from a saved state borrow via "
@@ -367,10 +366,6 @@ def build_parser(algo: Optional[str] = None) -> argparse.ArgumentParser:
                         "disk tier under 'disk', stays host-resident "
                         "under 'host'). Residency knob only — never "
                         "enters run identity")
-    p.add_argument("--fused_kernels", type=int, default=0,
-                   help="route the optimizer update through the Pallas "
-                        "fused masked-SGD kernel (salientgrads; measured "
-                        "neutral on AlexNet3D — see RESULTS.md)")
     p.add_argument("--remat", type=int, default=0,
                    help="rematerialize local-step activations (trades FLOPs "
                         "for HBM so --client_chunk can rise)")

@@ -481,7 +481,6 @@ def build_algorithm(args: argparse.Namespace, algo_name: str, data=None):
                          getattr(args, "stratified_sampling", 0)),
                      stratified_mode=getattr(args, "stratified_mode",
                                              "exact"),
-                     fused_kernels=bool(getattr(args, "fused_kernels", 0)),
                      track_personal=bool(
                          getattr(args, "track_personal", 1)),
                      eval_cache=bool(getattr(args, "eval_cache", 0)))
@@ -943,7 +942,7 @@ def run_experiment(args: argparse.Namespace,
             cat_path, cat_info = "", None
             if getattr(args, "obs_catalog", 1) and args.results_dir:
                 from ..obs import catalog as obs_catalog
-                from ..obs.regress import git_sha as _git_sha
+                from ..utils.records import git_sha as _git_sha
 
                 cat_path = obs_catalog.catalog_path(args.results_dir)
                 cat_info = {

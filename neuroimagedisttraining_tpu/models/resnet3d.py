@@ -13,7 +13,8 @@ reference reuses (``salient_models.py:13-81``).
 :class:`ResNet3DL3S2D` is the TPU-fast twin over phase-decomposed input —
 the r4 measurement found the stem stage (C_in=1 stride-2 conv + GN + relu
 + pool) is 66% of the step at full volume, the same disease the AlexNet3D
-path cured with the s2d + pool-first treatment (ops/s2d.py, RESULTS.md).
+path cured with the s2d + pool-first treatment (ops/s2d.py; RESULTS.md
+section 1).
 """
 from __future__ import annotations
 

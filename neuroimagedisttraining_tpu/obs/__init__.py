@@ -26,8 +26,6 @@ The ANALYSIS half — from recording to diagnosis (offline, CLI:
 * :mod:`~.health` — per-client/per-site ledger: participation and
   fault attribution via deterministic replay, per-site accuracy
   trajectories, degraded-site flags.
-* :mod:`~.regress` — noise-aware bench-trajectory regression detection
-  (``results/bench_history.jsonl``; CI gate: ``scripts/perf_gate.py``).
 * :mod:`~.compile` — compile-time observability: per-entry-point
   compile wall-time via ``jax.monitoring`` listeners, cache-hit
   counters, AOT ``cost_analysis()`` FLOPs/bytes.
@@ -108,7 +106,6 @@ from . import (
     metrics,
     numerics,
     recorder,
-    regress,
     report,
     slo,
     trace,
@@ -116,5 +113,4 @@ from . import (
 
 __all__ = ["analyze", "catalog", "comm", "compile", "devtrace",
            "diff", "events", "export", "health", "memory", "metrics",
-           "numerics", "recorder", "regress", "report", "slo",
-           "trace"]
+           "numerics", "recorder", "report", "slo", "trace"]

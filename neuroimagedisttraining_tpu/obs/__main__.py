@@ -21,12 +21,6 @@
     python -m neuroimagedisttraining_tpu.obs slo results/synthetic \
         [--slo_spec 'p99:round_time_s<2.5@w=20'] [--enforce] [--json]
 
-    # regression-gate a value against the bench history
-    # (scripts/perf_gate.py is the fuller CI surface)
-    python -m neuroimagedisttraining_tpu.obs regress --value 1.66 \
-        --metric salientgrads_rounds_per_sec_abcd_alexnet3d_8clients \
-        [--history results/bench_history.jsonl]
-
     # FLEET: list the run catalog (--rebuild rescans run dirs first —
     # the pre-catalog migration)
     python -m neuroimagedisttraining_tpu.obs ls results [--json] \
@@ -42,8 +36,7 @@
 
     # byte-deterministic static HTML fleet report from the catalog
     python -m neuroimagedisttraining_tpu.obs report results \
-        [--out results/fleet_report.html] \
-        [--history results/bench_history.jsonl]
+        [--out results/fleet_report.html]
 
     # cross-process causal trace: merge the per-process
     # *.xtrace.json streams of a --xtrace federation/serving run dir
@@ -68,8 +61,7 @@ Exit codes: analyze — 0 on success, 2 when the dir holds no streams;
 tail — 0 (interrupt to stop; --once prints what's there and exits,
 --all prints the newest line of every cataloged run, 2 when no stream
 resolves); slo — 0, 1 with --enforce when a replayed run ends
-FAILING, 2 when nothing replays; regress — the perf-gate codes (0
-pass, 1 regression, 2 no history); ls — 0, 2 when the catalog is
+FAILING, 2 when nothing replays; ls — 0, 2 when the catalog is
 empty and nothing rescans; diff — 0 when the --expect expectation
 holds (or no expectation), 1 when it is violated, 2 when a run fails
 to load; report — 0, 2 when the catalog resolves empty; xtrace — 0,
@@ -420,7 +412,6 @@ def fleet_diff_cli(target_a: str, target_b: str,
 
 
 def fleet_report_cli(target: str, out_path: str = "",
-                     history: str = "",
                      out: Callable[[str], None] = print) -> int:
     """``obs report``: render the static HTML fleet report from the
     catalog. Exit 2 when the catalog resolves empty."""
@@ -438,10 +429,7 @@ def fleet_report_cli(target: str, out_path: str = "",
         return 2
     out_path = out_path or os.path.join(results_dir,
                                         "fleet_report.html")
-    history = history or os.path.join(results_dir,
-                                      "bench_history.jsonl")
     written = obs_report.write_report(out_path, path,
-                                      history_path=history,
                                       results_dir=results_dir)
     out(f"fleet report -> {written}")
     return 0
@@ -644,12 +632,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     help="print the summary JSON instead of the "
                          "report")
 
-    pr = sub.add_parser("regress", help="bench-history regression gate")
-    pr.add_argument("--history", default="results/bench_history.jsonl")
-    pr.add_argument("--metric", required=True)
-    pr.add_argument("--value", type=float, required=True)
-    pr.add_argument("--lower-is-better", action="store_true")
-
     pl = sub.add_parser("ls", help="list the run catalog")
     pl.add_argument("target", nargs="?", default="results",
                     help="results dir (its runs_index.jsonl) or a "
@@ -687,9 +669,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     pp.add_argument("--out", default="",
                     help="output path (default "
                          "<results_dir>/fleet_report.html)")
-    pp.add_argument("--history", default="",
-                    help="bench history for the rounds/sec scatter "
-                         "(default <results_dir>/bench_history.jsonl)")
 
     pw = sub.add_parser(
         "watch", help="live fleet dashboard (heartbeat ledger lanes)")
@@ -795,33 +774,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                               expect=args.expect, as_json=args.json,
                               metrics=args.metrics)
 
-    if args.cmd == "report":
-        return fleet_report_cli(args.target, out_path=args.out,
-                                history=args.history)
-
-    from . import regress as obs_regress
-
-    # mirror scripts/perf_gate.py so the two regress surfaces cannot
-    # disagree on a verdict: the same per-metric defaults (comm SLO
-    # metrics are lower-is-better with their own band), the same
-    # fresh-clone auto-backfill of the default history from the
-    # committed MULTICHIP_r* artifacts, and the same
-    # own-commit exclusion (a rerun's just-appended measurement must
-    # not join its own baseline)
-    if not os.path.exists(args.history) and \
-            args.history == "results/bench_history.jsonl":
-        obs_regress.backfill_multichip_files(os.getcwd(), args.history)
-    defaults = obs_regress.metric_gate_defaults(args.metric)
-    verdict = obs_regress.gate(
-        args.history, args.metric, args.value,
-        rel_threshold=defaults.get(
-            "rel_threshold", obs_regress.DEFAULT_REL_THRESHOLD),
-        mad_k=defaults.get("mad_k", obs_regress.DEFAULT_MAD_K),
-        higher_is_better=(not args.lower_is_better
-                          and defaults.get("higher_is_better", True)),
-        exclude_git_sha=obs_regress.git_sha())
-    print(json.dumps(verdict))
-    return int(verdict["exit_code"])
+    # "report": the subcommand is required, so nothing else gets here
+    return fleet_report_cli(args.target, out_path=args.out)
 
 
 if __name__ == "__main__":

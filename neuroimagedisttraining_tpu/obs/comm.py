@@ -1,9 +1,6 @@
 """Communication observability: the analytical wire-cost model.
 
-At scale-32 on the 8-device mesh, aggregation is 55.8% of the round
-(MULTICHIP_r05) — and until now nothing could say where those bytes and
-milliseconds go. This module prices the cross-chip aggregation wire
-*analytically*, per ``agg_impl`` and per top-level leaf group, so every
+This module prices the cross-chip aggregation wire *analytically*, per ``agg_impl`` and per top-level leaf group, so every
 round's JSONL line carries the modeled bytes-on-the-wire, the analyzer
 (schema v3 ``comm`` section) can report measured-vs-modeled efficiency,
 and the what-if table projects every alternative wire at the live mask
@@ -417,9 +414,7 @@ def probe_aggregate(algo, state: Any = None, iters: int = 4,
     materialized twice):
 
     * ``agg_ms`` (``timing``) — wall ms per aggregation via
-      ``collectives.time_weighted_agg``, the SAME harness
-      ``agg_microbench`` uses, so the probed number is methodology-
-      comparable to the gated ``agg_ms_*`` bench history;
+      ``collectives.time_weighted_agg``;
     * ``flops`` / ``bytes_accessed`` / ``compile_s`` (``cost``) — AOT
       ``jit_cost_analysis`` of a single-agg program: the no-trace side
       of the devtrace fallback (``share_from_cost_analysis`` consumes

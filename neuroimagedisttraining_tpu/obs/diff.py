@@ -15,7 +15,7 @@ through, diffing two recorded runs on three planes:
   deduped streams: the first-bit-divergence round (exact float
   inequality — the determinism contracts are BIT contracts), the
   max abs delta, and a MAD-band significance verdict on overlapping
-  rounds (the obs/regress.py noise model) for when bit equality is
+  rounds (a median/MAD noise model) for when bit equality is
   not expected. Volatile keys (wall times, memory watermarks, probed
   agg timings) never count: they differ across bit-identical runs.
 * **event/health** — event-sequence diff keyed ``(round, type)`` (the

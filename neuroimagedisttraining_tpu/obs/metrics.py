@@ -1,8 +1,8 @@
 """Typed metrics registry: counters, gauges, streaming distributions.
 
 One registry per run (the runner's ObsSession owns it; a process-global
-default serves library callers like ``parallel.collectives`` and
-``bench.py``). Three metric types:
+default serves library callers with no run context). Three metric
+types:
 
 * :class:`Counter` — monotone accumulator (``inc``).
 * :class:`Gauge` — last-value-wins (``set``), e.g. HBM watermarks.
@@ -40,7 +40,7 @@ __all__ = [
 def median(xs) -> float:
     """Exact median of a non-empty sequence (shared by the analysis
     layer's robust statistics — obs/analyze.py outlier flags and
-    obs/regress.py noise bands must not drift apart)."""
+    obs/diff.py noise bands must not drift apart)."""
     s = sorted(xs)
     n = len(s)
     if not n:
@@ -226,8 +226,8 @@ class Distribution(_Metric):
 class _TimerHandle:
     """Handle returned by ``Registry.timer``: after the ``with`` block,
     ``elapsed`` holds the section's wall seconds (also observed into the
-    backing distribution) — callers like ``bench.py`` read their section
-    timing from the registry through it."""
+    backing distribution) — callers read their section timing from the
+    registry through it."""
 
     __slots__ = ("elapsed",)
 
@@ -325,9 +325,9 @@ class SectionTimer:
 
 
 # -- process-global default registry ------------------------------------
-# Library callers with no run context (collectives' agg micro-bench,
-# bench.py's section timers) record here; the runner's ObsSession uses
-# its OWN registry so per-run metrics.json never mixes runs.
+# Library callers with no run context record here; the runner's
+# ObsSession uses its OWN registry so per-run metrics.json never mixes
+# runs.
 
 _default = MetricsRegistry()
 

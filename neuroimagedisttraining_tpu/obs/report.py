@@ -15,9 +15,7 @@ cataloged run:
   (a subdir holding ``aggregator.jsonl`` + ``site<k>.jsonl``
   per-process streams — these live outside the catalog) renders one
   row per process: rounds, loss/wall sparklines, straggle counts,
-  and whether a clock-aligned ``federation.trace.json`` was merged;
-* a cross-run SCATTER (rounds/sec vs cohort size) from the bench
-  history (``results/bench_history.jsonl``).
+  and whether a clock-aligned ``federation.trace.json`` was merged.
 
 The report is a PURE function of its inputs: no timestamps (the
 events-stream convention), every iteration sorted, every float
@@ -29,15 +27,14 @@ from __future__ import annotations
 
 import html as _html
 import os
-import re
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 from .catalog import read_catalog
 from .export import dedupe_rounds, read_jsonl
 
 __all__ = [
     "REPORT_SCHEMA_VERSION", "build_report", "find_fed_dirs",
-    "load_fed_lanes", "load_runs", "scatter_points", "write_report",
+    "load_fed_lanes", "load_runs", "write_report",
 ]
 
 #: stamped in the report header (a report consumer's compat check)
@@ -210,60 +207,6 @@ def _fed_lane_rows(fed: Dict[str, Any]) -> List[str]:
     return rows
 
 
-def scatter_points(history: List[Dict[str, Any]]
-                   ) -> List[Tuple[str, int, float]]:
-    """(metric, cohort size, rounds/sec) points from the bench
-    history: every ``*rounds_per_sec*`` metric whose name carries a
-    ``_<N>clients`` cohort tag, keep-last per metric (the history is
-    append-only), sorted."""
-    last: Dict[str, Tuple[str, int, float]] = {}
-    for rec in history:
-        metric = str(rec.get("metric", ""))
-        v = rec.get("value")
-        if "rounds_per_sec" not in metric or \
-                not isinstance(v, (int, float)):
-            continue
-        m = re.search(r"_(\d+)clients", metric)
-        if not m:
-            continue
-        last[metric] = (metric, int(m.group(1)), float(v))
-    return [last[k] for k in sorted(last)]
-
-
-def _scatter_svg(points: List[Tuple[str, int, float]],
-                 width: int = 420, height: int = 220) -> str:
-    if not points:
-        return "<p>no rounds/sec bench points with a cohort tag</p>"
-    xs = [p[1] for p in points]
-    ys = [p[2] for p in points]
-    x_lo, x_hi = min(xs), max(xs)
-    y_lo, y_hi = min(ys), max(ys)
-    x_span = (x_hi - x_lo) or 1
-    y_span = (y_hi - y_lo) or 1.0
-    dots = []
-    for metric, x, y in points:
-        px = 40 + (width - 60) * (x - x_lo) / x_span
-        py = height - 30 - (height - 50) * (y - y_lo) / y_span
-        dots.append(
-            f'<circle cx="{px:.1f}" cy="{py:.1f}" r="4" '
-            f'fill="#0969da" fill-opacity="0.7">'
-            f'<title>{_html.escape(metric, quote=True)}: '
-            f'{x} clients, {_fmt(y)} rounds/s</title></circle>')
-    return (
-        f'<svg width="{width}" height="{height}" '
-        f'viewBox="0 0 {width} {height}">'
-        f'<line x1="40" y1="{height - 30}" x2="{width - 10}" '
-        f'y2="{height - 30}" stroke="#6e7781"/>'
-        f'<line x1="40" y1="10" x2="40" y2="{height - 30}" '
-        f'stroke="#6e7781"/>'
-        f'<text x="{width // 2}" y="{height - 8}" class="ax">'
-        f'cohort size (clients): {x_lo} .. {x_hi}</text>'
-        f'<text x="12" y="{height // 2}" class="ax" '
-        f'transform="rotate(-90 12 {height // 2})">rounds/sec: '
-        f'{_fmt(y_lo)} .. {_fmt(y_hi)}</text>'
-        + "".join(dots) + "</svg>")
-
-
 _CSS = """
 body{font:13px/1.45 -apple-system,'Segoe UI',sans-serif;margin:24px;
      color:#1f2328}
@@ -276,7 +219,6 @@ code{background:#f6f8fa;padding:1px 4px;border-radius:3px;
      font-size:12px}
 .tl i{display:inline-block;width:7px;height:14px;margin-right:1px}
 .tl i.ev{outline:1.5px solid #1f2328}
-.ax{font-size:11px;fill:#57606a}
 .muted{color:#57606a}
 svg.spark{vertical-align:middle}
 """
@@ -284,13 +226,11 @@ svg.spark{vertical-align:middle}
 
 def build_report(entries: List[Dict[str, Any]],
                  runs: Optional[Dict[str, Dict[str, Any]]] = None,
-                 history: Optional[List[Dict[str, Any]]] = None,
                  fed_runs: Optional[List[Dict[str, Any]]] = None
                  ) -> str:
     """The full fleet report HTML (a pure function of its inputs —
     the byte-determinism contract)."""
     runs = runs if runs is not None else load_runs(entries)
-    history = history or []
     rows = []
     wire_rows = []
     for e in entries:
@@ -355,7 +295,6 @@ def build_report(entries: List[Dict[str, Any]],
             wire_rows.append(
                 f"<tr><td><code>{_html.escape(key, quote=True)}"
                 f"</code></td><td>{_fmt(agg)}</td>{cells}</tr>")
-    points = scatter_points(history)
     parts = [
         "<!DOCTYPE html>",
         '<html lang="en"><head><meta charset="utf-8">',
@@ -401,30 +340,19 @@ def build_report(entries: List[Dict[str, Any]],
                 + ("".join(rows)
                    or '<tr><td colspan="5">no lanes</td></tr>')
                 + "</table>")
-    parts.append("<h2>Rounds/sec vs cohort size "
-                 '<span class="muted">(bench history)</span></h2>')
-    parts.append(_scatter_svg(points))
     parts.append("</body></html>")
     return "\n".join(parts) + "\n"
 
 
 def write_report(out_path: str, catalog: str,
-                 history_path: str = "",
                  results_dir: str = "") -> str:
-    """Read the catalog (+ optional bench history + federation run
-    dirs under ``results_dir``, default: the catalog's own dir),
-    render, write. Returns ``out_path``."""
+    """Read the catalog (+ federation run dirs under ``results_dir``,
+    default: the catalog's own dir), render, write. Returns
+    ``out_path``."""
     entries = read_catalog(catalog)
-    history: List[Dict[str, Any]] = []
-    if history_path and os.path.exists(history_path):
-        try:
-            history = read_jsonl(history_path, allow_partial_tail=True)
-        except ValueError:
-            history = []
     results_dir = results_dir or (os.path.dirname(catalog) or ".")
     fed_runs = [load_fed_lanes(d) for d in find_fed_dirs(results_dir)]
-    html_text = build_report(entries, history=history,
-                             fed_runs=fed_runs)
+    html_text = build_report(entries, fed_runs=fed_runs)
     d = os.path.dirname(out_path)
     if d:
         os.makedirs(d, exist_ok=True)

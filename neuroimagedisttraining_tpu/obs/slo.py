@@ -1,8 +1,8 @@
 """Online SLO engine: declarative objectives, streaming estimators,
 error budgets, and the run-health state machine.
 
-Everything diagnostic built so far is post-hoc (``obs/analyze.py`` runs
-after the run; ``perf_gate.py`` gates *between* runs). This module
+Everything else diagnostic is post-hoc (``obs/analyze.py`` runs after
+the run). This module
 closes the loop **in-run**: a declarative SLO spec is evaluated
 incrementally at the ``ObsSession`` record hook with O(1)-memory
 streaming estimators, SRE-style error budgets with fast/slow
@@ -151,7 +151,10 @@ class P2Quantile:
     """The P² streaming quantile (Jain & Chhabra 1985): five markers,
     O(1) memory regardless of stream length — the ``w=0`` (whole-run)
     estimator. Exact until five observations, then the classic
-    piecewise-parabolic marker update. Deterministic: no sampling."""
+    piecewise-parabolic marker update. Deterministic: no sampling.
+    Accurate on streams whose order carries no trend; for any order only
+    the hull and the markers' order are guaranteed (an ordered stream
+    can leave the estimate ranks away: tests/test_slo_estimators.py)."""
 
     def __init__(self, q: float):
         if not (0.0 < q < 1.0):

@@ -1,21 +1,17 @@
-"""Pallas TPU kernels for the framework's hot elementwise chains.
+"""Pallas TPU kernels of the ``--agg_kernels pallas`` leg.
 
-The hottest non-matmul op in every sparse-FL round is the masked optimizer
-update (``my_model_trainer.py:207-216``: SGD momentum + weight decay + post-
-step ``param *= mask``). Left to XLA this is a chain of small elementwise
-kernels *per pytree leaf*; the fused Pallas kernel below does the whole
-update — momentum accumulate, decayed step, mask projection — in ONE pass
-over HBM per leaf: 4 reads (p, m, g, mask) + 2 writes (p', m').
+The threshold top-k selection, the fused int8 quantize+reduce of the
+low-precision wire, and the mask projections of the SalientGrads init
+(``fused_mask_apply``, ``fused_score_mask_leaf``): each the Pallas backend
+of an XLA spelling it is bit-identical to (``ops/topk_select.py``,
+``parallel/collectives.py``).
 
-A second kernel fuses DisPFL-style masked-gradient SGD (mask applied to the
-gradient *before* the momentum accumulate, ``DisPFL/my_model_trainer.py:
-147-172``).
-
-Layout: each leaf is raveled and padded to (rows, 128) float32 — the VPU
-lane width; rows are padded to the (8, 128) f32 tile. On the ``cpu``
-backend the kernels run in interpreter mode so the tests exercise identical
-code; on a TPU they compile through Mosaic (``pytest -m tpu`` pins every
-kernel at the flagship's shapes); any other backend is an error.
+Layout: an elementwise kernel ravels its leaf and pads it to (rows, 128)
+float32 — the VPU lane width; rows are padded to the (8, 128) f32 tile. On
+the ``cpu`` backend the kernels run in interpreter mode so the tests
+exercise identical code; on a TPU they compile through Mosaic (``pytest -m
+tpu`` pins every kernel at the flagship's shapes); any other backend is an
+error.
 """
 from __future__ import annotations
 
@@ -44,14 +40,11 @@ def _interpret() -> bool:
     raise RuntimeError(
         f"the pallas kernels target TPU (interpreted on cpu for tests); "
         f"backend {backend!r} has no lowering for them — use the XLA "
-        "spellings (--agg_kernels xla, --fused_kernels 0)")
+        "spelling (--agg_kernels xla)")
 
 
 def _to_2d(x: jax.Array) -> Tuple[jax.Array, int]:
-    """Ravel + zero-pad to a (rows, LANES) f32 panel; rows % SUBLANES == 0.
-
-    vmap over a leading axis to panel a batch per-element (each element
-    padded independently — see fused_weighted_sum_leaf)."""
+    """Ravel + zero-pad to a (rows, LANES) f32 panel; rows % SUBLANES == 0."""
     flat = x.ravel()
     n = flat.shape[0]
     per_panel = LANES * SUBLANES
@@ -71,129 +64,6 @@ def _pick_block_rows(rows: int, budget: int = _BLOCK_ROWS) -> int:
 
 def _from_2d(panel: jax.Array, n: int, shape, dtype) -> jax.Array:
     return panel.ravel()[:n].reshape(shape).astype(dtype)
-
-
-def _masked_sgd_kernel(lr_ref, p_ref, m_ref, g_ref, mask_ref,
-                       p_out, m_out, *, momentum: float, wd: float,
-                       mask_grads: bool):
-    lr = lr_ref[0]
-    g = g_ref[:]
-    if mask_grads:
-        g = g * mask_ref[:]
-    g = g + wd * p_ref[:]
-    m_new = momentum * m_ref[:] + g
-    p_new = p_ref[:] - lr * m_new
-    if not mask_grads:
-        p_new = p_new * mask_ref[:]
-    p_out[:] = p_new
-    m_out[:] = m_new
-
-
-@functools.partial(jax.jit, static_argnames=("momentum", "wd", "mask_grads"))
-def fused_masked_sgd_leaf(p, m, g, mask, lr, momentum: float = 0.0,
-                          wd: float = 0.0, mask_grads: bool = False):
-    """One leaf's fused update. ``mask_grads=False`` -> SalientGrads
-    semantics (post-step ``p *= mask``); ``True`` -> DisPFL masked-gradient
-    SGD. Returns (p_new, m_new) with the leaf's original shape/dtype."""
-    shape, dtype = p.shape, p.dtype
-    p2, n = _to_2d(p.astype(jnp.float32))
-    m2, _ = _to_2d(m.astype(jnp.float32))
-    g2, _ = _to_2d(g.astype(jnp.float32))
-    k2, _ = _to_2d(mask.astype(jnp.float32))
-    rows = p2.shape[0]
-    block_rows = _pick_block_rows(rows)
-    grid = (rows // block_rows,)
-
-    vmem_spec = pl.BlockSpec(
-        (block_rows, LANES), lambda i: (i, 0), memory_space=pltpu.VMEM)
-    kernel = functools.partial(
-        _masked_sgd_kernel, momentum=momentum, wd=wd, mask_grads=mask_grads)
-    p_new, m_new = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # lr scalar
-            vmem_spec, vmem_spec, vmem_spec, vmem_spec,
-        ],
-        out_specs=[vmem_spec, vmem_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct(p2.shape, jnp.float32),
-            jax.ShapeDtypeStruct(m2.shape, jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(jnp.asarray(lr, jnp.float32).reshape(1), p2, m2, g2, k2)
-    # momentum keeps its own dtype (f32 buffers stay f32 under bf16 params)
-    return (_from_2d(p_new, n, shape, dtype),
-            _from_2d(m_new, n, shape, m.dtype))
-
-
-def fused_masked_sgd_step(params: Any, momentum_tree: Any, grads: Any,
-                          mask: Any, lr, momentum: float = 0.0,
-                          wd: float = 0.0, mask_grads: bool = False
-                          ) -> Tuple[Any, Any]:
-    """Pytree-level fused update (drop-in for optim.sgd_momentum_step +
-    mask projection)."""
-    flat_p, treedef = jax.tree_util.tree_flatten(params)
-    flat_m = treedef.flatten_up_to(momentum_tree)
-    flat_g = treedef.flatten_up_to(grads)
-    flat_k = treedef.flatten_up_to(mask)
-    out_p, out_m = [], []
-    for p, m, g, k in zip(flat_p, flat_m, flat_g, flat_k):
-        p2, m2 = fused_masked_sgd_leaf(
-            p, m, g, k, lr, momentum=momentum, wd=wd, mask_grads=mask_grads)
-        out_p.append(p2)
-        out_m.append(m2)
-    return (jax.tree_util.tree_unflatten(treedef, out_p),
-            jax.tree_util.tree_unflatten(treedef, out_m))
-
-
-# -- fused weighted aggregation ----------------------------------------------
-
-def _wsum_kernel(w_ref, x_ref, out_ref):
-    """out = sum_c w[c] * x[c] for one (clients, block, LANES) tile."""
-    x = x_ref[:]                       # (C, block_rows, LANES)
-    acc = jnp.zeros(x.shape[1:], jnp.float32)
-    for c in range(x.shape[0]):        # static unroll over clients
-        acc = acc + w_ref[c] * x[c]    # scalar SMEM load per client
-    out_ref[:] = acc
-
-
-@jax.jit
-def fused_weighted_sum_leaf(stacked: jax.Array, weights: jax.Array):
-    """Sample-weighted FedAvg reduction over a leading client axis in one
-    HBM pass (the `psum` in fedavg_api.py:102-117), fused across the whole
-    leaf instead of C separate scale+add kernels."""
-    c = stacked.shape[0]
-    shape = stacked.shape[1:]
-    dtype = stacked.dtype
-    flat = stacked.reshape(c, -1).astype(jnp.float32)
-    n = flat.shape[1]
-    panels = jax.vmap(lambda v: _to_2d(v)[0])(flat)  # per-client pad + panel
-    rows = panels.shape[1]
-    # the input block is (c, block_rows, LANES): shrink block_rows by the
-    # client count so VMEM stays ~_BLOCK_ROWS*LANES*4B regardless of c
-    block_rows = _pick_block_rows(rows, max(SUBLANES, _BLOCK_ROWS // max(c, 1)))
-    grid = (rows // block_rows,)
-
-    out = pl.pallas_call(
-        _wsum_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((c, block_rows, LANES), lambda i: (0, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
-        interpret=_interpret(),
-    )(weights.astype(jnp.float32), panels)
-    return out.ravel()[:n].reshape(shape).astype(dtype)
-
-
-def fused_weighted_sum(stacked_tree: Any, weights: jax.Array) -> Any:
-    return jax.tree_util.tree_map(
-        lambda x: fused_weighted_sum_leaf(x, weights), stacked_tree)
 
 
 # -- threshold top-k selection (the --agg_kernels wire leg) -------------------

@@ -1,8 +1,7 @@
 """Threshold-refinement top-k selection — the wire's shared selection core.
 
-``jax.lax.top_k`` is SORT-bound on XLA:CPU: the scale-32 exact topk
-aggregate measured 26.7 s/agg at ANY density (RESULTS Round-12), which
-is why ``--agg_topk_sample`` existed at all. But the selection never
+``jax.lax.top_k`` is SORT-bound in row length at ANY density, which is
+why ``--agg_topk_sample`` existed at all. But the selection never
 needed the sorted ORDER — only the k-th largest magnitude, used as a
 threshold. This module computes that threshold exactly in O(n) passes
 with no data-dependent memory traffic, by refining a cut over the f32

@@ -1,22 +1,24 @@
 """Communication-efficient cross-chip aggregation collectives.
 
 The dense federated aggregate (``core.state.weighted_tree_sum``) moves every
-f32 parameter across ICI every round as ONE monolithic contraction — at the
-scale-32 dry-run configuration that is 55.8% of the round (MULTICHIP_r05).
-This module is the ``agg`` subsystem that shrinks and overlaps that transfer,
-three composable levers behind one ``weighted_mean`` surface:
+f32 parameter across ICI every round as ONE monolithic contraction. On the
+chip that is 0.09-0.23 ms of a 668-1,573 ms round at 2.57 M parameters and
+33 ms of 1,801 at 568 M (``aggregate_ms_per_round``, PERF.md section 5), so
+nothing here is on a measured critical path yet (ROADMAP D6). This module is
+the ``agg`` subsystem that shrinks and overlaps that transfer, composable
+levers behind one ``weighted_mean`` surface:
 
 * **bucketed** — per-leaf local partials inside ``shard_map``, reduced by
   ONE multi-operand ``psum`` per fixed-size bucket, so XLA can pipeline
   bucket k's collective against bucket k+1's local compute (and against
   the tail of local training) instead of one serialized all-reduce
   barrier. Bucket boundaries snap to leaf boundaries of the
-  ``vectorize_weights`` flattening order: measured on the scale-32
-  CPU-mesh dry-run, flattening-into-buckets costs a full extra copy of
-  the cohort matrix (the copy, not the reduce, dominated) while whole-
-  leaf groups cost nothing. Off-mesh the bucketed contraction is
-  element-for-element the dense one — bit-equal
-  (tests/test_collectives.py).
+  ``vectorize_weights`` flattening order: flattening into buckets costs
+  a full extra copy of the cohort matrix while whole-leaf groups cost
+  nothing. Off-mesh the bucketed contraction is element-for-element
+  the dense one: bit-equal op by op, and within a few ulp inside a
+  jitted round, where XLA associates a [C, N_total] bucket and a
+  [C, n_leaf] dot in different orders (tests/test_collectives.py).
 * **low-precision wire** — per-device f32 local partials are cast to bf16
   (or stochastic-rounded int8 with a per-bucket scale) for the cross-chip
   hop and accumulated in f32 on every receiver (``all_gather`` of the
@@ -62,8 +64,9 @@ three composable levers behind one ``weighted_mean`` surface:
   scheduler can pipeline wire against compute (and, in the fused scan
   path, against the tail of local training that produces later groups'
   leaves). Scheduling-only: per-bucket math is bit-identical either
-  way, so the knob never enters run identity. Verified via
-  ``obs/devtrace.py``'s collective-vs-compute interval overlap.
+  way, so the knob never enters run identity. What overlap it buys on
+  a chip is not measured (``obs/devtrace.py`` reduces a trace to the
+  collective-vs-compute overlap; the one four-chip cell runs ``dense``).
 
 Everything is jit-traceable and composes with the Byzantine-robust defenses
 (``robust.aggregation`` transforms the stacked locals BEFORE aggregation, so
@@ -658,8 +661,9 @@ def _reduce_mat(mat: jax.Array, weights: jax.Array, *,
                 wire: str = "f32", rng: Optional[jax.Array] = None,
                 kernels: str = "xla") -> jax.Array:
     """Off-mesh reduce: out[j] = sum_c weights[c] * mat[c, j] in bucket
-    layout — element-for-element the dense reduction (bit-equal for
-    ``wire='f32'``; the wire casts apply per client since there is no
+    layout — element-for-element the dense reduction (for
+    ``wire='f32'`` bit-equal as an op of its own, a few ulp apart where
+    XLA fuses the two into different programs; the wire casts apply per client since there is no
     per-device partial to cast).
 
     ``kernels='pallas'`` routes the int8 wire through the fused
@@ -804,7 +808,8 @@ def weighted_mean(stacked: Any, weights: jax.Array, *, mesh=None,
     """Weighted mean over the leading client axis, via the bucketed
     (optionally low-precision-wire) reduce. Drop-in for
     ``core.state.weighted_tree_sum`` (callers pass already-normalized
-    weights); ``wire='f32'`` off-mesh is bit-equal to it. With a usable
+    weights); ``wire='f32'`` off-mesh is the same sum (see
+    :func:`_reduce_mat` for how far "same" goes). With a usable
     ``clients`` mesh the whole reduce runs inside ``shard_map`` on
     per-leaf local partials with one collective per leaf-group bucket —
     the [C, N] client matrix is never materialized.
@@ -916,15 +921,14 @@ def masked_weighted_mean(stacked: Any, weights: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# micro-bench
+# timing harness
 # ---------------------------------------------------------------------------
 
 def time_weighted_agg(agg_fn, stacked: Any, weights: jax.Array,
                       out_template: Any, iters: int = 8) -> float:
     """Wall-clock seconds per aggregation of ``agg_fn(stacked,
-    weights, i)`` — THE timing harness for aggregation paths (shared
-    by :func:`agg_microbench` and obs/comm.py's ``probe_agg_ms``, so
-    probed and benched numbers stay methodology-comparable): an
+    weights, i)`` — the timing harness of obs/comm.py's
+    ``probe_agg_ms`` (``--obs_comm``): an
     in-graph ``fori_loop`` over ``iters`` calls with ``jnp.roll``-ed
     weights so XLA cannot hoist the contraction, accumulated into an
     ``out_template``-shaped f32 tree, timed after one compile+warmup
@@ -947,136 +951,3 @@ def time_weighted_agg(agg_fn, stacked: Any, weights: jax.Array,
     out = run(stacked, weights)
     float(jax.tree_util.tree_leaves(out)[0].sum())
     return (time.perf_counter() - t0) / iters
-
-
-def agg_microbench(mesh=None, n_clients: int = 32, iters: int = 8,
-                   dense_ratio: float = 0.5,
-                   bucket_size: int = DEFAULT_BUCKET_SIZE,
-                   model_key: str = "3dcnn",
-                   sample_shape: Tuple[int, ...] = (121, 145, 121, 1),
-                   impls: Tuple[str, ...] = AGG_IMPLS,
-                   topk_density: float = 0.1, topk_sample: int = 0,
-                   hier_inner: int = 0, hier_wire: str = "bf16",
-                   overlap: bool = True, kernels: str = "xla") -> dict:
-    """Time one weighted-mean aggregation per ``agg_impl`` on the flagship
-    parameter tree stacked over ``n_clients`` (honored-mask locals at
-    ``dense_ratio``), sharded over ``mesh`` when given. Methodology
-    follows ``__graft_entry__._agg_realparams_probe``: in-graph
-    ``fori_loop`` bodies with ``jnp.roll``-ed weights so XLA cannot hoist
-    the contraction, timed over ``iters`` aggregations after a
-    compile+warmup run. Returns ``{"agg_ms_<impl>": ms, ...}`` plus, per
-    timed impl, the ``obs.comm.WireCostModel``'s modeled per-device wire
-    bytes as ``wire_bytes_<impl>`` (so the gated bench history tracks
-    time AND bytes together) and the workload descriptors.
-
-    ``kernels`` picks the selection/quantize backend for the impls that
-    have one (int8, topk, hier) — the flag surface plus the internal
-    ``'sort'`` legacy spelling, so the bench can still price the
-    pre-threshold sort baseline the kernel leg replaced."""
-    from ..core.state import weighted_tree_sum
-    from ..models import create_model, init_params
-    from ..ops.sparsity import kernel_flags
-    from ..ops.topk_select import check_kernels
-
-    check_kernels(kernels)
-
-    model = create_model(model_key, num_classes=1)
-    shapes = jax.eval_shape(
-        lambda k: init_params(model, k, sample_shape), jax.random.PRNGKey(0))
-    leaves, treedef = jax.tree_util.tree_flatten(shapes)
-    n_params = sum(int(np.prod(l.shape)) for l in leaves)
-
-    sharding = None
-    if mesh is not None and "clients" in mesh.axis_names:
-        from jax.sharding import NamedSharding
-
-        sharding = NamedSharding(mesh, P("clients"))
-
-    def put(x):
-        return x if sharding is None else jax.device_put(x, sharding)
-
-    # honored-mask stacked locals: a host-random SNIP-style mask at
-    # dense_ratio on kernel leaves, applied to every client's tree
-    flags = jax.tree_util.tree_leaves(
-        kernel_flags(jax.tree_util.tree_unflatten(treedef, leaves)))
-    rs = np.random.RandomState(0)
-    key = jax.random.PRNGKey(0)
-    mask_leaves, stacked_leaves = [], []
-    for i, (l, k) in enumerate(zip(leaves, flags)):
-        m = (rs.rand(*l.shape) < dense_ratio).astype(np.float32) \
-            if k else np.ones(l.shape, np.float32)
-        mask_leaves.append(jnp.asarray(m))
-        x = jax.random.normal(jax.random.fold_in(key, i),
-                              (n_clients,) + tuple(l.shape),
-                              jnp.float32) * 0.01
-        stacked_leaves.append(put(x * m[None]))
-    mask = jax.tree_util.tree_unflatten(treedef, mask_leaves)
-    stacked = jax.tree_util.tree_unflatten(treedef, stacked_leaves)
-    w = rs.rand(n_clients).astype(np.float32)
-    w = put(jnp.asarray(w / w.sum()))
-    plan = build_sparse_plan(mask)
-
-    kw = dict(mesh=mesh, bucket_size=bucket_size, overlap=overlap)
-    hw = "f32" if hier_wire == "sparse" else hier_wire
-    agg_fns = {
-        "dense": lambda st, wv, i: weighted_tree_sum(st, wv),
-        "bucketed": lambda st, wv, i: weighted_mean(st, wv, wire="f32",
-                                                    **kw),
-        "bf16": lambda st, wv, i: weighted_mean(st, wv, wire="bf16", **kw),
-        "int8": lambda st, wv, i: weighted_mean(
-            st, wv, wire="int8", rng=jax.random.fold_in(key, i),
-            kernels=kernels, **kw),
-        "sparse": lambda st, wv, i: sparse_weighted_mean(st, wv, plan,
-                                                         wire="f32", **kw),
-        "topk": lambda st, wv, i: topk_weighted_mean(
-            st, wv, topk_density, plan=plan, sample=topk_sample,
-            kernels=kernels, **kw)[0],
-        # hier: auto slice split unless requested; int8 cross-slice wire
-        # draws its stochastic-rounding key like the int8 impl
-        "hier": lambda st, wv, i: (
-            sparse_weighted_mean(st, wv, plan, wire="f32",
-                                 hier_inner=hier_inner or -1, **kw)
-            if hier_wire == "sparse" else weighted_mean(
-                st, wv, wire=hw, hier_inner=hier_inner or -1,
-                rng=(jax.random.fold_in(key, i) if hw == "int8"
-                     else None), kernels=kernels, **kw)),
-    }
-
-    def time_agg(agg_fn):
-        return time_weighted_agg(agg_fn, stacked, w, shapes, iters)
-
-    # timings flow through the PROCESS-GLOBAL obs registry (labeled by
-    # impl) and the bench dict is read back from it — the bench/tooling
-    # surface; note an ObsSession snapshots its own per-run registry,
-    # so these do NOT land in a run's metrics.json
-    from ..obs import metrics as obs_metrics
-
-    agg_dist = obs_metrics.get_registry().distribution("agg_ms")
-    result = {}
-    n_devices = (int(mesh.shape["clients"]) if mesh is not None
-                 and "clients" in mesh.axis_names else 1)
-    # modeled per-device wire bytes per impl (obs/comm.py) — recorded
-    # beside the timings so the gated history tracks ms AND bytes
-    from ..obs.comm import WireCostModel
-
-    wire_model = WireCostModel.from_params(
-        shapes, bucket_size=bucket_size, n_devices=n_devices, plan=plan,
-        topk_density=topk_density, hier_wire=hier_wire)
-    for name in impls:
-        if name not in agg_fns:
-            # a typo'd --impls must fail loudly, not print a timing-less
-            # JSON line that appends nothing to the gated history
-            raise ValueError(
-                f"unknown agg impl {name!r}; choose from "
-                f"{tuple(agg_fns)}")
-        agg_dist.labels(impl=name).observe(time_agg(agg_fns[name]) * 1e3)
-        result[f"agg_ms_{name}"] = agg_dist.labels(impl=name).last
-        result[f"wire_bytes_{name}"] = wire_model.bytes_for(name)
-    result.update(
-        n_params=n_params, n_clients=n_clients, n_devices=n_devices,
-        bucket_size=bucket_size, sparse_density=plan.density,
-        topk_density=topk_density, topk_sample=topk_sample,
-        hier_wire=hier_wire, hier_inner=hier_inner,
-        overlap=int(overlap), model_key=model_key, iters=iters,
-        kernels=kernels)
-    return result

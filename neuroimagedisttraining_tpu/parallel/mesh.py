@@ -47,7 +47,8 @@ def make_mesh(
 def fit_client_devices(n_clients: int, available: int) -> int:
     """Largest device count <= available that divides ``n_clients`` (the
     clients mesh axis must divide the client count). Shared by the runner
-    and bench.py so device-fitting policy lives in one place."""
+    and the benchmark's harness so device-fitting policy lives in one
+    place."""
     n = min(max(1, available), max(1, n_clients))
     while n_clients % n:
         n -= 1

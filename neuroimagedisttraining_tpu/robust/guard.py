@@ -89,9 +89,8 @@ def guarded_aggregate(stacked: Any, weights: jax.Array, ok: jax.Array,
     over the whole aggregation. The clean branch runs ``aggregate_fn``
     on the untouched inputs — bitwise the unguarded aggregate, and the
     only full-tree work a clean round pays beyond it is the read-only
-    finite screen that produced ``ok`` (measured +2.9% of the scale-32
-    dry-run round vs +13% for an unconditional row-sanitize, RESULTS.md
-    "Round-7"). The bad branch select-zeroes the quarantined rows,
+    finite screen that produced ``ok`` (not measured on a chip: no cell
+    runs the guard). The bad branch select-zeroes the quarantined rows,
     renormalizes the weights over the survivors, aggregates, and carries
     ``fallback`` (the previous global model) when nobody survived.
 

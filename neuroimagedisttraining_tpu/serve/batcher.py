@@ -7,8 +7,8 @@ Open-loop traffic arrives one request at a time; the device wants
 elapsed since the OLDEST pending request (the latency bound: a lone
 request on an idle worker never waits longer than the linger). This is
 the classic serving trade — linger higher for throughput, lower for
-tail latency — and both knobs are ``--serve_*`` flags so the RESULTS
-table can sweep them.
+tail latency — and both knobs are ``--serve_*`` flags so a sweep can
+set them.
 
 Thread contract: any number of producer threads ``submit()``; one
 consumer thread (the worker's serve loop) calls ``next_batch()``.

@@ -142,7 +142,7 @@ def _make_session(args, algo_name: str, identity: str, out_dir: str,
     if catalog and getattr(args, "obs_catalog", 1) and \
             getattr(args, "results_dir", ""):
         from ..obs import catalog as obs_catalog
-        from ..obs.regress import git_sha as _git_sha
+        from ..utils.records import git_sha as _git_sha
 
         cat_path = obs_catalog.catalog_path(args.results_dir)
         cat_info = {

@@ -1,11 +1,12 @@
 """Where the persistent XLA compilation cache lives.
 
 The flagship round program compiles for the better part of a minute on a
-TPU, and every process that runs it (CLI run, bench cell, smoke) would pay
+TPU, and every process that runs it (CLI run, benchmark cell, smoke) would pay
 that again. JAX's persistent cache removes the repeat — but only when each
 process looks in the same place, because the directory is where entries are
 found. One rule, applied by every entry point (``experiments.runner.main``,
-``bench.py``, ``__graft_entry__.py``, ``chip_smoke.py``) before its first
+``benchmarks/run.py``, ``__graft_entry__.py``, ``chip_smoke.py``) before its
+first
 compile:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX already reads it; nothing here

@@ -9,11 +9,24 @@ device queue stays full.
 """
 from __future__ import annotations
 
+import subprocess
 import time
 from typing import Any, Callable, Dict, Optional
 
 import jax
 import numpy as np
+
+
+def git_sha(repo_root: Optional[str] = None) -> str:
+    """Current commit SHA ('' when git is unavailable — catalog entries
+    stay useful without it)."""
+    try:
+        out = subprocess.run(
+            ["git", "rev-parse", "HEAD"], cwd=repo_root or None,
+            capture_output=True, text=True, timeout=10)
+        return out.stdout.strip() if out.returncode == 0 else ""
+    except Exception:
+        return ""
 
 
 def to_float(v):

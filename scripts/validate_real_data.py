@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Real-data validation runbook (VERDICT r3 item 8).
 
-Every convergence number in RESULTS.md is synthetic planted-signal because
+Every convergence number in RESULTS.md (section 4) is synthetic planted-signal because
 the real cohorts are not in the build environment. When they ARE present,
 this is the one command that validates the framework on them:
 

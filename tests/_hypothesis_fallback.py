@@ -186,6 +186,18 @@ def settings(max_examples=_DEFAULT_MAX_EXAMPLES, deadline=None,
     return apply
 
 
+def example(**kwargs):
+    """An explicit example: ``given`` runs it before the drawn ones (the
+    decorators stack ``@given`` above ``@example``)."""
+
+    def apply(fn):
+        fn._fallback_examples = getattr(fn, "_fallback_examples", []) \
+            + [kwargs]
+        return fn
+
+    return apply
+
+
 def given(**param_strategies):
     """Runs the test body ``max_examples`` times with drawn kwargs,
     seeded from the test's qualified name — deterministic across
@@ -199,10 +211,11 @@ def given(**param_strategies):
             seed = zlib.crc32(
                 f"{fn.__module__}.{fn.__qualname__}".encode())
             rnd = random.Random(seed)
-            for i in range(n):
-                kwargs = {name: strat.example(rnd)
-                          for name, strat in
-                          sorted(param_strategies.items())}
+            explicit = getattr(fn, "_fallback_examples", [])
+            for i in range(-len(explicit), n):
+                kwargs = explicit[i] if i < 0 else {
+                    name: strat.example(rnd)
+                    for name, strat in sorted(param_strategies.items())}
                 try:
                     fn(**kwargs)
                 except Exception as e:

@@ -1,16 +1,32 @@
-"""One tier-1 case of the benchmark's family seam (ISSUE 27's, left out by
-PR 27; the whole rehearsal is benchmarks/tests/test_family_seam.py, which the
-driver's tier-1 run does not collect): a configuration of a family that ships
-with no cell (2-D images, ten classes, softmax CE: benchmarks/tests/images2d)
-loads, builds through the program's own entry points and passes its own
-reference check, from files alone."""
+"""What the benchmark needs of the program, held in tier-1 (the driver's
+tier-1 run does not collect benchmarks/tests).
+
+One case of the family seam (ISSUE 27's, left out by PR 27; the whole
+rehearsal is benchmarks/tests/test_family_seam.py): a configuration of a
+family that ships with no cell (2-D images, ten classes, softmax CE:
+benchmarks/tests/images2d) loads, builds through the program's own entry
+points and passes its own reference check, from files alone.
+
+And the guard a deleting PR needs (PR 31): everything the files under
+``benchmarks/`` import from the package, read off ``algo``, pass to the
+program's functions or put on its command line still exists. The names come
+from the benchmark's source text and data files; nothing under
+``benchmarks/`` is executed for it."""
+import ast
+import glob
+import importlib
 import importlib.util
+import inspect
+import json
 import os
+import re
 import sys
 
 import jax
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PACKAGE = "neuroimagedisttraining_tpu"
 
 
 def _bench_conftest():
@@ -52,3 +68,121 @@ def test_a_family_without_a_cell_loads_builds_and_checks(tmp_path,
     assert manifest.family_of({}).__name__ == "benchmarks.families.volumes"
     assert manifest.family_of({"family": "tokens"}).__name__ \
         == "benchmarks.families.tokens"
+
+
+# ---------------------------------------------------------------------------
+# the static guard
+# ---------------------------------------------------------------------------
+
+def _benchmark_files():
+    """Every module under ``benchmarks/`` (its own tests apart): path
+    relative to the repo -> source."""
+    out = {}
+    for path in sorted(glob.glob(os.path.join(REPO, "benchmarks", "**",
+                                              "*.py"), recursive=True)):
+        rel = os.path.relpath(path, REPO)
+        if not rel.startswith(os.path.join("benchmarks", "tests")):
+            with open(path) as f:
+                out[rel] = f.read()
+    return out
+
+
+_FILES = _benchmark_files()
+#: those whose text names the package
+_SOURCES = {rel: src for rel, src in _FILES.items() if PACKAGE in src}
+
+
+@pytest.mark.parametrize("rel", sorted(_SOURCES))
+def test_what_a_benchmark_module_names_of_the_package_exists(rel):
+    """Each ``from neuroimagedisttraining_tpu... import name`` and ``import
+    neuroimagedisttraining_tpu...`` resolves, wherever in the module it
+    stands, and each file of the package its text cites is there."""
+    source = _SOURCES[rel]
+    for node in ast.walk(ast.parse(source)):
+        if isinstance(node, ast.Import):
+            for alias in node.names:
+                if alias.name.split(".")[0] == PACKAGE:
+                    importlib.import_module(alias.name)
+        elif isinstance(node, ast.ImportFrom) and node.level == 0 \
+                and (node.module or "").split(".")[0] == PACKAGE:
+            module = importlib.import_module(node.module)
+            for alias in node.names:
+                if not hasattr(module, alias.name):   # a submodule, then
+                    importlib.import_module(f"{node.module}.{alias.name}")
+    for cited in re.findall(PACKAGE + r"/[\w/]+\.py", source):
+        assert os.path.exists(os.path.join(REPO, cited)), (rel, cited)
+
+
+def _calls_on(tree, owner: str, attr: str):
+    """The ``owner.attr(...)`` calls of a module."""
+    return [node for node in ast.walk(tree)
+            if isinstance(node, ast.Call)
+            and isinstance(node.func, ast.Attribute)
+            and node.func.attr == attr
+            and isinstance(node.func.value, ast.Name)
+            and node.func.value.id == owner]
+
+
+def _binds(call, fn, skip_self=False):
+    """The call's positional count and keywords fit ``fn``'s signature."""
+    args = [None] * (len(call.args) + int(skip_self))
+    inspect.signature(fn).bind(*args, **{k.arg: None for k in call.keywords})
+
+
+def test_what_the_harness_reads_off_the_program_is_still_there():
+    """The attributes the benchmark reads off ``algo``, the way it calls
+    ``run``, ``build_algorithm`` and ``maybe_shard``, and every flag its
+    data files put on the program's command line."""
+    from neuroimagedisttraining_tpu.algorithms import SalientGrads
+    from neuroimagedisttraining_tpu.analysis.identity import collect_flags
+    from neuroimagedisttraining_tpu.core.state import HyperParams
+    from neuroimagedisttraining_tpu.data import make_synthetic_federated
+    from neuroimagedisttraining_tpu.experiments import runner
+    from neuroimagedisttraining_tpu.models import create_model
+
+    assert os.path.join("benchmarks", "lib", "harness.py") in _SOURCES
+    trees = {rel: ast.parse(src) for rel, src in _FILES.items()}
+    read = {node.attr for tree in trees.values() for node in ast.walk(tree)
+            if isinstance(node, ast.Attribute)
+            and isinstance(node.value, ast.Name) and node.value.id == "algo"}
+    # the seam as ISSUE 31 found it: a benchmark PR may read more or less
+    assert {"run", "init_state", "clone_state", "_round_jit", "_donate",
+            "eval_cache", "client_chunk", "apply_fn", "hp", "data",
+            "clients_per_round", "loss_type", "compute_dtype"} <= read
+    algo = SalientGrads(
+        create_model("small3dcnn", num_classes=1),
+        make_synthetic_federated(n_clients=2, samples_per_client=4,
+                                 test_per_client=2,
+                                 sample_shape=(8, 8, 8, 1)),
+        HyperParams(lr=0.05, local_epochs=1, steps_per_epoch=1,
+                    batch_size=4),
+        loss_type="bce", frac=1.0, seed=0, dense_ratio=0.5)
+    assert not [name for name in sorted(read) if not hasattr(algo, name)]
+
+    harness = trees[os.path.join("benchmarks", "lib", "harness.py")]
+    runs = _calls_on(harness, "algo", "run")
+    builds = _calls_on(harness, "runner", "build_algorithm")
+    shards = _calls_on(harness, "runner", "maybe_shard")
+    assert runs and builds and shards
+    for call in runs:
+        _binds(call, type(algo).run, skip_self=True)
+        assert "fuse_rounds" in {k.arg for k in call.keywords}
+    for call in builds:
+        _binds(call, runner.build_algorithm)
+        assert "data" in {k.arg for k in call.keywords}
+    for call in shards:
+        _binds(call, runner.maybe_shard)
+
+    with open(os.path.join(REPO, PACKAGE, "experiments", "config.py")) as f:
+        known = set(collect_flags(f.read()))
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    passed = {"client_num_in_total", "mesh_devices", "seed"}  # program_flags
+    for entry in bench["configs"]:
+        with open(os.path.join(REPO, entry["file"])) as f:
+            passed |= set(json.load(f)["flags"])
+    for path in glob.glob(os.path.join(REPO, "benchmarks", "traffic",
+                                       "*.json")):
+        with open(path) as f:
+            passed |= set(json.load(f)["flags"])
+    assert not sorted(passed - known)

@@ -1,9 +1,11 @@
-"""The compile-cache placement rule and chip_smoke.py's refusals (CPU tier).
+"""The compile-cache placement rule, chip_smoke.py's refusals and the
+multi-device dry run (CPU tier).
 
 What chip_smoke.py proves on a TPU is proven there (``python chip_smoke.py``
 through the chip tool); here only what must hold WITHOUT a chip: the cache
-lands where the next process will look, and the smoke never passes on a
-CPU or apart from the program it checks.
+lands where the next process will look, the smoke never passes on a
+CPU or apart from the program it checks, and ``__graft_entry__.py`` runs a
+sharded round on the devices it is given.
 """
 import os
 import shutil
@@ -92,3 +94,28 @@ def test_chip_smoke_fails_apart_from_the_program(tmp_path):
     assert out.returncode != 0
     assert "cannot import the program" in out.stderr
     assert '"ok"' not in out.stdout
+
+
+def test_dryrun_multichip_runs_a_sharded_round_on_four_devices():
+    """``__graft_entry__.py 4`` as the driver starts it, on four virtual CPU
+    devices: one compiled round on a ``clients`` mesh with its all-reduce,
+    finite loss and eval, the hybrid mesh, and nothing timed."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("PYTHONPATH", "XLA_FLAGS")}
+    env["JAX_PLATFORMS"] = "cpu"
+    # the suite's own cache directory, not the checkout's .jax_cache
+    env[compile_cache.CACHE_ENV] = jax.config.jax_compilation_cache_dir
+    out = subprocess.run(
+        [sys.executable, "__graft_entry__.py", "4"], cwd=REPO, env=env,
+        capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    ok = [ln for ln in out.stdout.splitlines()
+          if ln.startswith("dryrun_multichip OK:")]
+    assert len(ok) == 1, out.stdout[-2000:]
+    line = ok[0]
+    assert "4 cpu devices" in line and "'clients': 4" in line
+    assert "hybrid mesh={'clients': 2, 'space': 2} OK" in line
+    n = int(line.split("all-reduces in the round=")[1].split(",")[0])
+    assert n >= 1
+    # a check that the round runs, never a timing
+    assert " ms" not in line and "share" not in line

@@ -62,6 +62,23 @@ def _max_err(a, b):
                                jax.tree_util.tree_leaves(b)))
 
 
+def _assert_reassociated_sum_close(dense, other, what):
+    """The contract of a bucketed (or hier, off-mesh) aggregate against the
+    dense per-leaf contraction: the same f32 sum of C terms, associated in
+    the order XLA picks for each operand shape (one [C, N_total] bucket
+    against a [C, n_leaf] dot per leaf), so equal to a few ulp at the scale
+    of the parameters, not bit for bit. Measured on XLA:CPU 0.9.0 after two
+    rounds: one ulp (6e-8) in an 8-element leaf of O(1) values."""
+    scale = max(1.0, max(float(jnp.max(jnp.abs(x)))
+                         for x in jax.tree_util.tree_leaves(dense)))
+    atol = 4 * float(np.finfo(np.float32).eps) * scale
+    err = _max_err(dense, other)
+    assert err <= atol, (
+        f"{what}: max |difference| {err:.3g} over {atol:.3g} (4 ulp of "
+        f"f32 at scale {scale:.3g}): more than a re-associated sum of C "
+        "terms explains")
+
+
 def test_bucketed_bit_equal_dense():
     tree, w = _tree(), _weights()
     dense = weighted_tree_sum(tree, w)
@@ -207,22 +224,24 @@ def _run2(cls, agg_impl, model, data, hp, **kw):
 
 
 def test_salientgrads_agg_impl_round_parity():
-    """Two SalientGrads rounds per impl: bucketed and sparse are bit-equal
-    to the dense default (locals honor the static SNIP mask, so the
-    compressed reduce loses nothing); bf16 stays within wire precision;
-    int8 trains finite."""
+    """Two SalientGrads rounds per impl: sparse is bit-equal to the dense
+    default (locals honor the static SNIP mask, so the compressed reduce
+    loses nothing, and it contracts leaf by leaf as dense does); bucketed
+    re-associates the sum and stays within a few ulp; bf16 stays within
+    wire precision; int8 trains finite."""
     from neuroimagedisttraining_tpu.algorithms import SalientGrads
 
     model, data, hp = _small_setup()
     kw = dict(dense_ratio=0.5, itersnip_iterations=1)
     _, sd, loss_d = _run2(SalientGrads, "dense", model, data, hp, **kw)
     assert np.isfinite(loss_d)
-    for impl in ("bucketed", "sparse"):
-        algo, s, _ = _run2(SalientGrads, impl, model, data, hp, **kw)
-        assert _leaves_equal(sd.global_params, s.global_params), impl
-        if impl == "sparse":
-            assert algo._agg_sparse_plan is not None
-            assert algo._agg_sparse_plan.density < 1.0
+    _, s, _ = _run2(SalientGrads, "bucketed", model, data, hp, **kw)
+    _assert_reassociated_sum_close(sd.global_params, s.global_params,
+                                   "bucketed")
+    algo, s, _ = _run2(SalientGrads, "sparse", model, data, hp, **kw)
+    assert _leaves_equal(sd.global_params, s.global_params), "sparse"
+    assert algo._agg_sparse_plan is not None
+    assert algo._agg_sparse_plan.density < 1.0
     _, sb, loss_b = _run2(SalientGrads, "bf16", model, data, hp, **kw)
     assert np.isfinite(loss_b)
     assert _max_err(sd.global_params, sb.global_params) < 2e-2
@@ -254,7 +273,8 @@ def test_salientgrads_sparse_fused_matches_unfused():
 def test_robust_defense_composes_with_agg_impls():
     """Defenses transform the stacked locals BEFORE aggregation, so they
     compose with every agg_impl: the deterministic clipping defense is
-    bit-equal across dense/bucketed/sparse, and weak-DP + sparse keeps
+    bit-equal across dense/sparse and within a few ulp for bucketed (a
+    re-associated sum), and weak-DP + sparse keeps
     the mask invariant (noise on dead coordinates is dropped by the
     compressed reduce)."""
     from neuroimagedisttraining_tpu.algorithms import SalientGrads
@@ -266,10 +286,13 @@ def test_robust_defense_composes_with_agg_impls():
     clip = dict(defense_type="norm_diff_clipping", norm_bound=0.5)
     _, sd, _ = _run2(SalientGrads, "dense", model, data, hp,
                      defense=RobustAggregator(**clip), **kw)
-    for impl in ("bucketed", "sparse"):
-        _, s, _ = _run2(SalientGrads, impl, model, data, hp,
-                        defense=RobustAggregator(**clip), **kw)
-        assert _leaves_equal(sd.global_params, s.global_params), impl
+    _, s, _ = _run2(SalientGrads, "bucketed", model, data, hp,
+                    defense=RobustAggregator(**clip), **kw)
+    _assert_reassociated_sum_close(sd.global_params, s.global_params,
+                                   "bucketed behind the clipping defense")
+    _, s, _ = _run2(SalientGrads, "sparse", model, data, hp,
+                    defense=RobustAggregator(**clip), **kw)
+    assert _leaves_equal(sd.global_params, s.global_params), "sparse"
     _, sw, loss = _run2(
         SalientGrads, "sparse", model, data, hp,
         defense=RobustAggregator("weak_dp", norm_bound=0.5, stddev=0.01),
@@ -290,6 +313,8 @@ def test_robust_defense_composes_with_agg_impls():
 
 
 def test_fedavg_bucketed_bit_equal_and_sparse_rejected():
+    # the name is the driver's record of this test; the contract is a few
+    # ulp (see _assert_reassociated_sum_close)
     from neuroimagedisttraining_tpu.algorithms import FedAvg
 
     model, data, hp = _small_setup()
@@ -297,7 +322,8 @@ def test_fedavg_bucketed_bit_equal_and_sparse_rejected():
                      track_personal=False)
     _, sb, _ = _run2(FedAvg, "bucketed", model, data, hp,
                      track_personal=False)
-    assert _leaves_equal(sd.global_params, sb.global_params)
+    _assert_reassociated_sum_close(sd.global_params, sb.global_params,
+                                   "fedavg bucketed")
     with pytest.raises(ValueError, match="static-mask"):
         _run2(FedAvg, "sparse", model, data, hp, track_personal=False)
     with pytest.raises(ValueError, match="agg_impl"):
@@ -340,13 +366,3 @@ def test_fused_metric_contract_raises():
         algo.run_rounds_fused(state, 0, 2)
 
 
-def test_agg_microbench_smoke():
-    """The micro-bench surface bench.py / scripts/bench_agg.py consume,
-    at CI scale."""
-    out = coll.agg_microbench(n_clients=4, iters=1,
-                              model_key="small3dcnn",
-                              sample_shape=(8, 8, 8, 1))
-    for k in ("agg_ms_dense", "agg_ms_bucketed", "agg_ms_sparse",
-              "agg_ms_bf16", "agg_ms_int8"):
-        assert out[k] > 0, k
-    assert 0 < out["sparse_density"] < 1

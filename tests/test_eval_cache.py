@@ -95,9 +95,12 @@ def test_cached_metrics_bit_equal_full_eval(cls, kw):
             state.personal_params, algo.data.x_test, algo.data.y_test,
             algo.data.n_test)
         assert float(ev["personal_acc"]) == float(full["acc"]), r
-        np.testing.assert_array_equal(
-            np.asarray(ev["acc_per_client"]),
-            np.asarray(full["acc_per_client"]))
+        # per client, from the cache's own rows: the integer counts the
+        # accuracies divide. (``ev["acc_per_client"]`` is the GLOBAL
+        # model's per-site accuracy, ``_eval_impl``: another quantity.)
+        for k in ("correct", "total"):
+            np.testing.assert_array_equal(
+                np.asarray(state.eval_cache[k]), np.asarray(full[k]), k)
         assert _loss_close(float(ev["personal_loss"]),
                            float(full["loss"])), r
 

@@ -378,19 +378,3 @@ def test_jsonl_fault_recovery_fields_and_fused(tmp_path):
     assert all(r.get("round_time_s", 0) > 0 for r in recs_f)
 
 
-def test_collectives_agg_timings_flow_through_registry():
-    from neuroimagedisttraining_tpu.parallel.collectives import (
-        agg_microbench,
-    )
-
-    prev = metrics.set_registry(None)
-    try:
-        out = agg_microbench(n_clients=4, iters=1, model_key="small3dcnn",
-                             sample_shape=(8, 8, 8, 1),
-                             impls=("dense", "bucketed"))
-        reg = metrics.get_registry()
-        d = reg.distribution("agg_ms")
-        assert d.labels(impl="dense").last == out["agg_ms_dense"]
-        assert d.labels(impl="bucketed").last == out["agg_ms_bucketed"]
-    finally:
-        metrics.set_registry(prev)

@@ -1,14 +1,13 @@
-"""Telemetry ANALYSIS layer (obs/analyze, health, regress, compile).
+"""Telemetry ANALYSIS layer (obs/analyze, health, compile).
 
 Covers the from-recording-to-diagnosis contract: synthetic round
 streams with known-injected anomalies must produce exactly the expected
 flags in ``analysis.json`` (straggler round index + phase, memory-leak
 key, clean stream silent), the host fault-trace replay must agree
-bit-for-bit with the in-jit injector's draws, the bench-history
-regression gate must pass the committed trajectory and fail a -20%
-value, compile events must attribute to the dispatching obs span, and
-the whole pipeline must hold end-to-end through a real ``--obs`` run
-with ``--fault_spec straggle=...``.
+bit-for-bit with the in-jit injector's draws, compile events must
+attribute to the dispatching obs span, and the whole pipeline must hold
+end-to-end through a real ``--obs`` run with ``--fault_spec
+straggle=...``.
 """
 import json
 import os
@@ -22,7 +21,6 @@ from neuroimagedisttraining_tpu.obs import (
     export,
     health,
     metrics,
-    regress,
     trace,
 )
 
@@ -336,105 +334,7 @@ def test_partial_participation_replay_counts():
     assert total == 12  # 6 rounds x 2 selected
 
 
-# ---------------------------------------------------------------------------
-# regress: history, backfill, gate
-# ---------------------------------------------------------------------------
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _seed_history(hist, metric, values):
-    for v in values:
-        regress.append_history(hist, {"metric": metric, "value": v},
-                               source="seed", git_sha="")
-
-
-def test_gate_passes_current_and_fails_regressed(tmp_path):
-    """Acceptance: exit 0 on a value inside the history's band, non-zero
-    on a synthetically regressed (-20%) value."""
-    hist = str(tmp_path / "hist.jsonl")
-    metric = "salientgrads_rounds_per_sec_abcd_alexnet3d_8clients"
-    _seed_history(hist, metric, [1.00, 1.02, 0.99, 1.01, 1.00])
-    values = [e["value"] for e in regress.read_history(hist, metric)]
-    assert len(values) == 5
-    current = values[-1]
-    ok = regress.gate(hist, metric, current)
-    assert ok["exit_code"] == regress.EXIT_OK and not ok["regression"]
-    bad = regress.gate(hist, metric, 0.8 * current)
-    assert bad["exit_code"] == regress.EXIT_REGRESSION
-    assert bad["regression"]
-    none = regress.gate(hist, "no_such_metric", 1.0)
-    assert none["exit_code"] == regress.EXIT_NO_HISTORY
-
-
-def test_detect_regression_noise_band():
-    hist = [1.0, 1.01, 0.99, 1.02, 0.98]
-    # within the 5% band: fine
-    assert not regress.detect_regression(hist, 0.97)["regression"]
-    # far below: regression
-    v = regress.detect_regression(hist, 0.80)
-    assert v["regression"] and v["margin"] < 0
-    # a noisy history earns a wider band
-    noisy = [1.0, 1.4, 0.7, 1.3, 0.75]
-    assert not regress.detect_regression(noisy, 0.80)["regression"]
-    # lower-is-better flips the direction
-    lat = regress.detect_regression([10.0, 10.1, 9.9], 12.0,
-                                    higher_is_better=False)
-    assert lat["regression"]
-    assert not regress.detect_regression(
-        [10.0, 10.1, 9.9], 10.2, higher_is_better=False)["regression"]
-
-
-def test_gate_excludes_own_commit_measurements(tmp_path):
-    """bench.py appends before the gate judges — a commit must be
-    judged against OTHER commits' trajectory, or rerunning a regressed
-    build would shift the median toward itself."""
-    hist = str(tmp_path / "h.jsonl")
-    for v in (1.0, 1.01, 0.99):
-        regress.append_history(hist, {"metric": "m", "value": v},
-                               git_sha="")
-    # the commit under test recorded its regressed value 5 times
-    for _ in range(5):
-        regress.append_history(hist, {"metric": "m", "value": 0.8},
-                               git_sha="deadbeef")
-    unexcluded = regress.gate(hist, "m", 0.8)
-    excluded = regress.gate(hist, "m", 0.8,
-                            exclude_git_sha="deadbeef")
-    assert excluded["regression"]
-    assert excluded["exit_code"] == regress.EXIT_REGRESSION
-    # without the exclusion the self-recorded values mask the hit
-    assert not unexcluded["regression"]
-
-
-def test_append_history_and_read_roundtrip(tmp_path):
-    hist = str(tmp_path / "h.jsonl")
-    entry = regress.append_history(
-        hist, {"metric": "m", "value": 1.5, "unit": "r/s",
-               "extra": {"clients": 8}}, source="test")
-    assert entry["value"] == 1.5
-    back = regress.read_history(hist, "m")
-    assert len(back) == 1 and back[0]["extra"]["clients"] == 8
-    with pytest.raises(ValueError, match="value"):
-        regress.append_history(hist, {"metric": "m"})
-
-
-def test_perf_gate_cli(tmp_path):
-    import subprocess
-    import sys
-
-    hist = str(tmp_path / "hist.jsonl")
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    gate_py = os.path.join(REPO, "scripts", "perf_gate.py")
-    _seed_history(hist, "salientgrads_rounds_per_sec_abcd_alexnet3d_8clients",
-                  [1.66, 1.71, 1.67, 1.67, 1.71])
-    ok = subprocess.run(
-        [sys.executable, gate_py, "--history", hist, "--value", "1.70"],
-        capture_output=True, text=True, env=env, cwd=REPO)
-    assert ok.returncode == 0, ok.stdout + ok.stderr
-    bad = subprocess.run(
-        [sys.executable, gate_py, "--history", hist, "--value", "1.33"],
-        capture_output=True, text=True, env=env, cwd=REPO)
-    assert bad.returncode == 1, bad.stdout + bad.stderr
 
 
 def test_no_internal_timer_shim_callers():

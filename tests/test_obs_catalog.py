@@ -261,3 +261,29 @@ def test_session_without_catalog_path_writes_nothing(tmp_path):
     s.record_round({"round": 0, "train_loss": 1.0})
     s.finish()
     assert not os.path.exists(cat)
+
+
+# ---------------------------------------------------------------------------
+# the git SHA a catalog entry carries (utils/records.git_sha)
+# ---------------------------------------------------------------------------
+
+def test_git_sha_is_head_inside_a_repository_and_empty_outside(
+        tmp_path, monkeypatch):
+    import subprocess
+
+    from neuroimagedisttraining_tpu.utils.records import git_sha
+
+    # wherever the temporary directory lies, git looks no further up
+    monkeypatch.setenv("GIT_CEILING_DIRECTORIES", str(tmp_path))
+    outside = tmp_path / "plain"
+    outside.mkdir()
+    assert git_sha(str(outside)) == ""     # never raises, never guesses
+    assert git_sha(str(tmp_path / "missing")) == ""
+    repo = tmp_path / "repo"
+    repo.mkdir()
+    git = ["git", "-c", "user.name=t", "-c", "user.email=t@example.org"]
+    for cmd in (["init", "-q"], ["commit", "-q", "--allow-empty", "-m", "x"]):
+        subprocess.run(git + cmd, cwd=repo, check=True, capture_output=True)
+    head = subprocess.run(["git", "rev-parse", "HEAD"], cwd=repo, check=True,
+                          capture_output=True, text=True).stdout.strip()
+    assert len(head) == 40 and git_sha(str(repo)) == head

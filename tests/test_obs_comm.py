@@ -1,7 +1,6 @@
-"""Communication observability (obs/comm.py, obs/devtrace.py, comm SLO
-gates): the wire-cost model, message/backend byte accounting, the
-schema-v3 analyzer comm section, the MULTICHIP-seeded perf gates, the
-live-tail CLI, and the bench_agg history wiring.
+"""Communication observability (obs/comm.py, obs/devtrace.py): the
+wire-cost model, message/backend byte accounting, the schema-v3 analyzer
+comm section, and the live-tail CLI.
 """
 import json
 import math
@@ -16,7 +15,6 @@ from neuroimagedisttraining_tpu.obs import (
     comm as obs_comm,
     devtrace as obs_devtrace,
     export,
-    regress,
 )
 
 REPO_ROOT = os.path.join(os.path.dirname(__file__), "..")
@@ -486,23 +484,6 @@ def test_devtrace_excludes_overlapping_aggregate_rows():
     assert att["totals"]["agg_share"] == pytest.approx(0.4)
 
 
-def test_obs_regress_cli_uses_comm_defaults(tmp_path, capsys):
-    """`python -m ...obs regress` must reach the same verdict as
-    scripts/perf_gate.py on the comm SLO metrics (lower-is-better,
-    comm band) without extra flags."""
-    from neuroimagedisttraining_tpu.obs.__main__ import main
-
-    hist = str(tmp_path / "hist.jsonl")
-    regress.backfill_multichip_files(REPO_ROOT, hist)
-    rc = main(["regress", "--history", hist, "--metric",
-               "scale32_agg_ms", "--value", str(1181.075 * 1.2)])
-    capsys.readouterr()
-    assert rc == regress.EXIT_REGRESSION
-    rc = main(["regress", "--history", hist, "--metric",
-               "scale32_agg_ms", "--value", "1015.3"])
-    capsys.readouterr()
-    assert rc == regress.EXIT_OK
-
 
 def test_devtrace_profile_dir_roundtrip(tmp_path):
     import gzip
@@ -536,180 +517,6 @@ def test_share_from_cost_analysis_fallback():
         {"flops": 1e6}, {"flops": 1e9, "bytes_accessed": None})
     assert est2["basis"] == "flops"
     assert not obs_devtrace.share_from_cost_analysis({}, {})["present"]
-
-
-# ---------------------------------------------------------------------------
-# comm SLO gates (MULTICHIP-seeded perf_gate)
-# ---------------------------------------------------------------------------
-
-def test_multichip_parse_and_backfill(tmp_path):
-    parsed = regress.parse_multichip_artifact(
-        os.path.join(REPO_ROOT, "MULTICHIP_r05.json"))
-    assert parsed["scale32_round_ms"] == pytest.approx(1819.6)
-    assert parsed["scale32_agg_share"] == pytest.approx(55.8)
-    assert parsed["scale32_agg_ms"] == pytest.approx(
-        1819.6 * 0.558, rel=1e-6)
-    assert parsed["bench_round"] == 5
-    # r01 predates the scale-32 probe: nothing to seed
-    assert regress.parse_multichip_artifact(
-        os.path.join(REPO_ROOT, "MULTICHIP_r01.json")) is None
-
-    hist = str(tmp_path / "hist.jsonl")
-    n = regress.backfill_multichip_files(REPO_ROOT, hist)
-    # r03/r04/r05 carry the probe line, three metrics each
-    assert n == 9
-    assert regress.backfill_multichip_files(REPO_ROOT, hist) == 0
-    entries = regress.read_history(hist, "scale32_agg_ms")
-    assert len(entries) == 3
-    assert all(e["git_sha"] == "" for e in entries)
-
-
-def _gate(hist, metric, value):
-    d = regress.metric_gate_defaults(metric)
-    return regress.gate(
-        hist, metric, value,
-        rel_threshold=d["rel_threshold"], mad_k=d["mad_k"],
-        higher_is_better=d["higher_is_better"],
-        exclude_git_sha=regress.git_sha(REPO_ROOT))
-
-
-def test_comm_gate_passes_current_fails_injection(tmp_path):
-    """Acceptance pin: the seeded MULTICHIP history passes on current
-    numbers and fails (exit 1) on a +20% agg_ms / +10pp agg_share
-    injection over the baseline median."""
-    hist = str(tmp_path / "hist.jsonl")
-    regress.backfill_multichip_files(REPO_ROOT, hist)
-    med_ms = sorted(e["value"] for e in
-                    regress.read_history(hist, "scale32_agg_ms"))[1]
-    med_share = sorted(e["value"] for e in
-                       regress.read_history(hist,
-                                            "scale32_agg_share"))[1]
-    # current numbers (the r05 measurements) pass
-    v = _gate(hist, "scale32_agg_ms", 1819.6 * 0.558)
-    assert v["exit_code"] == regress.EXIT_OK, v["reason"]
-    v = _gate(hist, "scale32_agg_share", 55.8)
-    assert v["exit_code"] == regress.EXIT_OK, v["reason"]
-    # +20% agg_ms over baseline fails
-    v = _gate(hist, "scale32_agg_ms", med_ms * 1.2)
-    assert v["exit_code"] == regress.EXIT_REGRESSION, v["reason"]
-    # +10 percentage points of agg share fails
-    v = _gate(hist, "scale32_agg_share", med_share + 10.0)
-    assert v["exit_code"] == regress.EXIT_REGRESSION, v["reason"]
-
-
-def test_comm_gate_excludes_own_commit(tmp_path):
-    """A rerun regressed build appending its own (huge) measurement
-    must not shift the baseline it is judged against."""
-    hist = str(tmp_path / "hist.jsonl")
-    regress.backfill_multichip_files(REPO_ROOT, hist)
-    sha = regress.git_sha(REPO_ROOT)
-    assert sha  # the repo is a git checkout
-    regress.append_history(
-        hist, {"metric": "scale32_agg_ms", "value": 99999.0,
-               "unit": "ms"}, source="rerun", repo_root=REPO_ROOT)
-    v = _gate(hist, "scale32_agg_ms", 1015.0)
-    assert v["exit_code"] == regress.EXIT_OK
-    # without the exclusion the poisoned entry WOULD join the window
-    poisoned = regress.gate(
-        hist, "scale32_agg_ms", 1015.0, rel_threshold=0.15, mad_k=0.0,
-        higher_is_better=False, exclude_git_sha="")
-    assert poisoned["history_points"] == 4
-
-
-def test_perf_gate_cli_comm_defaults(tmp_path, capsys):
-    """scripts/perf_gate.py resolves lower-is-better + the comm band
-    from the metric name; --backfill seeds MULTICHIP too."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate", os.path.join(REPO_ROOT, "scripts", "perf_gate.py"))
-    perf_gate = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(perf_gate)
-    hist = str(tmp_path / "hist.jsonl")
-    rc = perf_gate.main(["--backfill", "--history", hist])
-    out = json.loads(capsys.readouterr().out.strip())
-    assert rc == 0 and out["backfilled_multichip"] == 9
-    rc = perf_gate.main(["--history", hist, "--metric",
-                         "scale32_agg_ms", "--value", "1015.3"])
-    verdict = json.loads(capsys.readouterr().out.strip())
-    assert rc == regress.EXIT_OK and verdict["judged"]
-    rc = perf_gate.main(["--history", hist, "--metric",
-                         "scale32_agg_ms", "--value",
-                         str(verdict["baseline_median"] * 1.2)])
-    capsys.readouterr()
-    assert rc == regress.EXIT_REGRESSION
-
-
-def test_bench_agg_unknown_impl_raises():
-    from neuroimagedisttraining_tpu.parallel.collectives import (
-        agg_microbench,
-    )
-
-    with pytest.raises(ValueError, match="unknown agg impl"):
-        agg_microbench(n_clients=4, iters=1, model_key="small3dcnn",
-                       sample_shape=(8, 8, 8, 1), impls=("bf18",))
-
-
-def test_metric_gate_defaults_prefixes():
-    d = regress.metric_gate_defaults("scale32_agg_share")
-    assert d == {"higher_is_better": False, "rel_threshold": 0.15,
-                 "mad_k": 0.0}
-    assert regress.metric_gate_defaults(
-        "agg_ms_sparse_3dcnn_c32_d8") == {"higher_is_better": False}
-    assert regress.metric_gate_defaults("rounds_per_sec") == {}
-
-
-# ---------------------------------------------------------------------------
-# bench_agg history wiring (satellite)
-# ---------------------------------------------------------------------------
-
-def test_bench_agg_appends_history(tmp_path):
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_agg", os.path.join(REPO_ROOT, "scripts", "bench_agg.py"))
-    bench_agg = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench_agg)
-    hist = str(tmp_path / "hist.jsonl")
-    out = bench_agg.main([
-        "--model", "small3dcnn", "--clients", "4", "--iters", "1",
-        "--devices", "1", "--impls", "dense,bf16",
-        "--history", hist])
-    assert "agg_ms_dense" in out and "agg_ms_bf16" in out
-    # modeled wire bytes recorded beside the timings (PR 7): the gated
-    # history tracks time AND bytes per impl
-    assert out["wire_bytes_bf16"] == out["wire_bytes_dense"] / 2
-    entries = regress.read_history(hist)
-    metrics = {e["metric"] for e in entries}
-    tag = f"small3dcnn_c4_d{out['n_devices']}"
-    assert metrics == {f"agg_ms_dense_{tag}", f"agg_ms_bf16_{tag}",
-                       f"agg_bytes_dense_{tag}", f"agg_bytes_bf16_{tag}"}
-    for e in entries:
-        assert e["source"] == "bench_agg"
-        assert e["extra"]["n_params"] == out["n_params"]
-        if e["metric"].startswith("agg_ms_"):
-            assert e["unit"] == "ms"
-            # the microbench timings gate lower-is-better by prefix
-            assert regress.metric_gate_defaults(e["metric"]) == {
-                "higher_is_better": False}
-        else:
-            assert e["unit"] == "bytes"
-            # bytes are analytic — lower-is-better with a tight band
-            d = regress.metric_gate_defaults(e["metric"])
-            assert d["higher_is_better"] is False
-            assert d["rel_threshold"] < 0.05
-    # non-default impl knobs qualify the metric NAME, so a sweep run
-    # gates against its own trajectory, not the default config's
-    # (identical name = identical workload); timing-only knobs (sample,
-    # overlap) stay out of the byte metric's name
-    out2 = bench_agg.main([
-        "--model", "small3dcnn", "--clients", "4", "--iters", "1",
-        "--devices", "1", "--impls", "topk", "--topk_density", "0.2",
-        "--topk_sample", "64", "--overlap", "0", "--history", hist])
-    assert "agg_ms_topk" in out2
-    metrics2 = {e["metric"] for e in regress.read_history(hist)}
-    assert f"agg_ms_topk-tk0.2-tks64-ov0_{tag}" in metrics2
-    assert f"agg_bytes_topk-tk0.2_{tag}" in metrics2
 
 
 # ---------------------------------------------------------------------------

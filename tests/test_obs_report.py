@@ -4,10 +4,8 @@ tail --all).
 Covers the byte-determinism contract (two generations over the same
 catalog are bit-identical; no timestamps anywhere), the report's
 content obligations (every cataloged run renders; INCOMPLETE marker;
-wire-cost table from the comm metrics; scatter from cohort-tagged
-bench history), graceful degradation on missing artifacts, the
-``scatter_points`` history parsing (keep-last, ``_<N>clients`` tag),
-and the CLI exit codes: ``ls`` (2 on empty, --rebuild migration),
+wire-cost table from the comm metrics), graceful degradation on
+missing artifacts, and the CLI exit codes: ``ls`` (2 on empty, --rebuild migration),
 ``report`` (2 on empty catalog), ``tail --all`` (catalog-resolved
 fan-out, 2 when nothing resolves).
 """
@@ -96,41 +94,6 @@ def test_report_degrades_without_artifacts(tmp_path):
         assert "gone" in f.read()
 
 
-def test_scatter_points_parse_and_keep_last():
-    history = [
-        {"metric": "fedavg_rounds_per_sec_synthetic_8clients",
-         "value": 1.0},
-        {"metric": "fedavg_rounds_per_sec_synthetic_8clients",
-         "value": 2.0},  # append-only rerun: keep-last
-        {"metric": "fedavg_rounds_per_sec_synthetic_32clients",
-         "value": 0.5},
-        {"metric": "fedavg_rounds_per_sec_no_cohort_tag",
-         "value": 9.9},  # no _<N>clients tag: dropped
-        {"metric": "some_other_metric_8clients", "value": 3.0},
-        {"metric": "fedavg_rounds_per_sec_synthetic_16clients",
-         "value": "bad"},
-    ]
-    pts = report.scatter_points(history)
-    assert pts == [
-        ("fedavg_rounds_per_sec_synthetic_32clients", 32, 0.5),
-        ("fedavg_rounds_per_sec_synthetic_8clients", 8, 2.0),
-    ]
-
-
-def test_report_includes_history_scatter(tmp_path):
-    results, cat = _seed_fleet(tmp_path)
-    hist = os.path.join(results, "bench_history.jsonl")
-    _write_jsonl(hist, [
-        {"metric": "fedavg_rounds_per_sec_synthetic_8clients",
-         "value": 1.5},
-        {"metric": "fedavg_rounds_per_sec_synthetic_32clients",
-         "value": 0.8}])
-    out = str(tmp_path / "fleet.html")
-    report.write_report(out, cat, history_path=hist)
-    with open(out) as f:
-        html = f.read()
-    assert "<circle" in html and "8 clients" in html
-
 
 def test_fmt_is_the_single_float_formatter():
     assert report._fmt(True) == "1" and report._fmt(False) == "0"
@@ -213,3 +176,24 @@ def test_tail_all_prints_newest_line_per_run(tmp_path):
                     out=ev_lines.append) == 2
     assert all("SLO_BREACH" in ln for ln in ev_lines)
     assert tail_all(str(tmp_path / "empty")) == 0  # nothing resolves
+
+
+def test_every_documented_subcommand_exists_and_the_reverse(capsys):
+    """The module docstring is the CLI's ``--help`` text: each ``python -m
+    neuroimagedisttraining_tpu.obs <subcommand>`` it shows is one the
+    parser takes, and each subcommand is shown (PR 31 removed one)."""
+    import re
+
+    import pytest
+
+    from neuroimagedisttraining_tpu.obs import __main__ as cli
+
+    documented = set(re.findall(
+        r"python -m neuroimagedisttraining_tpu\.obs (\w+)", cli.__doc__))
+    with pytest.raises(SystemExit) as done:
+        cli.main(["--help"])
+    assert done.value.code == 0
+    usage = capsys.readouterr().out
+    taken = set(re.search(r"\{([\w,]+)\}", usage).group(1).split(","))
+    assert documented == taken == {"analyze", "tail", "slo", "ls", "diff",
+                                   "report", "watch", "xtrace"}

@@ -1,131 +1,9 @@
-"""Pallas fused kernels vs reference jnp math (interpret mode on CPU)."""
+"""The ``--agg_kernels pallas`` leg vs its XLA spellings and reference
+math (interpret mode on CPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-from neuroimagedisttraining_tpu.ops.pallas_kernels import (
-    fused_masked_sgd_leaf,
-    fused_masked_sgd_step,
-    fused_weighted_sum,
-)
-
-
-def _ref_update(p, m, g, k, lr, mom, wd, mask_grads):
-    g = np.asarray(g, np.float64)
-    p = np.asarray(p, np.float64)
-    m = np.asarray(m, np.float64)
-    k = np.asarray(k, np.float64)
-    if mask_grads:
-        g = g * k
-    g = g + wd * p
-    m_new = mom * m + g
-    p_new = p - lr * m_new
-    if not mask_grads:
-        p_new = p_new * k
-    return p_new, m_new
-
-
-@pytest.mark.parametrize("shape", [(7,), (5, 3), (4, 4, 4, 2), (300, 7)])
-@pytest.mark.parametrize("mask_grads", [False, True])
-def test_fused_masked_sgd_leaf_matches_reference(shape, mask_grads):
-    rng = np.random.RandomState(0)
-    p = rng.randn(*shape).astype(np.float32)
-    m = rng.randn(*shape).astype(np.float32)
-    g = rng.randn(*shape).astype(np.float32)
-    k = (rng.rand(*shape) > 0.5).astype(np.float32)
-    lr, mom, wd = 0.05, 0.9, 1e-4
-    p2, m2 = fused_masked_sgd_leaf(
-        jnp.asarray(p), jnp.asarray(m), jnp.asarray(g), jnp.asarray(k),
-        lr, momentum=mom, wd=wd, mask_grads=mask_grads)
-    rp, rm = _ref_update(p, m, g, k, lr, mom, wd, mask_grads)
-    np.testing.assert_allclose(np.asarray(p2), rp, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(m2), rm, rtol=1e-5, atol=1e-6)
-    assert p2.shape == shape and p2.dtype == jnp.float32
-
-
-def test_fused_step_pytree():
-    rng = np.random.RandomState(1)
-
-    def tree(f):
-        return {"a": {"kernel": jnp.asarray(f((33, 9))),
-                      "bias": jnp.asarray(f((9,)))},
-                "b": jnp.asarray(f((2, 3, 4)))}
-
-    params = tree(lambda s: rng.randn(*s).astype(np.float32))
-    mom = tree(lambda s: np.zeros(s, np.float32))
-    grads = tree(lambda s: rng.randn(*s).astype(np.float32))
-    mask = tree(lambda s: np.ones(s, np.float32))
-    p2, m2 = fused_masked_sgd_step(params, mom, grads, mask, 0.1,
-                                   momentum=0.9)
-    # plain SGD when mask is all-ones
-    expect = jax.tree_util.tree_map(lambda p, g: p - 0.1 * g, params, grads)
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
-        p2, expect)
-
-
-def test_fused_weighted_sum_matches_einsum():
-    rng = np.random.RandomState(2)
-    stacked = {"w": jnp.asarray(rng.randn(5, 17, 11).astype(np.float32)),
-               "b": jnp.asarray(rng.randn(5, 260).astype(np.float32))}
-    weights = jnp.asarray([0.1, 0.2, 0.3, 0.25, 0.15], jnp.float32)
-    got = fused_weighted_sum(stacked, weights)
-    expect = jax.tree_util.tree_map(
-        lambda x: jnp.einsum("c...,c->...", x, weights), stacked)
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5),
-        got, expect)
-
-
-def test_fused_sgd_preserves_momentum_dtype():
-    """bf16 params + f32 momentum buffer: the buffer must stay f32."""
-    from neuroimagedisttraining_tpu.ops.pallas_kernels import (
-        fused_masked_sgd_leaf,
-    )
-
-    p = jnp.ones((33,), jnp.bfloat16)
-    m = jnp.zeros((33,), jnp.float32)
-    g = jnp.full((33,), 0.5, jnp.float32)
-    mask = jnp.ones((33,), jnp.float32)
-    p2, m2 = fused_masked_sgd_leaf(p, m, g, mask, 0.1, momentum=0.9)
-    assert p2.dtype == jnp.bfloat16
-    assert m2.dtype == jnp.float32
-
-
-def test_fused_kernels_round_matches_xla_round():
-    """--fused_kernels routes the optimizer through the Pallas kernel; a
-    SalientGrads round must produce the same result as the XLA chain
-    (interpret mode on CPU exercises identical kernel code)."""
-    import jax
-    import numpy as np
-
-    from neuroimagedisttraining_tpu.algorithms import SalientGrads
-    from neuroimagedisttraining_tpu.core.state import HyperParams
-    from neuroimagedisttraining_tpu.data import make_synthetic_federated
-    from neuroimagedisttraining_tpu.models import create_model
-
-    data = make_synthetic_federated(
-        n_clients=4, samples_per_client=16, test_per_client=4,
-        sample_shape=(8, 8, 8, 1), loss_type="bce", class_num=2)
-    model = create_model("small3dcnn", num_classes=1)
-    hp = HyperParams(lr=0.05, lr_decay=1.0, momentum=0.9, weight_decay=5e-4,
-                     grad_clip=10.0, local_epochs=1, steps_per_epoch=2,
-                     batch_size=8)
-    a = SalientGrads(model, data, hp, loss_type="bce", frac=1.0, seed=0,
-                     dense_ratio=0.5)
-    b = SalientGrads(model, data, hp, loss_type="bce", frac=1.0, seed=0,
-                     dense_ratio=0.5, fused_kernels=True)
-    sa = a.init_state(jax.random.PRNGKey(0))
-    sb = b.init_state(jax.random.PRNGKey(0))
-    sa, _ = a.run_round(sa, 0)
-    sb, _ = b.run_round(sb, 0)
-    for la, lb in zip(jax.tree_util.tree_leaves(sa.global_params),
-                      jax.tree_util.tree_leaves(sb.global_params)):
-        np.testing.assert_allclose(np.asarray(la), np.asarray(lb),
-                                   rtol=1e-5, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -468,37 +346,10 @@ def _assert_trees_close(got, want, rtol, atol):
 
 
 @pytest.mark.tpu
-def test_tpu_fused_masked_sgd_step_alexnet_tree():
-    """--fused_kernels 1: the optimizer kernel on the whole tree, and
-    under the client vmap the round applies it in."""
-    from neuroimagedisttraining_tpu.core.optim import sgd_momentum_step
-
-    key = jax.random.PRNGKey(0)
-    p, m, g = (_alexnet_tree(jax.random.fold_in(key, i), lead=(2,))
-               for i in range(3))
-    mask = jax.tree_util.tree_map(
-        lambda x: (x > 0).astype(jnp.float32), _alexnet_tree(key, (2,)))
-    lr = jnp.float32(0.05)
-
-    def ref(p, m, g, k):
-        p2, m2 = sgd_momentum_step(p, m, g, lr, 0.9, 5e-4)
-        return jax.tree_util.tree_map(lambda a, b: a * b, p2, k), m2
-
-    def fused(p, m, g, k):
-        return fused_masked_sgd_step(p, m, g, k, lr, momentum=0.9,
-                                     wd=5e-4)
-
-    got = jax.jit(jax.vmap(fused))(p, m, g, mask)
-    want = jax.jit(jax.vmap(ref))(p, m, g, mask)
-    _assert_trees_close(got, want, rtol=1e-6, atol=1e-8)
-
-
-@pytest.mark.tpu
 @pytest.mark.parametrize("c", [8, 32])
-def test_tpu_weighted_sums_are_f32_exact(c):
-    """The pallas weighted sum AND the jnp spelling the round ships
-    (core.state.weighted_tree_sum, a tensordot) against an f64 host
-    reference: on the chip an f32 contraction must not be rounded
+def test_tpu_weighted_tree_sum_is_f32_exact(c):
+    """The weighted sum the round ships (core.state.weighted_tree_sum, a
+    tensordot) against an f64 host reference: on the chip an f32 contraction must not be rounded
     through bf16. Terms are ~1e-2 * w; atol 2e-8 admits the f32 rounding
     of a cancelling c-term sum and sits 50x under one bf16 rounding of a
     term (1e-2 * 2^-9 * w ~ 1e-6 at c=8)."""
@@ -511,8 +362,6 @@ def test_tpu_weighted_sums_are_f32_exact(c):
     want = jax.tree_util.tree_map(
         lambda x: np.tensordot(w64, np.asarray(x, np.float64),
                                axes=1).astype(np.float32), tree)
-    _assert_trees_close(jax.jit(fused_weighted_sum)(tree, w), want,
-                        rtol=2e-6, atol=2e-8)
     _assert_trees_close(jax.jit(weighted_tree_sum)(tree, w), want,
                         rtol=2e-6, atol=2e-8)
 

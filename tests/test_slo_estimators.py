@@ -8,10 +8,15 @@ streams:
 * the WINDOWED estimator is exact — its value equals
   ``np.quantile(window, q, method='linear')`` on the identical trailing
   window, at every step of the stream;
-* the P² estimator (``w=0``, whole-run) stays inside the exact
-  quantile ENVELOPE ``[Q(q - 0.1), Q(q + 0.1)]`` (and the stream's
-  hull) once warm — the documented tolerance of the five-marker
-  approximation;
+* the P² estimator (``w=0``, whole-run) keeps what five markers
+  guarantee for a stream in ANY order: an estimate inside the stream's
+  hull, marker heights in order, marker positions strictly increasing
+  from 1 to the count. Its accuracy claim, the quantile ENVELOPE
+  ``[Q(q - 0.1), Q(q + 0.1)]``, is about streams whose order carries no
+  trend, and is held on seeded i.i.d. streams in tests/test_slo.py: an
+  ordered stream (a long low run after the markers settled high) drags
+  the estimate outside it, and the draw that showed so stays here as
+  an explicit example;
 * the fixed-reservoir estimator is EXACT (nearest-rank) while the
   stream fits its reservoir;
 * all three are deterministic: the same stream yields the same
@@ -29,9 +34,14 @@ import pytest
 # the deterministic shim keeps the properties exercised (weaker — no
 # shrinking — but never a silent skip)
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
 except ImportError:
-    from _hypothesis_fallback import given, settings, strategies as st
+    from _hypothesis_fallback import (
+        example,
+        given,
+        settings,
+        strategies as st,
+    )
 
 from neuroimagedisttraining_tpu.obs.slo import (
     P2Quantile,
@@ -59,25 +69,40 @@ def test_windowed_quantile_exact_on_every_window(data, q, window):
                                    atol=1e-9)
 
 
+#: hypothesis's falsifying draw for the envelope this test used to claim
+#: for every order (q 0.5: estimate -7086204.77 against Q(0.6) =
+#: -7099563.4): high values first, then a long run near -1e7
+_P2_ORDERED_STREAM = [
+    0, 1182461, 1350832, 2313865, 2669832, 2940272, 3016182, 5689917,
+    6750946, -7556906, 9289823, 9486978, 9786083, -9935965, -9936090,
+    -9939302, -9941733, -9942203, -9945070, -9946290, -9949304, -9949527,
+    -9950843, -9952787, -9953513, -9955571, -9960420, -9960518, -9964230,
+    -9965042, -7310061, -7402419, -7494665, -7515060, -7527440, -7527459,
+    -7556738, -9960822, -6783817, -9986292, -9987688, -9967162, -9989517,
+    -9992715, -9992748, -8026393, -9995638, -9999689, -8081538, 6, -5, 5,
+    -4, 4, -3, 3, -2, 2, -1, 1]
+
+
 @settings(max_examples=60, deadline=None)
-@given(data=st.data(), q=st.sampled_from(_QS))
-def test_p2_quantile_within_exact_envelope(data, q):
-    # unique, well-spread samples: the five-marker parabolic update's
-    # tolerance claim is about position error (<= ~1.5 ranks), which
-    # the VALUE envelope [Q(q-0.1), Q(q+0.1)] captures for distinct
-    # values; massive tie collapse is the windowed estimator's job
-    xs = data.draw(st.lists(
-        st.integers(-10_000_000, 10_000_000),
-        min_size=60, max_size=300, unique=True))
+@given(xs=st.lists(st.integers(-10_000_000, 10_000_000),
+                   min_size=60, max_size=300, unique=True),
+       q=st.sampled_from(_QS))
+@example(xs=_P2_ORDERED_STREAM, q=0.5)
+def test_p2_quantile_within_exact_envelope(xs, q):
+    # what the five markers guarantee whatever the order of the stream
+    # (the module docstring has why the quantile envelope is not among it)
     arr = np.asarray(xs, dtype=np.float64)
     est = P2Quantile(q)
-    for x in arr:
+    for n, x in enumerate(arr, 1):
         est.observe(float(x))
-    v = est.value()
-    assert arr.min() <= v <= arr.max()
-    lo = np.quantile(arr, max(0.0, q - 0.1))
-    hi = np.quantile(arr, min(1.0, q + 0.1))
-    assert lo <= v <= hi, (q, v, lo, hi)
+        if n >= 5:
+            assert est._h == sorted(est._h), (n, est._h)
+            assert est._pos[0] == 1.0 and est._pos[4] == float(n)
+            assert all(a < b for a, b in zip(est._pos, est._pos[1:])), \
+                (n, est._pos)
+            assert est._h[0] == arr[:n].min()
+            assert est._h[4] == arr[:n].max()
+    assert arr.min() <= est.value() <= arr.max()
 
 
 @settings(max_examples=60, deadline=None)
