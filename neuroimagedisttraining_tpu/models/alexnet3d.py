@@ -165,9 +165,12 @@ def phased_stem_stage(mdl: nn.Module, x, *, stem_kernel: int, features: int,
             mu_c, sig_c = _group_stats(zf, g, eps)
         # ONE pool on zs = max over window of z for scale>=0 channels,
         # -min for scale<0 channels (flax pads max-pool with -inf, so a
-        # padded pool ring never wins the selection). The pool is told that
-        # zs is conv + bias: its backward then reads the conv output the
-        # conv fusion wrote, not a second copy with the bias added
+        # padded pool ring never wins the selection). The pool's geometry
+        # picks its backward (ops/pool_vjp.py). Disjoint windows (AlexNet3D)
+        # are told that zs is conv + bias: the backward then reads the conv
+        # output the conv fusion wrote, not a second copy with the bias
+        # added. Overlapping ones (ResNet_l3's (3, 2, 1), no conv bias) are
+        # handed zs itself
         with jax.named_scope("pool"):
             m = max_pool3d(zs, kernel=pk, strides=ps, padding=pp,
                            summands=summands)

@@ -15,7 +15,7 @@ from typing import Optional, Sequence, Tuple, Union
 import flax.linen as nn
 import jax.numpy as jnp
 
-from ..ops.pool_vjp import max_pool3d_of_sum
+from ..ops.pool_vjp import max_pool3d_of_sum, max_pool3d_windows
 
 Ints3 = Union[int, Tuple[int, int, int]]
 
@@ -63,16 +63,27 @@ def max_pool3d(x, kernel: Ints3, strides: Ints3, padding: Ints3 = 0, *,
                summands=None):
     """torch MaxPool3d semantics (floor mode) on (N, D, H, W, C).
 
-    ``summands=(c, bias)`` says that the caller computed ``x`` as ``c + bias``
-    (``bias`` per channel). Where the windows do not overlap (``kernel ==
-    strides``, no padding) the backward then needs no ``select-and-scatter``
-    and no copy of ``x`` (ops/pool_vjp.py); the values and the gradient are
-    the same bit for bit. Any other geometry keeps ``lax.reduce_window``'s
-    own VJP."""
+    The values are ``nn.max_pool``'s; what the geometry selects is the
+    backward (ops/pool_vjp.py), which where it can leaves XLA's
+    ``select-and-scatter`` for a kernel with the same first-match gradient:
+
+    * windows that overlap or carry the ``-inf`` ring (``strides <= kernel``
+      and not the plain disjoint case): one primitive for every such
+      geometry, ResNet_l3's ``(3, 2, 1)`` among them;
+    * disjoint windows (``kernel == strides``, no padding) where
+      ``summands=(c, bias)`` says that the caller computed ``x`` as
+      ``c + bias`` (``bias`` per channel): the backward then needs no copy
+      of ``x``; bit-equal to autodiff's;
+    * anything else (disjoint windows of a tensor that is no such sum,
+      strides past the window) keeps ``lax.reduce_window``'s own VJP."""
     k = _triple(kernel)
     s = _triple(strides)
     p = _triple(padding)
-    if summands is not None and k == s and p == (0, 0, 0):
+    if (k, p) != (s, (0, 0, 0)):
+        if x.ndim == 5 and all(si <= ki and pi < ki
+                               for ki, si, pi in zip(k, s, p)):
+            return max_pool3d_windows(x, k, s, p)
+    elif summands is not None:
         c, bias = summands
         if c.dtype == bias.dtype == x.dtype:
             return max_pool3d_of_sum(x, c, bias, k)

@@ -18,8 +18,13 @@ it stays GSPMD's. Pinned here, on the 8 virtual CPU devices at 8^3:
 * a ``space`` axis of 2 and a client count the devices do not divide compile
   that parent's program;
 * a round on one device lowers to the parent's text;
-* lowered for the TPU, the AlexNet3D round on a mesh holds the kernel.
+* lowered for the TPU, the AlexNet3D round on a mesh holds the kernel;
+* lowered for the TPU on one device (ISSUE 32), the ResNet_l3 round holds
+  the overlapping-window kernel under ``stem/pool`` and no
+  ``select_and_scatter``, the AlexNet3D round is ISSUE 32's parent's but for
+  source locations, and ``pool_bwd_lowerings`` counted both choices.
 """
+import base64
 import hashlib
 import re
 
@@ -34,6 +39,7 @@ from neuroimagedisttraining_tpu.algorithms.base import FedAlgorithm
 from neuroimagedisttraining_tpu.core.state import HyperParams
 from neuroimagedisttraining_tpu.data import make_synthetic_federated
 from neuroimagedisttraining_tpu.models import create_model
+from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
 from neuroimagedisttraining_tpu.ops.s2d import phased_sample_shape
 from neuroimagedisttraining_tpu.parallel import make_mesh
 from neuroimagedisttraining_tpu.parallel.mesh import shard_federated_hybrid
@@ -201,6 +207,85 @@ def test_alexnet_round_on_a_mesh_lowered_for_the_tpu(frac, backward):
     # the trunk's two overlapping pools keep XLA's op in either round
     assert "select_and_scatter" in text
     assert ("tpu_custom_call" in text) == (backward == "tpu_custom_call")
+
+
+RESNET = dict(model="3dresnet_s2d", sample_shape=phased_sample_shape(
+    (33, 33, 33), 3, 3))
+MOSAIC_BODY = re.compile(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22')
+# sha256 of the AlexNet3D round below at ISSUE 32's parent (c663281)
+PARENTS_ALEXNET_ROUND = (
+    "935aa0758ae0d43206f2b082adb4d0f34d4896d9e006554ee328fd306b998f99")
+
+
+def without_source_locations(text):
+    """A lowered module's text less its ``loc(...)`` and with every Mosaic
+    kernel, serialised in its custom call with the source lines of every
+    frame that led to it, printed without them."""
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    def kernel(match):
+        context = ir.Context()
+        tpu.register_dialect(context)
+        context.allow_unregistered_dialects = True  # 'stable_mosaic'
+        context.load_all_available_dialects()
+        with context:
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+            return module.operation.get_asm(enable_debug_info=False)
+    return MOSAIC_BODY.sub(kernel, re.sub(r"loc\([^)]*\)", "", text))
+
+
+def names_of(text, ref):
+    """Every name in the chain of locations that ``ref`` (``#loc12``) of a
+    module printed with debug info stands for."""
+    defs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
+    seen, todo, names = set(), [ref], []
+    while todo:
+        ref = todo.pop()
+        if ref not in seen and ref in defs:
+            seen.add(ref)
+            names += re.findall(r'"([^"]*)"', defs[ref])
+            todo += re.findall(r"#loc\d+", defs[ref])
+    return names
+
+
+def one_chip_round_for_the_tpu(**model):
+    # a chip that reports a memory limit maps its clients one at a time
+    algo = build("salientgrads", clients=4, frac=0.5, devices=1,
+                 client_chunk=1, **model)
+    shapes = jax.eval_shape(algo.init_state, jax.random.PRNGKey(3))
+    return lowered_round(algo, shapes, platform="tpu")
+
+
+def test_one_chip_rounds_lowered_for_the_tpu_hold_both_pool_kernels():
+    """``resnet3d_abcd.protocol``'s and ``alexnet3d_abcd.train``'s rounds at
+    CI size (33^3, 69^3), as one chip lowers them."""
+    before = obs_metrics.set_registry(None)
+    try:
+        resnet = one_chip_round_for_the_tpu(**RESNET).as_text(
+            debug_info=True)
+        alexnet = one_chip_round_for_the_tpu(**ALEXNET).as_text()
+        counted = obs_metrics.get_registry().snapshot()[
+            "pool_bwd_lowerings"]["labeled"]
+    finally:
+        obs_metrics.set_registry(before)
+    # ResNet_l3: its one pool is the stem's, (3, 2, 1) on the conv output
+    assert "select_and_scatter" not in resnet
+    assert len(re.findall(r"custom_call @tpu_custom_call", resnet)) == 1
+    # the kernel sits in a function of its own (jitted: traced once for all
+    # the programs of a process), called from under stem/pool in the backward
+    call, = re.findall(r"call @pool_backward\(.*loc\((#loc\d+)\)", resnet)
+    named = names_of(resnet, call)
+    assert any("transpose(jvp(" in n and "stem/pool" in n for n in named), named
+    kernel_fn = resnet[resnet.index("func.func private @pool_backward("):]
+    assert "tpu_custom_call" in kernel_fn[:kernel_fn.index("return")]
+    # AlexNet3D: PR 26's kernel on the stem, XLA's op on the trunk's pools
+    assert alexnet.count("tpu_custom_call") == 1
+    assert "select_and_scatter" in alexnet
+    assert hashlib.sha256(without_source_locations(alexnet).encode()
+                          ).hexdigest() == PARENTS_ALEXNET_ROUND
+    assert counted == {"geometry=3_2_1,spelling=kernel": 1.0,
+                       "geometry=3_3_0,spelling=kernel": 1.0}
 
 
 @pytest.mark.parametrize("algo_name", sorted(ALGOS))
