@@ -1060,7 +1060,8 @@ class FedAlgorithm(abc.ABC):
         # batch_gather, optimizer (core/trainer.py, inside
         # local_train); stem with conv, norm, pool inside it
         # (models/alexnet3d.py:phased_stem_stage, inside the model's
-        # module); embed, attention with full or window inside it, router,
+        # module); embed, attention with full or window or (a selecting
+        # layer's) indexer, select and selected inside it, router,
         # experts, shared_expert, dense_mlp, lm_head (models/decoder.py;
         # lm_head also around the per-token CE in core/losses.py).
         # The forward/backward pass needs none: JAX prints it as

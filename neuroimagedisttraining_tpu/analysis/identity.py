@@ -107,6 +107,8 @@ FLAG_CLASSES: Dict[str, Tuple[str, str]] = {
     "lm_expert_shards": ("identity", "lm...e<E>: experts held"),
     "lm_tensor_shards": ("identity", "lm...t<T>: heads and vocabulary "
                                      "rows held"),
+    "lm_vocab_shards": ("identity", "v<V> after lm...t<T>: vocabulary rows "
+                                    "held where heads divide otherwise"),
     "global_test": ("identity", "'-g' reference-parity tag"),
     "tag": ("identity", "explicit identity suffix"),
     # -- inert (telemetry / logging / placement / scheduling-only) ---------

@@ -55,6 +55,10 @@ def build_parser(algo: Optional[str] = None) -> argparse.ArgumentParser:
     p.add_argument("--lm_tensor_shards", type=int, default=1,
                    help="decoder models: chips that share a layer's heads "
                         "and the vocabulary rows")
+    p.add_argument("--lm_vocab_shards", type=int, default=0,
+                   help="decoder models: chips that share the vocabulary "
+                        "rows where that is not --lm_tensor_shards (0: it "
+                        "is; a model with fewer KV heads than chips)")
     p.add_argument("--data_dir", type=str, default="",
                    help="dataset root (ABCD .h5 path or CIFAR batches dir)")
     p.add_argument("--partition_method", type=str, default="dir",
@@ -967,6 +971,9 @@ def run_identity(args: argparse.Namespace, algo: Optional[str] = None,
         # a chip's share of a decoder model is another model: its depth,
         # its experts and its shapes all change the state's structure
         parts.append("lm{}e{}t{}".format(*share))
+    vocab_shards = getattr(args, "lm_vocab_shards", 0)
+    if vocab_shards and vocab_shards != share[2]:
+        parts.append(f"v{vocab_shards}")
     # defense and fine-tune knobs change training behavior — they must
     # split checkpoint/log/stat_info lineages (unlike inert identity tags)
     if algo == "salientgrads" and getattr(args, "stratified_sampling", 0):

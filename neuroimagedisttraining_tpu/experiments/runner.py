@@ -79,7 +79,8 @@ def _decoder_share(args):
     if args.model.lower() not in decoder.CONFIGS:
         return None, None
     share = dict(layers=args.lm_layers, expert_shards=args.lm_expert_shards,
-                 tensor_shards=args.lm_tensor_shards)
+                 tensor_shards=args.lm_tensor_shards,
+                 vocab_shards=args.lm_vocab_shards)
     return share, decoder.held_config(args.model.lower(),
                                       decoder.Share(**share))
 
@@ -1088,7 +1089,9 @@ def run_experiment(args: argparse.Namespace,
         state = algo.place_state(state)
         if _decoder_share(args)[0] is not None:
             # how the first batch's routed slots fall on the held experts
-            # (two gauges; a model without experts sets none)
+            # and, where a layer selects its keys, how much of the causal
+            # square it keeps (one forward; a model that sows nothing sets
+            # no gauge)
             from ..obs import metrics as obs_metrics
             from ..obs.expert_load import record_expert_load
 

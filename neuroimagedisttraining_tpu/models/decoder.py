@@ -1,26 +1,41 @@
-"""The Laguna decoder family: a causal language model of pre-norm blocks with
-full and sliding-window attention layers of different head counts, a per-head
-output gate, one leading dense SwiGLU MLP and sparse MLPs (a router over all
-published experts, a shared expert) after it.
+"""Decoder language models: causal pre-norm blocks whose layers are read off
+a published ``config.json``. Attention is full, sliding-window, or over the
+keys a learned indexer selects (``sa_config``: each query attends the
+``topk`` keys its indexer scores highest under the causal mask); heads may
+carry a per-head output gate or a QK-norm, rotary angles may come from three
+position streams (``mrope_section``); an MLP is a dense SwiGLU or a sparse
+one (a router over all published experts, with or without a shared expert).
 
-The published configuration is ``CONFIGS["laguna_s"]`` (Laguna-S-2.1,
-https://huggingface.co/poolside/Laguna-S-2.1/blob/main/config.json, keys as
-published); ``laguna_tiny`` keeps its structure at a size the CPU tests run.
+Two families, keys as published: ``CONFIGS["laguna_s"]`` (Laguna-S-2.1,
+https://huggingface.co/poolside/Laguna-S-2.1/blob/main/config.json) and
+``CONFIGS["keye_vl2"]`` (the language model of Keye-VL-2.0-30B-A3B,
+https://huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B/blob/main/config.json;
+its vision tower is not here: the catalog has no width of it);
+``laguna_tiny`` and ``keye_tiny`` keep their structure at a size the CPU
+tests run.
 What ONE CHIP holds of a model is :class:`Share`: the depth kept (leading
 layers; the rest lie on further chips as pipeline stages), over how many
 chips the routed experts of a layer are divided, and over how many the heads
-and the vocabulary. The chip then computes its partial results: its experts'
-part of the routed sum (what the absent experts would add is left out), its
-heads' part of the attention output, logits over its vocabulary rows. Nothing
-here stands in for the absent chips or their traffic; sums over all shares,
-the shared expert counted once, give the uncut layer (tests/test_decoder.py).
+(``tensor_shards``) and the vocabulary (``vocab_shards``). The chip then
+computes its partial results: its experts' part of the routed sum (what the
+absent experts would add is left out), its heads' part of the attention
+output, logits over its vocabulary rows. Nothing here stands in for the
+absent chips or their traffic; sums over all shares, the shared expert
+counted once, give the uncut layer (tests/test_decoder.py).
 
-The config is silent on five things, set by the Qwen-MoE family's convention
-(whose keys it uses) and listed as ``assumed`` in
+Laguna's config is silent on five things, set by the Qwen-MoE family's
+convention (whose keys it uses) and listed as ``assumed`` in
 ``benchmarks/reference/laguna_s.py``, the plain reference the tests hold this
 file to: SwiGLU (silu) MLPs; pre-norm residuals without QK-norm; the router's
 softmax over all logits in float32, then top-k, renormalised, scaled; the
 shared expert added ungated; headwise output gating ``sigmoid(x W_g)``.
+Keye's is silent on the QK-norm (Qwen3-MoE's: an RMSNorm over each head's
+features before the rotary), on how ``mrope_section`` divides the frequency
+pairs, and on the indexer's equations (DeepSeek-V3.2-Exp's, which the
+catalog names): ``benchmarks/reference/keye_vl2.py`` lists them. **The
+indexer reads ``stop_gradient`` of its input and the selection is a hard
+choice, so the token loss gives its weights no gradient; the alignment loss
+that trains it is not here, and it stays as initialised.**
 """
 from __future__ import annotations
 
@@ -32,6 +47,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 _PERIOD = ["full_attention"] + ["sliding_attention"] * 3
 
@@ -117,24 +133,96 @@ CONFIGS = {
         "num_attention_heads_per_layer": [4, 6, 6, 6] * 2,
         "moe_router_logit_softcapping": 0,
     },
+    "keye_vl2": {
+        "attention_bias": False,
+        "decoder_sparse_step": 1,
+        "head_dim": 128,
+        "hidden_act": "silu",
+        "hidden_size": 2048,
+        "intermediate_size": 6144,
+        "max_position_embeddings": 262144,
+        "max_window_layers": 48,
+        "mlp_only_layers": [],
+        "model_type": "KeyeVL2",
+        "moe_intermediate_size": 768,
+        "norm_topk_prob": True,
+        "num_attention_heads": 32,
+        "num_experts": 128,
+        "num_experts_per_tok": 8,
+        "num_hidden_layers": 48,
+        "num_key_value_heads": 4,
+        "num_local_experts": 128,
+        # not a key of the published file, which is silent on it: stated
+        # here as ``gating_types`` states Laguna's gate (Qwen3-MoE's
+        # convention, whose keys the config uses)
+        "qk_norm": True,
+        "rms_norm_eps": 1e-06,
+        "rope_scaling": {"mrope_section": [16, 24, 24],
+                         "rope_type": "default", "type": "default"},
+        "rope_theta": 10000000,
+        "sa_config": {"indexer_head_dim": 64, "indexer_num_heads": 16,
+                      "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+                      "q_chunk_size": 512, "topk": 2048},
+        "sliding_window": None,
+        "tie_word_embeddings": False,
+        "use_sliding_window": False,
+        "vocab_size": 151936,
+    },
+    # the same keys for the CPU tests: 4 KV heads with 8 query heads, 16
+    # experts top-4, an indexer of 4 heads of 8 that keeps 8 keys a query
+    "keye_tiny": {
+        "attention_bias": False,
+        "decoder_sparse_step": 1,
+        "head_dim": 16,
+        "hidden_act": "silu",
+        "hidden_size": 32,
+        "intermediate_size": 64,
+        "max_position_embeddings": 256,
+        "max_window_layers": 6,
+        "mlp_only_layers": [],
+        "model_type": "KeyeVL2",
+        "moe_intermediate_size": 16,
+        "norm_topk_prob": True,
+        "num_attention_heads": 8,
+        "num_experts": 16,
+        "num_experts_per_tok": 4,
+        "num_hidden_layers": 6,
+        "num_key_value_heads": 4,
+        "num_local_experts": 16,
+        "qk_norm": True,
+        "rms_norm_eps": 1e-06,
+        "rope_scaling": {"mrope_section": [2, 3, 3],
+                         "rope_type": "default", "type": "default"},
+        "rope_theta": 100,
+        "sa_config": {"indexer_head_dim": 8, "indexer_num_heads": 4,
+                      "indexer_num_kv_heads": 1, "kv_chunk_size": 4,
+                      "q_chunk_size": 4, "topk": 8},
+        "sliding_window": None,
+        "tie_word_embeddings": False,
+        "use_sliding_window": False,
+        "vocab_size": 64,
+    },
 }
-
 # queries of a full layer are scored in blocks of this many, each against the
 # keys up to its own end, so the scores of a long sequence never exist whole
 FULL_ATTENTION_BLOCK = 1024
-EXPERT_STATS = "expert_stats"   # the collection the sparse MLP sows into
+# the collection the sparse MLP and the selecting attention sow into
+EXPERT_STATS = "expert_stats"
 
 
 @dataclasses.dataclass(frozen=True)
 class Share:
     """What one chip holds: the first ``layers`` layers (0: all), the routed
-    experts divided over ``expert_shards`` chips, heads and vocabulary over
-    ``tensor_shards``; ``index`` is this chip's place among those that share
-    a layer (it picks the held experts' ids; weights are the chip's own)."""
+    experts divided over ``expert_shards`` chips, heads over
+    ``tensor_shards`` and the vocabulary over ``vocab_shards`` (0: as the
+    heads); ``index`` is this chip's place among those that share a layer
+    (it picks the held experts' ids; weights are the chip's own). An
+    indexer is held whole: its score sums over all its heads."""
     layers: int = 0
     expert_shards: int = 1
     tensor_shards: int = 1
     index: int = 0
+    vocab_shards: int = 0
 
 
 def held_config(name: str, share: Share = Share()) -> dict:
@@ -145,10 +233,12 @@ def held_config(name: str, share: Share = Share()) -> dict:
     cfg = dict(CONFIGS[name])
     n = share.layers or cfg["num_hidden_layers"]
     t, e = share.tensor_shards, share.expert_shards
+    v = share.vocab_shards or t
     cut = ("num_hidden_layers", "num_experts", "num_attention_heads",
-           "num_key_value_heads", "vocab_size")
+           "num_key_value_heads", "vocab_size") + (
+               ("num_local_experts",) if "num_local_experts" in cfg else ())
     for key, parts in (("num_attention_heads", t), ("num_key_value_heads", t),
-                       ("vocab_size", t), ("num_experts", e)):
+                       ("vocab_size", v), ("num_experts", e)):
         if cfg[key] % parts:
             raise ValueError(f"{name}: {key} {cfg[key]} does not divide "
                              f"over {parts} chips")
@@ -159,21 +249,29 @@ def held_config(name: str, share: Share = Share()) -> dict:
         num_hidden_layers=n, num_experts=cfg["num_experts"] // e,
         num_attention_heads=cfg["num_attention_heads"] // t,
         num_key_value_heads=cfg["num_key_value_heads"] // t,
-        vocab_size=cfg["vocab_size"] // t,
-        num_attention_heads_per_layer=[
-            h // t for h in cfg["num_attention_heads_per_layer"][:n]])
+        vocab_size=cfg["vocab_size"] // v)
+    if "num_local_experts" in cfg:
+        cfg["num_local_experts"] = cfg["num_experts"]
+    if "num_attention_heads_per_layer" in cfg:
+        cfg["num_attention_heads_per_layer"] = [
+            h // t for h in cfg["num_attention_heads_per_layer"][:n]]
     for key in ("layer_types", "mlp_layer_types", "gating_types"):
-        cfg[key] = cfg[key][:n]
+        if key in cfg:
+            cfg[key] = cfg[key][:n]
     cfg["first_expert"] = (share.index % e) * cfg["num_experts"]
     return cfg
 
 
-def rope_tables(rope: dict, head_dim: int, length: int):
+def rope_tables(rope: dict, head_dim: int, length: int, positions=None):
     """``(cos, sin, rot)``: float32 tables ``[length, rot / 2]`` of one layer
     kind's rotary embedding and the width it turns. ``yarn`` blends each
     frequency between itself (it turns often inside the original context)
     and itself over ``factor`` (it does not), and scales cos and sin by
-    ``attention_factor``."""
+    ``attention_factor``. With ``mrope_section`` the frequency pairs turn by
+    three position streams, section by section (``positions [3, length]``:
+    pair ``i`` of section ``s`` turns by ``positions[s] * freq[i]``); without
+    ``positions`` every stream counts 0, 1, 2, ... (text), which is the
+    one-stream embedding."""
     rot = int(head_dim * rope.get("partial_rotary_factor", 1))
     freq = float(rope["rope_theta"]) ** -(np.arange(0, rot, 2) / rot)
     scale = 1.0
@@ -192,6 +290,12 @@ def rope_tables(rope: dict, head_dim: int, length: int):
         freq = freq * (1 - ramp) + freq / rope["factor"] * ramp
         scale = rope.get("attention_factor") or \
             0.1 * math.log(rope["factor"]) + 1.0
+    if positions is not None:
+        sections = rope.get("mrope_section") or [rot // 2]
+        stream = np.repeat(np.arange(len(sections)), sections)
+        angles = positions.astype(jnp.float32)[stream].T \
+            * jnp.asarray(freq, jnp.float32)[None, :]
+        return jnp.cos(angles) * scale, jnp.sin(angles) * scale, rot
     angles = np.arange(length)[:, None] * freq[None, :]
     return (np.cos(angles) * scale).astype(np.float32), \
         (np.sin(angles) * scale).astype(np.float32), rot
@@ -268,6 +372,106 @@ def window_attention(q, k, v, window: int):
     return out.reshape((b, nb * window) + out.shape[3:])[:, :s_len]
 
 
+def index_scores(q_idx, w_idx, k_idx):
+    """The indexer's scores ``[B, Q, K]``, float32, of the queries ``q_idx
+    [B, Q, J, e]`` with head weights ``w_idx [B, Q, J]`` against the keys
+    ``k_idx [B, K, e]``: ``sum_j w[j] * relu(q[j] . k) / sqrt(J * e)``, one
+    head after another (one product batched over the heads compiles to an
+    executable nearly twice the size: 189 against 106 MB for the round, and
+    the chip machine's compile cache holds 192)."""
+    heads, e = q_idx.shape[-2:]
+    total = 0.0
+    for j in range(heads):
+        dot = jnp.einsum("bqe,bke->bqk", q_idx[:, :, j], k_idx,
+                         preferred_element_type=jnp.float32)
+        total = total + w_idx[:, :, j, None] * jax.nn.relu(dot)
+    return total / math.sqrt(heads * e)
+
+
+def ordered_keys(scores, seen):
+    """``scores`` (float32) as unsigned integers in the scores' own order,
+    -0 as +0; 0, which lies under every real score, where not ``seen``."""
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores), jnp.uint32)
+    keys = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    return jnp.where(seen, keys, jnp.uint32(0))
+
+
+def select_keys(scores, start: int, topk: int):
+    """``keep [B, Q, K]``: for the query at position ``start + q`` the
+    ``topk`` highest of ``scores [B, Q, K]`` over the keys ``k <= start + q``
+    (all of them where there are no more), ties to the lower ``k``: the set
+    ``lax.top_k`` gives under the causal mask, exactly, without a sort. The
+    scores map to unsigned integers in their own order; a row's k-th largest
+    is built from the top bit down (the largest value that ``topk`` of the
+    row reach: 32 counts over the row; two or four bits a pass, with three
+    or fifteen counts in each, took the same time on the chip); what lies
+    above it is kept, and of what equals it the first as many as are still
+    wanted (a running count, computed only where some row has more equals
+    than it wants: exact zeros of the ReLUs are the usual case)."""
+    q_pos = start + jnp.arange(scores.shape[-2])[:, None]
+    causal = jnp.arange(scores.shape[-1])[None, :] <= q_pos
+    keys = ordered_keys(scores, causal)
+
+    def settle(i, kth):
+        trial = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        reach = jnp.sum(keys >= trial[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(reach >= topk, trial, kth)
+
+    kth = jax.lax.fori_loop(0, 32, settle,
+                            jnp.zeros(scores.shape[:-1], jnp.uint32))
+    above, equal = keys > kth[..., None], keys == kth[..., None]
+    wanted = topk - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    # a row of fewer than topk visible keys has kth 0: the masked ones equal it
+    crowded = (jnp.sum(equal, axis=-1, dtype=jnp.int32) > wanted) & (kth > 0)
+    keep = jax.lax.cond(
+        jnp.any(crowded),
+        lambda: above | (equal & (jnp.cumsum(equal, axis=-1, dtype=jnp.int32)
+                                  <= wanted[..., None])),
+        lambda: above | equal)
+    return keep & causal
+
+
+def selected_attention(q, k, v, q_idx, w_idx, k_idx, topk: int,
+                       block=FULL_ATTENTION_BLOCK, want_kept=False):
+    """Causal attention in which a query sees the ``topk`` keys its indexer
+    scores highest (``q [B, S, n, g, d]`` over ``k, v [B, S, n, d]``; the
+    indexer's ``q_idx [B, S, J, e]``, ``w_idx [B, S, J]``, ``k_idx [B, S,
+    e]``), softmax over those keys alone. Computed as the masked product in
+    blocks of queries, each against the keys up to its own end, as
+    :func:`full_attention` is: at a quarter of the causal square kept the
+    product on the MXU costs a quarter of a gather of 2,048 keys a query
+    (one layer forward on the chip, the host's clock around the blocked
+    call: 61 against 267 ms; PERF.md section 6, PR 33). A
+    block whose end is within ``topk`` keeps every visible key and is not
+    scored. The selection and the output carry names under which the
+    decoder's remat keeps them. Returns the output and, with ``want_kept``,
+    the selection ``[B, S, S]``."""
+    s_len = q.shape[1]
+    one = jax.checkpoint(_attend)
+    outs, kept = [], []
+    for i in range(0, s_len, block):
+        end = min(i + block, s_len)
+        if end <= topk:
+            keep = (jnp.arange(end)[None, :]
+                    <= i + jnp.arange(end - i)[:, None])[None]
+        else:
+            with jax.named_scope("indexer"):
+                scores = index_scores(q_idx[:, i:end], w_idx[:, i:end],
+                                      k_idx[:, :end])
+            with jax.named_scope("select"):
+                keep = select_keys(scores, i, topk)
+            keep = checkpoint_name(keep, "selected_keys")
+        with jax.named_scope("selected"):
+            outs.append(one(q[:, i:end], k[:, :end], v[:, :end], keep))
+        if want_kept:
+            kept.append(jnp.pad(
+                jnp.broadcast_to(keep, (q.shape[0],) + keep.shape[1:]),
+                [(0, 0), (0, 0), (0, s_len - end)]))
+    out = checkpoint_name(jnp.concatenate(outs, axis=1), "attended")
+    return out, jnp.concatenate(kept, axis=1) if want_kept else None
+
+
 def _weight(module, name, shape):
     return module.param(name, nn.initializers.normal(0.02), shape)
 
@@ -283,6 +487,45 @@ def _norm_weight(module, name, width):
     return module.param(name, nn.initializers.ones, (width,))
 
 
+def layer_norm(x, w, b, eps: float):
+    """``(x - mean) / sqrt(var + eps) * w + b``, in float32."""
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * w + b).astype(x.dtype)
+
+
+class Indexer(nn.Module):
+    """The learned scorer of a selecting attention layer, held whole by
+    every chip: per position ``heads`` queries and one key of ``head_dim``
+    features (the key through a LayerNorm, both rotated on the temporal
+    stream) and ``heads`` weights. Nothing here is trained by the token
+    loss: it reads ``stop_gradient`` of its input and feeds a hard choice."""
+    heads: int
+    head_dim: int
+    rope_theta: float
+    eps: float
+
+    @nn.compact
+    def __call__(self, x, positions=None):
+        b, s_len, hidden = x.shape
+        j, e = self.heads, self.head_dim
+        x = jax.lax.stop_gradient(x)
+        q = (x @ _weight(self, "q_proj", (hidden, j * e))).reshape(
+            b, s_len, j, e)
+        k = layer_norm(
+            x @ _weight(self, "k_proj", (hidden, e)),
+            _norm_weight(self, "k_norm_scale", e),
+            self.param("k_norm_bias", nn.initializers.zeros, (e,)), self.eps)
+        w = (x @ _weight(self, "weights_proj", (hidden, j))).astype(
+            jnp.float32)
+        cos, sin, rot = rope_tables(
+            {"rope_theta": self.rope_theta, "rope_type": "default"}, e,
+            s_len, None if positions is None else positions[:1])
+        return jax.lax.stop_gradient(
+            (_rotate(q, cos, sin, rot), w, _rotate(k, cos, sin, rot)))
+
+
 class Attention(nn.Module):
     """The held heads' part of one attention layer's output."""
     kind: str
@@ -291,9 +534,13 @@ class Attention(nn.Module):
     head_dim: int
     window: int
     rope: Tuple     # the layer kind's rope_parameters as sorted items
+    gate: bool = True       # a per-head output gate
+    qk_norm: bool = False   # an RMSNorm over each head's q and k features
+    eps: float = 1e-6       # of the QK-norm and the indexer's LayerNorm
+    indexer: Tuple = ()     # sa_config as sorted items, where it selects
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         b, s_len, hidden = x.shape
         n, d = self.kv_heads, self.head_dim
         group = self.q_heads // n
@@ -301,20 +548,43 @@ class Attention(nn.Module):
             q = x @ _weight(self, "q_proj", (hidden, self.q_heads * d))
             k = x @ _weight(self, "k_proj", (hidden, n * d))
             v = x @ _weight(self, "v_proj", (hidden, n * d))
-            gate = jax.nn.sigmoid(
-                (x @ _weight(self, "gate_proj", (hidden, self.q_heads))
-                 ).astype(jnp.float32))
-            cos, sin, rot = rope_tables(dict(self.rope), d, s_len)
-            q = _rotate(q.reshape(b, s_len, n, group, d), cos, sin, rot)
-            k = _rotate(k.reshape(b, s_len, n, d), cos, sin, rot)
+            if self.gate:
+                gate = jax.nn.sigmoid(
+                    (x @ _weight(self, "gate_proj", (hidden, self.q_heads))
+                     ).astype(jnp.float32))
+            cos, sin, rot = rope_tables(_thaw(self.rope), d, s_len, positions)
+
+            def turned(a, name):
+                if self.qk_norm:
+                    a = rms_norm(a, _norm_weight(self, name, d), self.eps)
+                return _rotate(a, cos, sin, rot)
+
+            q = turned(q.reshape(b, s_len, n, group, d), "q_norm")
+            k = turned(k.reshape(b, s_len, n, d), "k_norm")
             v = v.reshape(b, s_len, n, d)
             if self.kind == "sliding_attention":
                 with jax.named_scope("window"):
                     out = window_attention(q, k, v, self.window)
+            elif self.kind == "selected_attention":
+                sa = _thaw(self.indexer)
+                with jax.named_scope("indexer"):
+                    scorer = Indexer(
+                        sa["indexer_num_heads"], sa["indexer_head_dim"],
+                        _thaw(self.rope)["rope_theta"], self.eps,
+                        name="indexer")(x, positions)
+                # free unless the caller opens the collection
+                # (obs/selection.py)
+                want = self.is_mutable_collection(EXPERT_STATS)
+                out, kept = selected_attention(q, k, v, *scorer, sa["topk"],
+                                               want_kept=want)
+                if want:
+                    self.sow(EXPERT_STATS, "selected_keys", kept)
             else:
                 with jax.named_scope("full"):
                     out = full_attention(q, k, v)
-            out = out * gate.reshape(b, s_len, n, group, 1).astype(out.dtype)
+            if self.gate:
+                out = out * gate.reshape(b, s_len, n, group, 1).astype(
+                    out.dtype)
             return out.reshape(b, s_len, -1) @ _weight(
                 self, "o_proj", (self.q_heads * d, hidden))
 
@@ -419,7 +689,8 @@ def routed_part(rows: int, chunks: int, tokens, w, order, slot_weight, sizes,
 
 class SparseMLP(nn.Module):
     """A router over all published experts, the held experts' part of the
-    routed sum, and the shared expert.
+    routed sum, and the shared expert where the model has one
+    (``shared_width`` > 0).
 
     Every token is routed over all ``n_experts`` logits with the published
     top-k, renormalisation and scale. Of its k slots those that fall on the
@@ -463,7 +734,8 @@ class SparseMLP(nn.Module):
                                          self.top_k)
             if self.renormalise:
                 top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-            top_p = top_p * self.scale
+            if self.scale != 1:
+                top_p = top_p * self.scale
             local = (top_e - self.first_expert).reshape(-1)
             local = jnp.where((local >= 0) & (local < self.held), local,
                               self.held)          # not held here: sorts last
@@ -476,12 +748,43 @@ class SparseMLP(nn.Module):
             routed = routed_part(*self.buffer_rows(tokens.shape[0]), tokens,
                                  w, order, top_p.reshape(-1), sizes,
                                  self.top_k)
-        with jax.named_scope("shared_expert"):
-            shared = SwiGLU(self.shared_width, name="shared_expert")(tokens)
+        if self.shared_width:
+            with jax.named_scope("shared_expert"):
+                shared = SwiGLU(self.shared_width, name="shared_expert")(
+                    tokens)
+            routed = routed + shared
         # free unless the caller opens the collection (obs/expert_load.py)
         self.sow(EXPERT_STATS, "held_counts", sizes)
         self.sow(EXPERT_STATS, "top_experts", top_e)
-        return (routed + shared).reshape(x.shape)
+        return routed.reshape(x.shape)
+
+
+def layer_plan(cfg: dict, layer: int) -> dict:
+    """What layer ``layer`` of the held configuration ``cfg`` is, from the
+    keys the config has: the attention ``kind`` (``layer_types``; without
+    it ``selected_attention`` where the config brings an ``sa_config``, else
+    full), its query ``heads``, its ``rope`` parameters (``rope_parameters``
+    by kind, or ``rope_theta`` with ``rope_scaling``), whether a per-head
+    ``gate`` and a ``qk_norm`` are there, and whether the MLP is ``sparse``
+    (``mlp_layer_types``; without it Qwen-MoE's rule of ``mlp_only_layers``
+    and ``decoder_sparse_step``)."""
+    n = cfg["num_hidden_layers"]
+    kind = cfg["layer_types"][layer] if "layer_types" in cfg else (
+        "selected_attention" if cfg.get("sa_config") else "full_attention")
+    if "mlp_layer_types" in cfg:
+        sparse = cfg["mlp_layer_types"][layer] == "sparse"
+    else:
+        sparse = layer not in cfg["mlp_only_layers"] and (
+            layer + 1) % cfg["decoder_sparse_step"] == 0
+    return {
+        "kind": kind, "sparse": sparse,
+        "heads": cfg.get("num_attention_heads_per_layer",
+                         [cfg["num_attention_heads"]] * n)[layer],
+        "rope": cfg["rope_parameters"][kind] if "rope_parameters" in cfg
+        else {"rope_theta": cfg["rope_theta"], "rope_type": "default",
+              **(cfg.get("rope_scaling") or {})},
+        "gate": cfg.get("gating_types", [None] * n)[layer] == "per_head",
+        "qk_norm": cfg.get("qk_norm", False)}
 
 
 class Block(nn.Module):
@@ -489,33 +792,35 @@ class Block(nn.Module):
     layer: int
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         cfg = _thaw(self.cfg)
-        kind = cfg["layer_types"][self.layer]
+        plan = layer_plan(cfg, self.layer)
         eps, hidden = cfg["rms_norm_eps"], x.shape[-1]
         x = x + Attention(
-            kind, cfg["num_attention_heads_per_layer"][self.layer],
-            cfg["num_key_value_heads"], cfg["head_dim"],
-            cfg["sliding_window"], _freeze(cfg["rope_parameters"][kind]),
-            name="attention")(
-                rms_norm(x, _norm_weight(self, "attn_norm", hidden), eps))
+            plan["kind"], plan["heads"], cfg["num_key_value_heads"],
+            cfg["head_dim"], cfg["sliding_window"] or 0,
+            _freeze(plan["rope"]), plan["gate"], plan["qk_norm"], eps,
+            _freeze(cfg.get("sa_config") or {}), name="attention")(
+                rms_norm(x, _norm_weight(self, "attn_norm", hidden), eps),
+                positions)
         h = rms_norm(x, _norm_weight(self, "mlp_norm", hidden), eps)
-        if cfg["mlp_layer_types"][self.layer] == "dense":
+        if not plan["sparse"]:
             with jax.named_scope("dense_mlp"):
                 return x + SwiGLU(cfg["intermediate_size"], name="mlp")(h)
         return x + SparseMLP(
             cfg["published"]["num_experts"], cfg["num_experts_per_tok"],
-            cfg["norm_topk_prob"], cfg["moe_routed_scaling_factor"],
+            cfg["norm_topk_prob"], cfg.get("moe_routed_scaling_factor", 1),
             cfg["first_expert"], cfg["num_experts"],
             cfg["moe_intermediate_size"],
-            cfg["shared_expert_intermediate_size"], name="mlp")(h)
+            cfg.get("shared_expert_intermediate_size", 0), name="mlp")(h)
 
 
 class Decoder(nn.Module):
     """``tokens [B, S]`` int32 (ids of the held vocabulary rows) ->
-    float32 logits ``[B, S, V_held]``. Each block is rematerialised
-    (``nn.remat``): the backward pass keeps the blocks' inputs and computes
-    one block's activations at a time."""
+    float32 logits ``[B, S, V_held]``; ``positions [3, S]`` where the rotary
+    streams differ (none: text, every stream counts the tokens). Each block
+    is rematerialised (``nn.remat``): the backward pass keeps the blocks'
+    inputs and computes one block's activations at a time."""
     cfg: Tuple
 
     @property
@@ -523,14 +828,32 @@ class Decoder(nn.Module):
         return _thaw(self.cfg)["vocab_size"]
 
     @nn.compact
-    def __call__(self, tokens, train: bool = False):
+    def __call__(self, tokens, train: bool = False, positions=None):
         cfg = _thaw(self.cfg)
         hidden, vocab = cfg["hidden_size"], cfg["vocab_size"]
         with jax.named_scope("embed"):
             x = jnp.take(_weight(self, "embed", (vocab, hidden)), tokens,
                          axis=0)
+        if positions is None and "mrope_section" in (
+                cfg.get("rope_scaling") or {}):
+            # text: the three streams count the tokens. Given as streams
+            # the compiler may not fold, the rotary tables are computed on
+            # the device; as constants they were 73 MB of the round's 106 MB
+            # executable at 16,384 tokens
+            positions = jax.lax.optimization_barrier(jnp.broadcast_to(
+                jnp.arange(tokens.shape[1]), (3, tokens.shape[1])))
+        where = () if positions is None else (positions,)
+        block = nn.remat(Block)
+        if cfg.get("sa_config"):
+            # a block's remat keeps the selection it made (S^2 / 2 bytes a
+            # layer) and what the heads attended to: going backward it
+            # neither scores nor chooses again, and computes the masked
+            # product once more, not twice
+            block = nn.remat(Block, policy=jax.checkpoint_policies
+                             .save_only_these_names("selected_keys",
+                                                    "attended"))
         for i in range(cfg["num_hidden_layers"]):
-            x = nn.remat(Block)(self.cfg, i, name=f"layers_{i}")(x)
+            x = block(self.cfg, i, name=f"layers_{i}")(x, *where)
         x = rms_norm(x, _norm_weight(self, "final_norm", hidden),
                      cfg["rms_norm_eps"])
         with jax.named_scope("lm_head"):

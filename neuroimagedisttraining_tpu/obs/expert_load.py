@@ -4,7 +4,8 @@ The decoder's sparse MLP (``models/decoder.py:SparseMLP``) sows, into the
 collection ``expert_stats``, the number of routed slots that fell on each
 held expert and every token's chosen experts; both are free unless a caller
 opens the collection. :func:`record_expert_load` runs one forward of a batch
-with it open and sets two gauges in a metrics registry:
+with it open and sets two gauges in a metrics registry (and a third where a
+layer selects its keys: :mod:`.selection`):
 
 ``expert_load_max_over_mean``
     the fullest held expert's slots over the mean of the held experts',
@@ -20,46 +21,55 @@ import numpy as np
 COLLECTION = "expert_stats"
 
 
+def sown_by_depth(sown, name: str) -> list:
+    """What the decoder's layers sowed under ``name``, in depth order."""
+    import jax
+
+    found = []
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+            sown.get(COLLECTION, {})):
+        keys = [getattr(k, "key", None) for k in path]
+        if name in keys:
+            found.append((next((int(k.rpartition("_")[2]) for k in keys
+                                if isinstance(k, str)
+                                and k.startswith("layers_")), 0), leaf))
+    return [leaf for _, leaf in sorted(found, key=lambda t: t[0])]
+
+
 def stacked_stats(sown) -> dict:
     """``{"held_counts": [layers, held], "top_experts": [layers, tokens,
     k]}`` from the collections a forward returned, the sparse layers in
     depth order; ``{}`` where the model sowed no such statistics."""
-    import jax
     import jax.numpy as jnp
 
-    found = {"held_counts": [], "top_experts": []}
-    for path, leaf in jax.tree_util.tree_leaves_with_path(
-            sown.get(COLLECTION, {})):
-        keys = [getattr(k, "key", None) for k in path]
-        depth = next((int(k.rpartition("_")[2]) for k in keys
-                      if isinstance(k, str) and k.startswith("layers_")), 0)
-        for name in found:
-            if name in keys:
-                found[name].append((depth, leaf))
+    found = {name: sown_by_depth(sown, name)
+             for name in ("held_counts", "top_experts")}
     if not found["held_counts"]:
         return {}
-    return {name: jnp.stack([leaf for _, leaf in
-                             sorted(leaves, key=lambda t: t[0])])
-            for name, leaves in found.items()}
-
-
-def expert_stats(apply_fn, params, x) -> dict:
-    """:func:`stacked_stats` of one forward of ``x`` through ``apply_fn``
-    (eval mode)."""
-    _, sown = apply_fn(params, x, train=False, rng=None,
-                       mutable=[COLLECTION])
-    return stacked_stats(sown)
+    return {name: jnp.stack(leaves) for name, leaves in found.items()}
 
 
 def record_expert_load(algo, params, registry) -> dict:
     """Gauges of the first training batch of client 0 (``hp.batch_size``
-    rows) through ``algo``'s model; ``{}`` and no gauge for a model without
-    experts. Returns what it set."""
+    rows) through ``algo``'s model, from ONE forward with the collection
+    open: the two above and, where a layer selects its keys,
+    ``selected_key_share`` (:mod:`.selection`). ``{}`` and no gauge for a
+    model that sows nothing. Returns what it set."""
     import jax
 
+    from .selection import (key_share, set_selected_key_share,
+                            stacked_selection)
+
+    def gauges(p, x):
+        _, sown = algo.apply_fn(p, x, train=False, rng=None,
+                                mutable=[COLLECTION])
+        kept = stacked_selection(sown)
+        return stacked_stats(sown), None if kept is None else key_share(kept)
+
     x = algo.data.x_train[0, :algo.hp.batch_size]
-    stats = jax.jit(lambda p, x: expert_stats(algo.apply_fn, p, x))(params, x)
-    return set_expert_load(stats, registry)
+    stats, share = jax.jit(gauges)(params, x)
+    return {**set_expert_load(stats, registry),
+            **set_selected_key_share(share, registry)}
 
 
 def set_expert_load(stats: dict, registry) -> dict:

@@ -70,6 +70,55 @@ def test_a_family_without_a_cell_loads_builds_and_checks(tmp_path,
         == "benchmarks.families.tokens"
 
 
+def test_the_selecting_family_loads_builds_and_checks(tmp_path):
+    """One case of the ``tokens_selected`` family (the whole rehearsal is
+    benchmarks/tests/test_tokens_selected_family.py): a tiny configuration
+    of it under the cell's own traffic mix is found by name, builds through
+    the program's entry points with ``--lm_vocab_shards``, and passes its
+    own reference check: routing and selection the reference's, the indexer
+    not moved by the round."""
+    bench = _bench_conftest()
+    from benchmarks.lib import harness, manifest
+    from neuroimagedisttraining_tpu.experiments import parse_args
+    from neuroimagedisttraining_tpu.models import decoder
+
+    family = manifest.family_of({"family": "tokens_selected"})
+    held = decoder.held_config("keye_tiny", decoder.Share(4, 4, 2, 0, 4))
+    config = {
+        "name": "tiny_selected", "source": "test fixture",
+        "family": "tokens_selected", "reference": "keye_vl2",
+        "published": held.pop("published"),
+        "first_expert": held.pop("first_expert"),
+        "held": {k: held.pop(k) for k in family.HELD_KEYS},
+        "flags": {"algo": "fedavg", "model": "keye_tiny", "lm_layers": 4,
+                  "lm_expert_shards": 4, "lm_tensor_shards": 2,
+                  "lm_vocab_shards": 4, "dataset": "token_shards",
+                  "track_personal": 0, "client_chunk": 1, "batch_size": 1,
+                  "epochs": 1, "lr": 0.5, "momentum": 0.0, "grad_clip": 10.0},
+        "cohort": {"n_sites": 8, "train_per_site": 1, "test_per_site": 1,
+                   "sequence_length": 32},
+        **held}
+    assert set(held) <= family.CONFIG_KEYS
+    path = bench.write_manifest(tmp_path, config, (("longctx", 1),))
+    cell = manifest.load_cell(path, "tiny_selected.longctx")
+    assert cell.family is family
+    algo = harness.build(cell, parse_args(harness.program_flags(cell, 3)), 3)
+    assert algo.data.x_train.shape == (8, 1, 32)
+    assert algo.clients_per_round == 2 and algo.data.class_num == 16
+    state = algo.init_state(jax.random.PRNGKey(3))
+    report = family.reference_check(
+        algo, state.global_params, harness.reference_of(cell), cell.config)
+    assert report["ok"], report
+    assert set(family.TOLERANCE) < set(report)
+    assert report["selection"]["error"] == 0.0 == report["routing"]["error"]
+    assert report["indexer_q_proj"]["error"] == 0.0
+    assert 0.3 < report["expert_load"]["selected_key_share"] < 0.5
+    with pytest.raises(ValueError, match="unknown key"):
+        manifest.load_cell(bench.write_manifest(
+            tmp_path / "bad", {**config, "rope_parameters": {}},
+            (("longctx", 1),)), "tiny_selected.longctx")
+
+
 # ---------------------------------------------------------------------------
 # the static guard
 # ---------------------------------------------------------------------------

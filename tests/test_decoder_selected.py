@@ -1,0 +1,371 @@
+"""The decoder whose queries attend the keys a learned indexer selects
+(models/decoder.py, ``keye_tiny``: 4 KV heads with 8 query heads, 16 experts
+top-4, an indexer of 4 heads of 8 that keeps 8 keys a query) held to its plain
+reference (benchmarks/reference/keye_vl2.py) on seeded weights, at a length
+over ``topk`` so that the selection cuts."""
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.reference import keye_vl2 as ref
+from neuroimagedisttraining_tpu.core.losses import PER_EXAMPLE_LOSSES
+from neuroimagedisttraining_tpu.models import (
+    create_model, decoder, init_params, make_apply_fn)
+from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
+from neuroimagedisttraining_tpu.obs.expert_load import record_expert_load
+from neuroimagedisttraining_tpu.obs import selection as obs_selection
+
+TINY = "keye_tiny"
+SHARE = decoder.Share(layers=4, expert_shards=4, tensor_shards=2,
+                      vocab_shards=4)
+SEQ, TOPK = 32, 8
+
+
+def _scaled(params, scale=8.0):
+    """The matrices scaled up so that attention, the indexer and the router
+    are far from their trivial values; the norms' weights moved off 1."""
+    return jax.tree_util.tree_map(
+        lambda a: a * scale if a.ndim > 1 else a
+        + 0.1 * jax.random.normal(jax.random.PRNGKey(a.size), a.shape),
+        params)
+
+
+def _close(got, want, tol=2e-5):
+    got, want = np.asarray(got), np.asarray(want)
+    scale = max(1.0, float(np.max(np.abs(want))))
+    assert float(np.max(np.abs(got - want))) <= tol * scale
+
+
+def _attention(cfg, q_heads, kv_heads):
+    plan = decoder.layer_plan(cfg, 0)
+    assert plan["kind"] == "selected_attention" and plan["qk_norm"]
+    assert not plan["gate"] and plan["sparse"]
+    return decoder.Attention(
+        plan["kind"], q_heads, kv_heads, cfg["head_dim"], 0,
+        decoder._freeze(plan["rope"]), False, True, cfg["rms_norm_eps"],
+        decoder._freeze(cfg["sa_config"]))
+
+
+def test_whole_model_logits_loss_gradients_and_an_indexer_left_alone():
+    cfg = decoder.held_config(TINY, SHARE)
+    model = decoder.decoder(TINY, SHARE)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, SEQ), 0,
+                                cfg["vocab_size"])
+    targets = jnp.concatenate(
+        [tokens[:, 1:], jnp.full_like(tokens[:, :1], -1)], axis=1)
+    params = _scaled(model.init(jax.random.PRNGKey(0), tokens)["params"])
+    apply_fn = make_apply_fn(model)
+    loss = PER_EXAMPLE_LOSSES["token_ce"]
+
+    def system(p):
+        logits = apply_fn(p, tokens, train=True, rng=jax.random.PRNGKey(0))
+        return jnp.mean(loss(logits, targets)), logits
+
+    def plain(p):
+        out = [ref.loss_and_logits(p, tokens[b], targets[b], cfg,
+                                   cfg["first_expert"]) for b in range(2)]
+        return (out[0][0] + out[1][0]) / 2, (
+            jnp.stack([o[1][0] for o in out]),
+            jnp.stack([jnp.stack(o[1][2]) for o in out], axis=1))
+
+    (s_loss, s_logits), s_grad = jax.value_and_grad(system, has_aux=True)(
+        params)
+    (r_loss, (r_logits, r_kept)), r_grad = jax.value_and_grad(
+        plain, has_aux=True)(ref.from_system(params))
+    _close(s_logits, r_logits)
+    _close(s_loss, r_loss)
+    # the selection cuts, and is the reference's, pair for pair
+    _, sown = apply_fn(params, tokens, train=False, rng=None,
+                       mutable=[decoder.EXPERT_STATS])
+    kept = obs_selection.stacked_selection(sown)
+    assert kept.shape == (4, 2, SEQ, SEQ)
+    np.testing.assert_array_equal(kept, r_kept)
+    np.testing.assert_array_equal(
+        kept.sum(-1), np.broadcast_to(np.minimum(np.arange(SEQ) + 1, TOPK),
+                                      (4, 2, SEQ)))
+    s_leaves = jax.tree_util.tree_leaves_with_path(ref.from_system(s_grad))
+    r_leaves = jax.tree_util.tree_leaves(r_grad)
+    assert len(s_leaves) == len(r_leaves) == 4 * 17 + 3
+    for (path, got), want in zip(s_leaves, r_leaves):
+        name = jax.tree_util.keystr(path)
+        if "indexer" in name:       # no gradient reaches it: exactly zero
+            assert not np.any(got) and not np.any(want), name
+            continue
+        err = np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-12)
+        assert err < 1e-4, (name, err)
+        assert np.linalg.norm(want) > 0, name
+
+
+def _plain_selected(q, k, v, q_idx, w_idx, k_idx, topk):
+    """The masked full product, the mask scattered from ``lax.top_k``."""
+    s_len = q.shape[1]
+    dots = jnp.einsum("bqje,bke->bqjk", q_idx, k_idx)
+    scores = jnp.sum(w_idx[..., None] * jax.nn.relu(dots), axis=2) \
+        / math.sqrt(q_idx.shape[2] * q_idx.shape[3])
+    causal = jnp.tril(jnp.ones((s_len, s_len), bool))
+    _, top = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf),
+                           min(topk, s_len))
+    keep = jnp.zeros(scores.shape, bool).at[
+        jnp.arange(2)[:, None, None], jnp.arange(s_len)[None, :, None],
+        top].set(True) & causal
+    att = jnp.einsum("bqngd,bknd->bngqk", q, k) / math.sqrt(q.shape[-1])
+    probs = jax.nn.softmax(jnp.where(keep[:, None, None], att, -jnp.inf), -1)
+    return jnp.einsum("bngqk,bknd->bqngd", probs, v), keep
+
+
+@pytest.mark.parametrize("ties", [False, True], ids=["scores", "all_tied"])
+@pytest.mark.parametrize("seq,block", [(32, 8), (32, 12), (32, 64), (30, 8),
+                                       (7, 4)])
+def test_selection_in_blocks_is_the_exact_top_k(seq, block, ties):
+    """``selected_attention`` with its queries in blocks (aligned or not,
+    one block, a length under ``topk``) against the masked full product over
+    an exact top-k; where every score ties (head weights of zero) the lower
+    keys win on both sides."""
+    keys = jax.random.split(jax.random.PRNGKey(seq + block), 6)
+    q = jax.random.normal(keys[0], (2, seq, 2, 3, 16))
+    k, v = (jax.random.normal(key, (2, seq, 2, 16)) for key in keys[1:3])
+    q_idx = jax.random.normal(keys[3], (2, seq, 4, 8))
+    k_idx = jax.random.normal(keys[4], (2, seq, 8))
+    w_idx = jax.random.normal(keys[5], (2, seq, 4)) * (0.0 if ties else 1.0)
+    out, kept = decoder.selected_attention(q, k, v, q_idx, w_idx, k_idx,
+                                           TOPK, block=block, want_kept=True)
+    want, keep = _plain_selected(q, k, v, q_idx, w_idx, k_idx, TOPK)
+    np.testing.assert_array_equal(kept, keep)
+    _close(out, want)
+    if ties and seq > 20:
+        np.testing.assert_array_equal(kept[0, 20], np.arange(seq) < TOPK)
+    assert decoder.selected_attention(q, k, v, q_idx, w_idx, k_idx, TOPK,
+                                      block=block)[1] is None
+
+
+def test_attention_layer_against_the_reference():
+    cfg = decoder.held_config(TINY)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, SEQ, cfg["hidden_size"]))
+    layer = _attention(cfg, 8, 4)
+    p = _scaled(layer.init(jax.random.PRNGKey(3), x)["params"])
+    out, sown = layer.apply({"params": p}, x, mutable=[decoder.EXPERT_STATS])
+    for b in range(2):
+        want, keep = ref.attention(p, x[b], cfg)
+        _close(out[b], want)
+        np.testing.assert_array_equal(
+            sown[decoder.EXPERT_STATS]["selected_keys"][0][b], keep)
+        # the reference's own loop over blocks of queries, recomputed or not
+        for kwargs in ({"q_block": 8}, {"q_block": 8, "remat": True},
+                       {"q_block": 12}):
+            again, keep_again = ref.attention(p, x[b], cfg, **kwargs)
+            _close(again, want, tol=1e-6)
+            np.testing.assert_array_equal(keep_again, keep)
+        full, seen = ref.attention(p, x[b], cfg, select=False)
+        np.testing.assert_array_equal(seen, np.tril(np.ones((SEQ, SEQ))))
+        assert float(jnp.max(jnp.abs(full - want))) > 1e-2
+
+
+def test_unequal_position_streams_against_the_references_rotary():
+    """Three different streams: the frequency pairs turn section by section
+    (2, 3, 3 pairs here), the indexer on the temporal stream; equal streams
+    are the one-stream embedding the tables give."""
+    cfg = decoder.held_config(TINY, SHARE)
+    model = decoder.decoder(TINY, SHARE)
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (1, SEQ), 0,
+                                cfg["vocab_size"])
+    params = _scaled(model.init(jax.random.PRNGKey(5), tokens)["params"])
+    t = jnp.arange(SEQ, dtype=jnp.float32)
+    streams = jnp.stack([t, t // 4, t % 4])
+    got = model.apply({"params": params}, tokens, positions=streams)
+    want, _, _ = ref.forward(ref.from_system(params), tokens[0], cfg,
+                             positions=streams)
+    _close(got[0], want)
+    text = model.apply({"params": params}, tokens)
+    assert float(jnp.max(jnp.abs(text - got))) > 1e-3
+    _close(model.apply({"params": params}, tokens,
+                       positions=ref.text_positions(SEQ)), text, tol=1e-5)
+    # the sections by hand: pairs 0-1 stream 0, 2-4 stream 1, 5-7 stream 2
+    rope = decoder.layer_plan(cfg, 0)["rope"]
+    assert rope["mrope_section"] == [2, 3, 3] and rope["rope_theta"] == 100
+    cos, _, rot = decoder.rope_tables(rope, 16, SEQ, streams)
+    assert rot == 16
+    for pair, stream in enumerate([0, 0, 1, 1, 1, 2, 2, 2]):
+        np.testing.assert_allclose(
+            cos[:, pair], np.cos(np.asarray(streams[stream])
+                                 * 100.0 ** (-2 * pair / 16)), atol=1e-6)
+
+
+def test_shares_add_up_to_the_uncut_layer():
+    """One layer's 4 head shares (each with the whole indexer, so each makes
+    the same selection) and 8 expert shares: the attention parts add up to
+    the uncut reference's attention, the routed parts to its sparse MLP; the
+    indexer and the selection are counted once."""
+    cfg = decoder.held_config(TINY)
+    d, hidden = cfg["head_dim"], cfg["hidden_size"]
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, SEQ, hidden))
+    whole = _attention(cfg, 8, 4)
+    p = _scaled(whole.init(jax.random.PRNGKey(7), x)["params"])
+    want = jnp.stack([ref.attention(p, x[b], cfg)[0] for b in range(2)])
+    total, selections = 0.0, []
+    for s in range(4):      # KV head s with its two query heads
+        q, kv = slice(2 * s * d, 2 * (s + 1) * d), slice(s * d, (s + 1) * d)
+        share = {**p, "q_proj": p["q_proj"][:, q], "o_proj": p["o_proj"][q],
+                 "k_proj": p["k_proj"][:, kv], "v_proj": p["v_proj"][:, kv]}
+        part, sown = _attention(cfg, 2, 1).apply(
+            {"params": share}, x, mutable=[decoder.EXPERT_STATS])
+        total = total + part
+        selections.append(sown[decoder.EXPERT_STATS]["selected_keys"][0])
+    _close(total, want)
+    for kept in selections[1:]:
+        np.testing.assert_array_equal(kept, selections[0])
+    # the sparse MLP: no shared expert, no scale
+    mlp_cfg = decoder.held_config(TINY, decoder.Share(expert_shards=8))
+
+    def sparse(first, held):
+        return decoder.SparseMLP(16, cfg["num_experts_per_tok"], True, 1,
+                                 first, held, cfg["moe_intermediate_size"], 0)
+
+    p = jax.tree_util.tree_map(
+        lambda a: a * 8.0, sparse(0, 16).init(jax.random.PRNGKey(8), x)[
+            "params"])
+    assert set(p) == {"router", "experts"}
+    flat = x.reshape(-1, hidden)
+    uncut, _ = ref.sparse_mlp(p, flat, cfg)
+    total = 0.0
+    for s in range(8):
+        held = {**p, "experts": {k: w[2 * s:2 * s + 2]
+                                 for k, w in p["experts"].items()}}
+        part = sparse(2 * s, 2).apply({"params": held}, x)
+        _close(part.reshape(flat.shape),
+               ref.sparse_mlp(held, flat, mlp_cfg, first_expert=2 * s)[0])
+        total = total + part
+    _close(total.reshape(flat.shape), uncut)
+    assert mlp_cfg["num_experts"] == mlp_cfg["num_local_experts"] == 2
+
+
+@pytest.mark.parametrize("seq", [6, TOPK, 20, SEQ])
+def test_selected_key_share_against_its_closed_form(seq):
+    """The gauge of ``obs/selection.py``, set by the program's own one
+    forward beside the expert-load gauges; at a length of at most ``topk`` every visible key is kept and
+    the layer IS full attention."""
+    from neuroimagedisttraining_tpu.algorithms import FedAvg
+    from neuroimagedisttraining_tpu.core.trainer import HyperParams
+    from neuroimagedisttraining_tpu.data.tokens import make_token_shards
+
+    cfg = decoder.held_config(TINY, SHARE)
+    data = make_token_shards(0, n_clients=2, vocab=cfg["vocab_size"],
+                             sequence_length=seq, train_per_client=1)
+    algo = FedAvg(decoder.decoder(TINY, SHARE), data,
+                  HyperParams(lr=0.1, local_epochs=1, steps_per_epoch=1,
+                              batch_size=1),
+                  loss_type="token_ce", seed=0, client_chunk=1,
+                  track_personal=False)
+    params = _scaled(algo.init_state(jax.random.PRNGKey(0)).global_params)
+    registry = obs_metrics.MetricsRegistry()
+    got = record_expert_load(algo, params, registry)
+    k = min(TOPK, seq)
+    want = (k * (k + 1) / 2 + (seq - k) * k) / (seq * (seq + 1) / 2)
+    assert set(got) == {"selected_key_share", "expert_load_max_over_mean",
+                        "held_slot_share"}
+    assert got["selected_key_share"] == pytest.approx(want, abs=1e-6)
+    assert set(registry.snapshot()) == set(got)
+    assert registry.snapshot()["selected_key_share"]["value"] == got[
+        "selected_key_share"]
+    assert (want == 1.0) == (seq <= TOPK)
+    assert ref.selected_pairs(seq, TOPK) == round(want * seq * (seq + 1) / 2)
+    # the published sizes: 23.44 % of the causal square at 16,384 tokens
+    assert ref.selected_pairs(16384, 2048) / (16384 * 16385 / 2) \
+        == pytest.approx(0.2344, abs=5e-5)
+    if seq <= TOPK:
+        x = jax.random.normal(jax.random.PRNGKey(9), (2, seq,
+                                                      cfg["hidden_size"]))
+        full_cfg = decoder.held_config(TINY)
+        layer = _attention(full_cfg, 8, 4)
+        p = _scaled(layer.init(jax.random.PRNGKey(10), x)["params"])
+        plan = decoder.layer_plan(full_cfg, 0)
+        full = decoder.Attention(
+            "full_attention", 8, 4, 16, 0, decoder._freeze(plan["rope"]),
+            False, True, full_cfg["rms_norm_eps"])
+        rest = {k: v for k, v in p.items() if k != "indexer"}
+        np.testing.assert_array_equal(layer.apply({"params": p}, x),
+                                      full.apply({"params": rest}, x))
+    # a model that selects nothing sets no gauge
+    assert obs_selection.set_selected_key_share(None, registry) == {}
+
+
+def test_held_config_with_vocab_shards_and_lagunas_unchanged():
+    cfg = decoder.held_config("keye_vl2", decoder.Share(
+        4, 8, 4, vocab_shards=8))
+    pub = decoder.CONFIGS["keye_vl2"]
+    for width in ("hidden_size", "head_dim", "intermediate_size",
+                  "moe_intermediate_size", "num_experts_per_tok", "sa_config",
+                  "rope_scaling", "rope_theta"):
+        assert cfg[width] == pub[width]
+    assert {k: cfg[k] for k in cfg["published"]} == {
+        "num_hidden_layers": 4, "num_experts": 16, "num_local_experts": 16,
+        "num_attention_heads": 8, "num_key_value_heads": 1,
+        "vocab_size": 18992}
+    assert cfg["published"] == {k: pub[k] for k in cfg["published"]}
+    assert decoder.held_config("keye_vl2", decoder.Share(
+        4, 8, 4, index=11, vocab_shards=8))["first_expert"] == 48
+    # without vocab_shards the vocabulary goes as the heads do
+    assert decoder.held_config("keye_vl2", decoder.Share(4, 8, 4))[
+        "vocab_size"] == 151936 // 4
+    with pytest.raises(ValueError, match="does not divide"):   # 4 KV heads
+        decoder.held_config("keye_vl2", decoder.Share(4, 8, 8))
+    model = create_model("keye_vl2", num_classes=18992, layers=4,
+                         expert_shards=8, tensor_shards=4, vocab_shards=8)
+    shapes = jax.eval_shape(lambda: init_params(
+        model, jax.random.PRNGKey(0), (16,), jnp.int32))
+    assert sum(a.size for a in jax.tree_util.tree_leaves(shapes)) \
+        == 408_768_000
+    indexer = shapes["layers_3"]["attention"]["indexer"]
+    assert sum(a.size for a in jax.tree_util.tree_leaves(indexer)) \
+        == 2_261_120
+    # Laguna's cut, key for key, as the rule stood before vocab_shards
+    for name, share in (("laguna_s", decoder.Share(5, 32, 8)),
+                        ("laguna_s", decoder.Share()),
+                        ("laguna_tiny", decoder.Share()),
+                        ("laguna_tiny", decoder.Share(5, 4, 2, index=3))):
+        pub = decoder.CONFIGS[name]
+        n = share.layers or pub["num_hidden_layers"]
+        t, e = share.tensor_shards, share.expert_shards
+        want = dict(pub)
+        want["published"] = {k: pub[k] for k in (
+            "num_hidden_layers", "num_experts", "num_attention_heads",
+            "num_key_value_heads", "vocab_size")}
+        want.update(
+            num_hidden_layers=n, num_experts=pub["num_experts"] // e,
+            num_attention_heads=pub["num_attention_heads"] // t,
+            num_key_value_heads=pub["num_key_value_heads"] // t,
+            vocab_size=pub["vocab_size"] // t,
+            num_attention_heads_per_layer=[
+                h // t for h in pub["num_attention_heads_per_layer"][:n]],
+            layer_types=pub["layer_types"][:n],
+            mlp_layer_types=pub["mlp_layer_types"][:n],
+            gating_types=pub["gating_types"][:n],
+            first_expert=(share.index % e) * (pub["num_experts"] // e))
+        assert decoder.held_config(name, share) == want
+    plan = decoder.layer_plan(decoder.held_config("laguna_s"), 1)
+    assert (plan["kind"], plan["heads"], plan["gate"], plan["qk_norm"],
+            plan["sparse"]) == ("sliding_attention", 72, True, False, True)
+    assert not decoder.layer_plan(decoder.held_config("laguna_s"), 0)["sparse"]
+    assert math.isclose(plan["rope"]["rope_theta"], 10000)
+
+
+@pytest.mark.parametrize("stated", [True, False, None])
+def test_qk_norm_is_read_from_the_configs_key_not_the_models_name(stated):
+    """``qk_norm`` is a key of the configuration, carried through the cut:
+    the same ``model_type`` without it (or with it false) has no QK-norm,
+    and another type with it has one."""
+    assert decoder.held_config(TINY, SHARE)["qk_norm"] is True
+    cfg = {k: v for k, v in decoder.held_config(TINY, SHARE).items()
+           if k != "qk_norm"}
+    cfg["model_type"] = "KeyeVL2" if not stated else "another"
+    if stated is not None:
+        cfg["qk_norm"] = stated
+    assert decoder.layer_plan(cfg, 0)["qk_norm"] is bool(stated)
+    model = decoder.Decoder(decoder._freeze(cfg))
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, SEQ), jnp.int32)))["params"]
+    attention = shapes["layers_0"]["attention"]
+    assert ("q_norm" in attention) == ("k_norm" in attention) == bool(stated)

@@ -217,6 +217,45 @@ def test_folding_round_of_the_decoder_holds_its_scopes():
                 asked.update(layers.values())
     assert asked == {"attention", "attention/full", "attention/window",
                      "router", "experts", "lm_head", "dense_mlp",
-                     "shared_expert", "embed"}
-    for scope in asked:
+                     "shared_expert", "embed"} | set(SELECTING_SCOPES)
+    for scope in asked - set(SELECTING_SCOPES):
         assert count(names, scope) > 0, scope
+
+
+SELECTING_SCOPES = ("attention/indexer", "attention/select",
+                    "attention/selected")
+
+
+def test_folding_round_of_the_selecting_decoder_holds_its_scopes():
+    """The tiny selecting decoder's compiled round at a length over
+    ``topk``: the indexer and the choice of the keys in the forward pass and
+    its recomputation (nothing of them is differentiated), the attention
+    over the selection in both passes, and none of the scopes of layers
+    this model has none of."""
+    from neuroimagedisttraining_tpu.data.tokens import make_token_shards
+    from neuroimagedisttraining_tpu.models import decoder
+
+    share = decoder.Share(4, 4, 2, 0, 4)
+    data = make_token_shards(0, n_clients=4, vocab=16, sequence_length=32,
+                             train_per_client=1)
+    hp = HyperParams(lr=0.05, local_epochs=1, steps_per_epoch=1,
+                     batch_size=1)
+    algo = FedAvg(decoder.decoder("keye_tiny", share), data, hp,
+                  loss_type="token_ce", frac=0.5, seed=3, client_chunk=1,
+                  track_personal=False)
+    state = algo.init_state(jax.random.PRNGKey(3))
+    compiled = algo._round_jit.lower(
+        state, jnp.arange(2, dtype=jnp.int32), jnp.asarray(0, jnp.float32),
+        data.x_train, data.y_train, data.n_train).compile()
+    names = op_names(compiled.as_text())
+    for scope in ("local_train", "aggregate", "embed", "attention", "router",
+                  "experts", "lm_head") + SELECTING_SCOPES:
+        assert count(names, scope) > 0, scope
+    for scope in ("attention", "attention/selected", "router", "experts"):
+        assert count(names, scope, "fwd") > 0, (scope, "forward")
+        assert count(names, scope, "bwd") > 0, (scope, "backward")
+    for scope in ("attention/full", "attention/window", "shared_expert",
+                  "dense_mlp"):
+        assert count(names, scope) == 0, scope
+    assert count(names, "indexer") == count(names, "attention/indexer")
+    assert count(names, "select") == count(names, "attention/select")
