@@ -637,10 +637,15 @@ class FedAlgorithm(abc.ABC):
         otherwise. Entry points donated here must return (or pass
         through) every input-state leaf so XLA can alias each donated
         buffer to an output — an unmatched donated leaf degrades to a
-        copy-with-warning, never to corruption."""
-        if not self._donate:
-            return jax.jit(fn)
-        return jax.jit(fn, donate_argnums=donate)
+        copy-with-warning, never to corruption. A model may bring what
+        its programs ask of the TPU's compiler (``tpu_compiler_options``:
+        the selecting decoder's, models/decoder.py); no other backend
+        knows the names."""
+        kwargs = {} if not self._donate else {"donate_argnums": donate}
+        options = getattr(self.model, "tpu_compiler_options", None)
+        if options and jax.default_backend() == "tpu":
+            kwargs["compiler_options"] = dict(options)
+        return jax.jit(fn, **kwargs)
 
     def cost_trained_clients_per_round(self) -> int:
         """Client training passes one round actually runs (cost accounting).

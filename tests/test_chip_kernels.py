@@ -1,4 +1,5 @@
-"""The stem pool's two backward kernels compiled by the chip's own compiler
+"""The stem pool's two backward kernels and the selecting attention's three
+(ISSUE 34) compiled by the chip's own compiler
 at the benchmark's shapes, for a v5e that is described, not attached
 (``on-chip-measurement`` guide, section 2.3). Interpret mode passes what
 Mosaic refuses: a strided load of 16-bit data, a DMA slice of a memref whose
@@ -9,13 +10,16 @@ The topology is described inside a fixture of this one file and nowhere at
 import time: only one process may load the TPU's library.
 """
 import contextlib
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from neuroimagedisttraining_tpu.ops import pool_vjp
+from benchmarks.lib import scopes
+from neuroimagedisttraining_tpu.models import decoder
+from neuroimagedisttraining_tpu.ops import masked_attention, pool_vjp
 
 
 @pytest.fixture(scope="module")
@@ -45,7 +49,10 @@ def no_compile_cache():
 
 
 def compiled_text(fn, one_chip, *shapes):
-    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+    """``shapes``: bfloat16 unless a dtype comes last in one."""
+    args = [jax.ShapeDtypeStruct(s[:-1], s[-1], sharding=one_chip)
+            if not isinstance(s[-1], int)
+            else jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
             for s in shapes]
     with no_compile_cache():
         return jax.jit(fn).lower(*args).compile().as_text()
@@ -68,3 +75,71 @@ def test_alexnet_stems_disjoint_kernel_compiles_for_the_chip(one_chip):
         one_chip, (16, 59, 71, 59, 64), (64,), (16, 19, 23, 19, 64),
         (16, 19, 23, 19, 64))
     assert "tpu_custom_call" in text
+
+
+# keye_vl2_fed.longctx: 16,384 tokens, 8 query heads on 1 KV head of 128
+ATTENTION = dict(q=(1, 16384, 1, 8, 128), kv=(1, 16384, 1, 128),
+                 keep=(1, 16384, 16384, jnp.int8),
+                 lse=(1, 1, 8, 1, 16384, jnp.float32))
+
+
+def test_selected_attentions_forward_kernel_compiles_for_the_chip(one_chip):
+    a = ATTENTION
+    text = compiled_text(
+        lambda q, k, v, keep: masked_attention._forward_pallas(
+            q, k, v, keep, tiles=masked_attention._TILES),
+        one_chip, a["q"], a["kv"], a["kv"], a["keep"])
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+
+
+def test_selected_attentions_dq_and_dkv_kernels_compile_for_the_chip(
+        one_chip):
+    a = ATTENTION
+    text = compiled_text(
+        lambda *args: masked_attention._backward_pallas(
+            *args, tiles=masked_attention._TILES),
+        one_chip, a["q"], a["kv"], a["kv"], a["keep"], a["q"], a["lse"],
+        a["q"])
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+
+
+def test_compiled_kernels_stand_under_the_callers_scope(one_chip):
+    """The benchmark joins a kernel's time to ``attention/selected`` by the
+    ``op_name`` of the COMPILED program. The kernels are jitted functions
+    the layers share, whose lowered body knows no caller: the compiler
+    composes the names, in both passes."""
+    def grads(q, k, v, keep):
+        def loss(q, k, v):
+            with jax.named_scope("attention"), jax.named_scope("selected"):
+                out = masked_attention.masked_attention(
+                    q, k, v, keep, decoder._attend, "selected")
+            return out.astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = compiled_text(grads, one_chip, (1, 1024, 1, 8, 128),
+                         (1, 1024, 1, 128), (1, 1024, 1, 128),
+                         (1, 1024, 1024, jnp.int8))
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(scopes.direction(n) for n in names) == ["bwd", "bwd", "fwd"]
+    for name in names:
+        assert scopes.under(name, "attention/selected"), name
+        assert name.endswith("/pallas_call"), name
+
+
+def test_the_selecting_decoders_compiler_options_are_known_to_the_chip(
+        one_chip):
+    """``Decoder.tpu_compiler_options`` reach ``jax.jit`` only where the
+    backend is a TPU: a name the chip's compiler does not know would fail
+    every program there and none here."""
+    share = decoder.Share(layers=1, expert_shards=4, tensor_shards=2,
+                          vocab_shards=4)
+    options = decoder.decoder("keye_tiny", share).tpu_compiler_options
+    assert options
+    x = jax.ShapeDtypeStruct((256, 256), jnp.bfloat16, sharding=one_chip)
+    with no_compile_cache():
+        jax.jit(lambda a: a @ a).lower(x).compile(compiler_options=options)
+    with no_compile_cache(), pytest.raises(Exception):
+        jax.jit(lambda a: a @ a).lower(x).compile(
+            compiler_options={"xla_tpu_no_such_option": True})

@@ -259,3 +259,56 @@ def test_folding_round_of_the_selecting_decoder_holds_its_scopes():
         assert count(names, scope) == 0, scope
     assert count(names, "indexer") == count(names, "attention/indexer")
     assert count(names, "select") == count(names, "attention/select")
+
+
+@pytest.mark.parametrize("platform,spelling,products", [
+    ("cpu", "xla", ("dot_general", "dot_general")),
+    ("tpu", "kernel", ("jit(attention_forward)", "jit(attention_backward)"))])
+def test_selected_attention_keeps_its_names_whatever_its_lowering(
+        platform, spelling, products):
+    """``attention_selected_ms_per_round`` and ``selected_attention_roofline``
+    read ``attention/selected`` in both passes. The masked product is a
+    ``custom_vjp`` over two primitives with a lowering per target
+    (ops/masked_attention.py): in the folding round of a selecting decoder
+    whose heads and sequence the kernels take (128 wide, 512 tokens), each
+    must leave its products under that name, forward and backward, and
+    count itself in ``attention_lowerings``. The kernels are one jitted
+    function a pass, shared by the layers: the lowered text has the call
+    under the scope, and the compiler puts the callee's ``pallas_call``
+    behind it (tests/test_chip_kernels.py reads that out of a compile)."""
+    from neuroimagedisttraining_tpu.data.tokens import make_token_shards
+    from neuroimagedisttraining_tpu.models import decoder
+    from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
+
+    cfg = decoder.held_config("keye_tiny", decoder.Share(2, 4, 2, 0, 4))
+    cfg = dict(cfg, head_dim=128, rope_scaling=dict(
+        cfg["rope_scaling"], mrope_section=[16, 24, 24]))
+    data = make_token_shards(0, n_clients=4, vocab=16, sequence_length=512,
+                             train_per_client=1)
+    hp = HyperParams(lr=0.05, local_epochs=1, steps_per_epoch=1,
+                     batch_size=1)
+    algo = FedAvg(decoder.Decoder(decoder._freeze(cfg)), data, hp,
+                  loss_type="token_ce", frac=0.5, seed=3, client_chunk=1,
+                  track_personal=False)
+    state = algo.init_state(jax.random.PRNGKey(3))
+    before = obs_metrics.set_registry(None)
+    try:
+        lowered = algo._round_jit.trace(
+            state, jnp.arange(2, dtype=jnp.int32),
+            jnp.asarray(0, jnp.float32), data.x_train, data.y_train,
+            data.n_train).lower(lowering_platforms=(platform,))
+        counted = obs_metrics.get_registry().snapshot()[
+            "attention_lowerings"]["labeled"]
+    finally:
+        obs_metrics.set_registry(before)
+    assert counted == {f"kind=selected,pass={p},spelling={spelling}": 1.0
+                       for p in ("forward", "backward")}
+    names = op_names(lowered.as_text(debug_info=True))
+    for direction, product in zip(("fwd", "bwd"), products):
+        under = {n.rsplit("/", 1)[-1] for n in names
+                 if scopes.under(n, "attention/selected")
+                 and scopes.direction(n) == direction}
+        assert product in under, (direction, under)
+    for scope in ("attention/indexer", "attention/select"):
+        assert count(names, scope) > 0, scope
+        assert count(names, scope, "bwd") == 0, scope
