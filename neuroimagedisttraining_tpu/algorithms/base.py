@@ -1066,9 +1066,10 @@ class FedAlgorithm(abc.ABC):
         # local_train); stem with conv, norm, pool inside it
         # (models/alexnet3d.py:phased_stem_stage, inside the model's
         # module); embed, attention with full or window or (a selecting
-        # layer's) indexer, select and selected inside it, router,
-        # experts, shared_expert, dense_mlp, lm_head (models/decoder.py;
-        # lm_head also around the per-token CE in core/losses.py).
+        # layer's) indexer, select and selected inside it, short_conv (a
+        # conv layer's whole operator), router, experts, shared_expert,
+        # dense_mlp, lm_head (models/decoder.py; lm_head also around the
+        # per-token CE in core/losses.py).
         # The forward/backward pass needs none: JAX prints it as
         # the jvp()/transpose(jvp()) wrapper of the op_name.
         # benchmarks/metrics/*.json read these scopes BY NAME from the

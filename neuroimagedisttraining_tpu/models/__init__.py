@@ -113,7 +113,8 @@ def make_apply_fn(model, compute_dtype=None, channel_inject=False) -> ApplyFn:
     inputs are cast on entry so every conv/matmul runs on the MXU in
     bfloat16 (~2.6x step throughput on AlexNet3D at full ABCD resolution);
     outputs are cast back to float32 so losses, gradients accumulated into
-    the f32 masters, and eval metrics keep full precision.
+    the f32 masters, and eval metrics keep full precision. Leaves the model
+    names in ``float32_leaves`` are not cast.
 
     ``channel_inject`` appends the trailing channel axis at apply time (the
     reference's per-batch ``x.unsqueeze(1)``, ``my_model_trainer.py:199``).
@@ -125,12 +126,18 @@ def make_apply_fn(model, compute_dtype=None, channel_inject=False) -> ApplyFn:
     """
     import jax.numpy as jnp
 
+    # leaves a model names as ``float32_leaves`` keep their type (the
+    # decoder's selection bias: it decides a choice among float32 scores)
+    kept = frozenset(getattr(model, "float32_leaves", ()))
+
     def _cast_in(tree):
-        return jax.tree_util.tree_map(
-            lambda a: a.astype(compute_dtype)
-            if jnp.issubdtype(a.dtype, jnp.floating) else a,
-            tree,
-        )
+        def cast(path, a):
+            named = path and getattr(path[-1], "key", None) in kept
+            if named or not jnp.issubdtype(a.dtype, jnp.floating):
+                return a
+            return a.astype(compute_dtype)
+
+        return jax.tree_util.tree_map_with_path(cast, tree)
 
     def _cast_out(tree):
         return jax.tree_util.tree_map(

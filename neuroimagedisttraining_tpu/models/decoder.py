@@ -1,27 +1,39 @@
 """Decoder language models: causal pre-norm blocks whose layers are read off
-a published ``config.json``. Attention is full, sliding-window, or over the
-keys a learned indexer selects (``sa_config``: each query attends the
-``topk`` keys its indexer scores highest under the causal mask); heads may
-carry a per-head output gate or a QK-norm, rotary angles may come from three
-position streams (``mrope_section``); an MLP is a dense SwiGLU or a sparse
-one (a router over all published experts, with or without a shared expert).
+a published ``config.json``. A layer's operator is one of four kinds: full
+attention, sliding-window attention, attention over the keys a learned
+indexer selects (``sa_config``: each query attends the ``topk`` keys its
+indexer scores highest under the causal mask), or a gated short convolution
+(``conv`` in ``layer_types``: two elementwise gates around a causal depthwise
+convolution of ``conv_L_cache`` taps, no nonlinearity, no positions). Heads
+may carry a per-head output gate or a QK-norm, rotary angles may come from
+three position streams (``mrope_section``); an MLP is a dense SwiGLU or a
+sparse one: a router over all published experts, with or without a shared
+expert, that routes by one of two rules: softmax over all logits, the top-k,
+renormalised; or a sigmoid of each logit, the top-k of the scores plus a
+selection bias that enters the choice only, the scores renormalised by ``sum
++ 1e-6``. Embedding and head are two leaves, or one where the config ties
+them.
 
-Two families, keys as published: ``CONFIGS["laguna_s"]`` (Laguna-S-2.1,
-https://huggingface.co/poolside/Laguna-S-2.1/blob/main/config.json) and
+Three families, keys as published: ``CONFIGS["laguna_s"]`` (Laguna-S-2.1,
+https://huggingface.co/poolside/Laguna-S-2.1/blob/main/config.json),
 ``CONFIGS["keye_vl2"]`` (the language model of Keye-VL-2.0-30B-A3B,
 https://huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B/blob/main/config.json;
-its vision tower is not here: the catalog has no width of it);
-``laguna_tiny`` and ``keye_tiny`` keep their structure at a size the CPU
-tests run.
+its vision tower is not here: the catalog has no width of it) and
+``CONFIGS["lfm2_8b_a1b"]`` (LFM2-8B-A1B,
+https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json);
+``laguna_tiny``, ``keye_tiny`` and ``lfm2_tiny`` keep their structure at a
+size the CPU tests run. What a layer is comes from the keys a config has
+(:func:`layer_plan`), never from a model's name.
 What ONE CHIP holds of a model is :class:`Share`: the depth kept (leading
 layers; the rest lie on further chips as pipeline stages), over how many
 chips the routed experts of a layer are divided, and over how many the heads
-(``tensor_shards``) and the vocabulary (``vocab_shards``). The chip then
-computes its partial results: its experts' part of the routed sum (what the
-absent experts would add is left out), its heads' part of the attention
-output, logits over its vocabulary rows. Nothing here stands in for the
-absent chips or their traffic; sums over all shares, the shared expert
-counted once, give the uncut layer (tests/test_decoder.py).
+and conv channels (``tensor_shards``) and the vocabulary (``vocab_shards``).
+The chip then computes its partial results: its experts' part of the routed
+sum (what the absent experts would add is left out), its heads' part of the
+attention output, its channels' part of a conv operator's output, logits
+over its vocabulary rows. Nothing here stands in for the absent chips or
+their traffic; sums over all shares, the shared expert counted once, give
+the uncut layer (tests/test_decoder.py, tests/test_decoder_lfm2.py).
 
 Laguna's config is silent on five things, set by the Qwen-MoE family's
 convention (whose keys it uses) and listed as ``assumed`` in
@@ -36,6 +48,17 @@ catalog names): ``benchmarks/reference/keye_vl2.py`` lists them. **The
 indexer reads ``stop_gradient`` of its input and the selection is a hard
 choice, so the token loss gives its weights no gradient; the alignment loss
 that trains it is not here, and it stays as initialised.**
+LFM2's row gives the keys and not the equations: the block's order, the conv
+operator's split order and taps, the QK-norm before the rotary, the sigmoid
+router with its bias in the choice only and the tied head are transformers'
+``modeling_lfm2_moe.py``'s, listed in ``benchmarks/reference/lfm2_moe.py``;
+``qk_norm``, ``scoring_func`` and ``tie_word_embeddings`` are stated as keys
+of the entry beside the published ones, and read as keys.
+**The selection bias (``expert_bias``) is a float32 leaf of the state (it
+stays float32 beside a bfloat16 compute copy: ``float32_leaves``), so the
+fold and a checkpoint carry it, but nothing here maintains it: pre-training's
+load-balancing update outside the gradient is left out, the token loss gives
+it no gradient, and it stays as initialised.**
 """
 from __future__ import annotations
 
@@ -52,6 +75,10 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops.masked_attention import SAVED_NAMES, masked_attention
 
 _PERIOD = ["full_attention"] + ["sliding_attention"] * 3
+# LFM2-8B-A1B's ``layer_types``: two conv layers, then ``full conv conv conv``
+# four times, and a tail whose last attention layer comes a layer early
+_LFM2_LAYERS = ["conv"] * 2 + (["full_attention"] + ["conv"] * 3) * 4 + [
+    "full_attention", "conv", "conv", "full_attention", "conv", "conv"]
 
 CONFIGS = {
     "laguna_s": {
@@ -204,6 +231,65 @@ CONFIGS = {
         "use_sliding_window": False,
         "vocab_size": 64,
     },
+    "lfm2_8b_a1b": {
+        "conv_L_cache": 3,
+        "conv_bias": False,
+        "hidden_size": 2048,
+        "intermediate_size": 7168,
+        "layer_types": _LFM2_LAYERS,
+        "max_position_embeddings": 128000,
+        "model_type": "lfm2_moe",
+        "moe_intermediate_size": 1792,
+        "norm_eps": 1e-05,
+        "norm_topk_prob": True,
+        "num_attention_heads": 32,
+        "num_dense_layers": 2,
+        "num_experts": 32,
+        "num_experts_per_tok": 4,
+        "num_hidden_layers": 24,
+        "num_key_value_heads": 8,
+        # the next, ``scoring_func`` and ``tie_word_embeddings`` are no keys
+        # of the published file, which is silent on all three: stated here
+        # as Keye's ``qk_norm`` is (transformers' ``Lfm2MoeAttention`` norms
+        # q and k a head before the rotary; ``Lfm2MoeSparseMoeBlock`` scores
+        # by a sigmoid of each logit, under the name DeepSeek-V3's config
+        # gives that rule; ``Lfm2MoeConfig`` ties the head by default)
+        "qk_norm": True,
+        "rope_theta": 1000000,
+        "routed_scaling_factor": 1,
+        "scoring_func": "sigmoid",
+        "tie_word_embeddings": True,
+        "use_expert_bias": True,
+        "vocab_size": 65536,
+    },
+    # the same keys for the CPU tests: the published pattern's first eight
+    # kinds, 2 dense layers, 4 KV heads with 8 query heads of 8, 16 experts
+    # top-4 with a selection bias, kernel 3
+    "lfm2_tiny": {
+        "conv_L_cache": 3,
+        "conv_bias": False,
+        "hidden_size": 64,
+        "intermediate_size": 128,
+        "layer_types": _LFM2_LAYERS[:8],
+        "max_position_embeddings": 256,
+        "model_type": "lfm2_moe",
+        "moe_intermediate_size": 32,
+        "norm_eps": 1e-05,
+        "norm_topk_prob": True,
+        "num_attention_heads": 8,
+        "num_dense_layers": 2,
+        "num_experts": 16,
+        "num_experts_per_tok": 4,
+        "num_hidden_layers": 8,
+        "num_key_value_heads": 4,
+        "qk_norm": True,
+        "rope_theta": 100,
+        "routed_scaling_factor": 1,
+        "scoring_func": "sigmoid",
+        "tie_word_embeddings": True,
+        "use_expert_bias": True,
+        "vocab_size": 64,
+    },
 }
 # queries of a full layer are scored in blocks of this many, each against the
 # keys up to its own end, so the scores of a long sequence never exist whole
@@ -219,7 +305,10 @@ class Share:
     ``tensor_shards`` and the vocabulary over ``vocab_shards`` (0: as the
     heads); ``index`` is this chip's place among those that share a layer
     (it picks the held experts' ids; weights are the chip's own). An
-    indexer is held whole: its score sums over all its heads."""
+    indexer is held whole: its score sums over all its heads. A conv
+    layer's channels go as the heads do: a chip holds the same ``hidden_size
+    / tensor_shards`` channels of the three streams of ``in_proj``, of the
+    taps and of ``out_proj``'s rows, and computes its part of the output."""
     layers: int = 0
     expert_shards: int = 1
     tensor_shards: int = 1
@@ -227,11 +316,40 @@ class Share:
     vocab_shards: int = 0
 
 
+def _sparse(cfg: dict, layer: int) -> bool:
+    """Whether layer ``layer``'s MLP is sparse: ``mlp_layer_types``; without
+    it ``num_dense_layers`` leading dense layers; without that Qwen-MoE's
+    rule of ``mlp_only_layers`` and ``decoder_sparse_step``."""
+    if "mlp_layer_types" in cfg:
+        return cfg["mlp_layer_types"][layer] == "sparse"
+    if "num_dense_layers" in cfg:
+        return layer >= cfg["num_dense_layers"]
+    return layer not in cfg["mlp_only_layers"] and (
+        layer + 1) % cfg["decoder_sparse_step"] == 0
+
+
+def least_layers(cfg: dict) -> int:
+    """The fewest leading layers that are still the model of a config with
+    ``layer_types``: its leading dense layers and one whole period of the
+    kinds that follow (the shortest stretch that holds every kind of the
+    list and comes again right after itself, as far as the list goes)."""
+    kinds = cfg["layer_types"]
+    dense = next((i for i in range(len(kinds)) if _sparse(cfg, i)),
+                 len(kinds))
+    rest = kinds[dense:]
+    for period in range(1, len(rest)):
+        if set(rest[:period]) == set(kinds) and all(
+                a == b for a, b in zip(rest[:period], rest[period:])):
+            return dense + period
+    return len(kinds)
+
+
 def held_config(name: str, share: Share = Share()) -> dict:
     """The published configuration ``name`` with the counts ``share`` holds
     in place of the published ones (no width changes), the published counts
     under ``published`` and the held experts' first id under
-    ``first_expert``."""
+    ``first_expert``; where the model has conv layers (``conv_L_cache``)
+    the channels a chip holds of each under ``conv_channels``."""
     cfg = dict(CONFIGS[name])
     n = share.layers or cfg["num_hidden_layers"]
     t, e = share.tensor_shards, share.expert_shards
@@ -239,13 +357,21 @@ def held_config(name: str, share: Share = Share()) -> dict:
     cut = ("num_hidden_layers", "num_experts", "num_attention_heads",
            "num_key_value_heads", "vocab_size") + (
                ("num_local_experts",) if "num_local_experts" in cfg else ())
-    for key, parts in (("num_attention_heads", t), ("num_key_value_heads", t),
-                       ("vocab_size", v), ("num_experts", e)):
+    divided = [("num_attention_heads", t), ("num_key_value_heads", t),
+               ("vocab_size", v), ("num_experts", e)]
+    if "conv_L_cache" in cfg:       # a conv layer's channels
+        divided.append(("hidden_size", t))
+    for key, parts in divided:
         if cfg[key] % parts:
             raise ValueError(f"{name}: {key} {cfg[key]} does not divide "
                              f"over {parts} chips")
     if not 0 < n <= cfg["num_hidden_layers"]:
         raise ValueError(f"{name}: {n} layers of {cfg['num_hidden_layers']}")
+    least = least_layers(cfg) if "layer_types" in cfg else 1
+    if n < least:
+        raise ValueError(
+            f"{name}: {n} layers cut a period of layer_types: the leading "
+            f"dense layers and one whole period are {least}")
     cfg["published"] = {key: cfg[key] for key in cut}
     cfg.update(
         num_hidden_layers=n, num_experts=cfg["num_experts"] // e,
@@ -260,6 +386,9 @@ def held_config(name: str, share: Share = Share()) -> dict:
     for key in ("layer_types", "mlp_layer_types", "gating_types"):
         if key in cfg:
             cfg[key] = cfg[key][:n]
+    if "conv_L_cache" in cfg:
+        cfg["published"]["conv_channels"] = cfg["hidden_size"]
+        cfg["conv_channels"] = cfg["hidden_size"] // t
     cfg["first_expert"] = (share.index % e) * cfg["num_experts"]
     return cfg
 
@@ -590,6 +719,38 @@ class Attention(nn.Module):
                 self, "o_proj", (self.q_heads * d, hidden))
 
 
+def short_conv(u, w):
+    """The causal depthwise convolution ``c[t] = sum_j w[:, j] * u[t - (K -
+    1 - j)]`` of ``u [B, S, C]`` with the taps ``w [C, K]`` (the last tap
+    weighs the current token, ``u`` is zero before the sequence): a sum of
+    shifted copies, which XLA fuses with the gates around it."""
+    taps, s_len = w.shape[1], u.shape[1]
+    return sum(
+        w[:, j] * jnp.pad(u, [(0, 0), (taps - 1 - j, 0), (0, 0)])[:, :s_len]
+        for j in range(taps))
+
+
+class ShortConv(nn.Module):
+    """The held channels' part of one gated short convolution's output:
+    ``(C * conv(B * x)) W_out`` with ``[B, C, x] = split(h W_in, 3)``, each
+    stream ``channels`` wide; the gates and the taps in float32. No
+    nonlinearity, no positions."""
+    channels: int
+    taps: int
+
+    @nn.compact
+    def __call__(self, x):
+        hidden = x.shape[-1]
+        with jax.named_scope("short_conv"):
+            gate_in, gate_out, u = jnp.split(
+                (x @ _weight(self, "in_proj", (hidden, 3 * self.channels))
+                 ).astype(jnp.float32), 3, axis=-1)
+            w = _weight(self, "conv", (self.channels, self.taps))
+            mixed = gate_out * short_conv(gate_in * u, w.astype(jnp.float32))
+            return mixed.astype(x.dtype) @ _weight(
+                self, "out_proj", (self.channels, hidden))
+
+
 class SwiGLU(nn.Module):
     width: int
 
@@ -688,13 +849,26 @@ def routed_part(rows: int, chunks: int, tokens, w, order, slot_weight, sizes,
     return routed((order, jnp.cumsum(sizes)), tokens, w, slot_weight)
 
 
+def choose_experts(scores, bias, top_k: int):
+    """``[tokens, top_k]``: each token's experts, the ``top_k`` highest of
+    its ``scores [tokens, experts]`` plus the selection ``bias [experts]``.
+    The bias enters here and nowhere else."""
+    return jax.lax.top_k(scores + bias, top_k)[1]
+
+
 class SparseMLP(nn.Module):
     """A router over all published experts, the held experts' part of the
     routed sum, and the shared expert where the model has one
     (``shared_width`` > 0).
 
     Every token is routed over all ``n_experts`` logits with the published
-    top-k, renormalisation and scale. Of its k slots those that fall on the
+    top-k, renormalisation and scale, by the rule ``score`` names:
+    ``softmax`` over all logits, the k largest, renormalised to sum 1; or
+    ``sigmoid`` of each logit, the k largest of the scores plus the
+    selection bias (the float32 leaf ``expert_bias``, which enters the
+    choice only: the weights are the scores without it, and no gradient
+    reaches it), renormalised by ``sum + 1e-6``. Of its k slots those that
+    fall on the
     experts held here (ids ``first_expert .. first_expert + held``) are
     computed: slots sorted by held expert, then :func:`routed_part`.
     **No capacity factor, no dropped slot**: the slots pass in chunks of
@@ -715,6 +889,7 @@ class SparseMLP(nn.Module):
     width: int
     shared_width: int
     usual_load: int = 4
+    score: str = "softmax"
 
     def buffer_rows(self, tokens: int):
         """``(rows, chunks)`` of the held slots' buffer: the rows of a chunk
@@ -731,10 +906,24 @@ class SparseMLP(nn.Module):
             logits = jnp.dot(
                 tokens, _weight(self, "router", (x.shape[-1], self.n_experts)),
                 preferred_element_type=jnp.float32)
-            top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
-                                         self.top_k)
-            if self.renormalise:
-                top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            if self.score == "sigmoid":
+                scores = jax.nn.sigmoid(logits)
+                top_e = choose_experts(scores, jax.lax.stop_gradient(
+                    _weight(self, "expert_bias", (self.n_experts,))
+                ).astype(jnp.float32), self.top_k)
+                top_p = jnp.take_along_axis(scores, top_e, axis=-1)
+                if self.renormalise:
+                    top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True)
+                                     + 1e-6)
+                # free unless the caller opens the collection
+                if self.is_mutable_collection(EXPERT_STATS):
+                    self.sow(EXPERT_STATS, "unbiased_experts",
+                             jax.lax.top_k(scores, self.top_k)[1])
+            else:
+                top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                             self.top_k)
+                if self.renormalise:
+                    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
             if self.scale != 1:
                 top_p = top_p * self.scale
             local = (top_e - self.first_expert).reshape(-1)
@@ -760,32 +949,53 @@ class SparseMLP(nn.Module):
         return routed.reshape(x.shape)
 
 
+def _norm_eps(cfg: dict) -> float:
+    """The RMSNorms' epsilon: ``rms_norm_eps``, or LFM2's ``norm_eps``."""
+    return cfg["rms_norm_eps"] if "rms_norm_eps" in cfg else cfg["norm_eps"]
+
+
 def layer_plan(cfg: dict, layer: int) -> dict:
     """What layer ``layer`` of the held configuration ``cfg`` is, from the
-    keys the config has: the attention ``kind`` (``layer_types``; without
-    it ``selected_attention`` where the config brings an ``sa_config``, else
-    full), its query ``heads``, its ``rope`` parameters (``rope_parameters``
-    by kind, or ``rope_theta`` with ``rope_scaling``), whether a per-head
-    ``gate`` and a ``qk_norm`` are there, and whether the MLP is ``sparse``
-    (``mlp_layer_types``; without it Qwen-MoE's rule of ``mlp_only_layers``
-    and ``decoder_sparse_step``)."""
+    keys the config has: the operator's ``kind`` (``layer_types``, where
+    ``conv`` is a gated short convolution; without it ``selected_attention``
+    where the config brings an ``sa_config``, else full), its query
+    ``heads`` of ``head_dim`` features (the key, or ``hidden_size`` over the
+    published heads), its ``rope`` parameters (``rope_parameters`` by kind,
+    or ``rope_theta`` with ``rope_scaling``), whether a per-head ``gate``
+    and a ``qk_norm`` are there, the norms' ``eps`` (``rms_norm_eps`` or
+    ``norm_eps``), whether the MLP is ``sparse`` (:func:`_sparse`), the
+    ``scale`` of its routed sum (``moe_routed_scaling_factor`` or
+    ``routed_scaling_factor``) and its router's rule ``score``: the key
+    ``scoring_func`` (``sigmoid``: each expert scored by itself, the choice
+    balanced by a selection bias, so ``use_expert_bias`` has to be stated
+    with it), ``softmax`` over all logits for a config without the key
+    (and then without a bias). The two other pairings are refused by the
+    keys' names: no config here needs them and no branch routes so."""
     n = cfg["num_hidden_layers"]
     kind = cfg["layer_types"][layer] if "layer_types" in cfg else (
         "selected_attention" if cfg.get("sa_config") else "full_attention")
-    if "mlp_layer_types" in cfg:
-        sparse = cfg["mlp_layer_types"][layer] == "sparse"
-    else:
-        sparse = layer not in cfg["mlp_only_layers"] and (
-            layer + 1) % cfg["decoder_sparse_step"] == 0
+    score = cfg.get("scoring_func", "softmax")
+    if score not in ("softmax", "sigmoid") or (
+            score == "sigmoid") != bool(cfg.get("use_expert_bias")):
+        raise ValueError(
+            f"scoring_func {score!r} with use_expert_bias "
+            f"{cfg.get('use_expert_bias')!r}: the router is a softmax "
+            "without a bias or a sigmoid with one")
     return {
-        "kind": kind, "sparse": sparse,
+        "kind": kind, "sparse": _sparse(cfg, layer),
         "heads": cfg.get("num_attention_heads_per_layer",
                          [cfg["num_attention_heads"]] * n)[layer],
+        "head_dim": cfg.get("head_dim") or cfg["hidden_size"] // cfg.get(
+            "published", cfg)["num_attention_heads"],
         "rope": cfg["rope_parameters"][kind] if "rope_parameters" in cfg
         else {"rope_theta": cfg["rope_theta"], "rope_type": "default",
               **(cfg.get("rope_scaling") or {})},
         "gate": cfg.get("gating_types", [None] * n)[layer] == "per_head",
-        "qk_norm": cfg.get("qk_norm", False)}
+        "qk_norm": cfg.get("qk_norm", False),
+        "eps": _norm_eps(cfg),
+        "scale": cfg.get("moe_routed_scaling_factor",
+                         cfg.get("routed_scaling_factor", 1)),
+        "score": score}
 
 
 class Block(nn.Module):
@@ -796,24 +1006,30 @@ class Block(nn.Module):
     def __call__(self, x, positions=None):
         cfg = _thaw(self.cfg)
         plan = layer_plan(cfg, self.layer)
-        eps, hidden = cfg["rms_norm_eps"], x.shape[-1]
-        x = x + Attention(
-            plan["kind"], plan["heads"], cfg["num_key_value_heads"],
-            cfg["head_dim"], cfg["sliding_window"] or 0,
-            _freeze(plan["rope"]), plan["gate"], plan["qk_norm"], eps,
-            _freeze(cfg.get("sa_config") or {}), name="attention")(
-                rms_norm(x, _norm_weight(self, "attn_norm", hidden), eps),
-                positions)
+        eps, hidden = plan["eps"], x.shape[-1]
+        # the norm before the operator keeps its name whatever the operator
+        h = rms_norm(x, _norm_weight(self, "attn_norm", hidden), eps)
+        if plan["kind"] == "conv":
+            x = x + ShortConv(cfg["conv_channels"], cfg["conv_L_cache"],
+                              name="conv")(h)
+        else:
+            x = x + Attention(
+                plan["kind"], plan["heads"], cfg["num_key_value_heads"],
+                plan["head_dim"], cfg.get("sliding_window") or 0,
+                _freeze(plan["rope"]), plan["gate"], plan["qk_norm"], eps,
+                _freeze(cfg.get("sa_config") or {}), name="attention")(
+                    h, positions)
         h = rms_norm(x, _norm_weight(self, "mlp_norm", hidden), eps)
         if not plan["sparse"]:
             with jax.named_scope("dense_mlp"):
                 return x + SwiGLU(cfg["intermediate_size"], name="mlp")(h)
         return x + SparseMLP(
             cfg["published"]["num_experts"], cfg["num_experts_per_tok"],
-            cfg["norm_topk_prob"], cfg.get("moe_routed_scaling_factor", 1),
+            cfg["norm_topk_prob"], plan["scale"],
             cfg["first_expert"], cfg["num_experts"],
             cfg["moe_intermediate_size"],
-            cfg.get("shared_expert_intermediate_size", 0), name="mlp")(h)
+            cfg.get("shared_expert_intermediate_size", 0),
+            score=plan["score"], name="mlp")(h)
 
 
 class Decoder(nn.Module):
@@ -827,6 +1043,11 @@ class Decoder(nn.Module):
     @property
     def num_classes(self) -> int:
         return _thaw(self.cfg)["vocab_size"]
+
+    # leaves the apply closure leaves out of its compute copy
+    # (``models.make_apply_fn``): the selection bias is added to float32
+    # scores and decides a choice, so it is not rounded with the matrices
+    float32_leaves = ("expert_bias",)
 
     @property
     def tpu_compiler_options(self) -> dict:
@@ -848,16 +1069,18 @@ class Decoder(nn.Module):
         cfg = _thaw(self.cfg)
         hidden, vocab = cfg["hidden_size"], cfg["vocab_size"]
         with jax.named_scope("embed"):
-            x = jnp.take(_weight(self, "embed", (vocab, hidden)), tokens,
-                         axis=0)
-        if positions is None and "mrope_section" in (
-                cfg.get("rope_scaling") or {}):
-            # text: the three streams count the tokens. Given as streams
-            # the compiler may not fold, the rotary tables are computed on
-            # the device; as constants they were 73 MB of the round's 106 MB
-            # executable at 16,384 tokens
+            embed = _weight(self, "embed", (vocab, hidden))
+            x = jnp.take(embed, tokens, axis=0)
+        if positions is None and "rope_parameters" not in cfg:
+            # text: every stream counts the tokens (three where the config
+            # divides the frequency pairs into sections, else one). Given
+            # as streams the compiler may not fold, the rotary tables are
+            # computed on the device; as constants they were 73 MB of the
+            # round's 106 MB executable at 16,384 tokens
+            streams = len((cfg.get("rope_scaling") or {}).get(
+                "mrope_section", [0]))
             positions = jax.lax.optimization_barrier(jnp.broadcast_to(
-                jnp.arange(tokens.shape[1]), (3, tokens.shape[1])))
+                jnp.arange(tokens.shape[1]), (streams, tokens.shape[1])))
         where = () if positions is None else (positions,)
         block = nn.remat(Block)
         if cfg.get("sa_config"):
@@ -871,8 +1094,13 @@ class Decoder(nn.Module):
         for i in range(cfg["num_hidden_layers"]):
             x = block(self.cfg, i, name=f"layers_{i}")(x, *where)
         x = rms_norm(x, _norm_weight(self, "final_norm", hidden),
-                     cfg["rms_norm_eps"])
+                     _norm_eps(cfg))
         with jax.named_scope("lm_head"):
+            if cfg.get("tie_word_embeddings"):
+                # the embedding is the head: one leaf, both uses in its
+                # gradient
+                return jnp.einsum("bsh,vh->bsv", x, embed,
+                                  preferred_element_type=jnp.float32)
             return jnp.dot(x, _weight(self, "lm_head", (hidden, vocab)),
                            preferred_element_type=jnp.float32)
 

@@ -119,6 +119,60 @@ def test_the_selecting_family_loads_builds_and_checks(tmp_path):
             (("longctx", 1),)), "tiny_selected.longctx")
 
 
+def test_the_short_conv_family_loads_builds_and_checks(tmp_path):
+    """One case of the ``tokens_shortconv`` family (the whole rehearsal is
+    benchmarks/tests/test_tokens_shortconv_family.py): a tiny configuration
+    of it under the cell's own traffic mix is found by name, builds through
+    the program's entry points, and passes its own reference check: the
+    routing the reference's, no selection bias moved by the round, the
+    gauge of what the bias does set on the way."""
+    bench = _bench_conftest()
+    from benchmarks.lib import harness, manifest
+    from neuroimagedisttraining_tpu.experiments import parse_args
+    from neuroimagedisttraining_tpu.models import decoder
+
+    family = manifest.family_of({"family": "tokens_shortconv"})
+    held = decoder.held_config("lfm2_tiny", decoder.Share(6, 4, 2))
+    config = {
+        "name": "tiny_shortconv", "source": "test fixture",
+        "family": "tokens_shortconv", "reference": "lfm2_moe",
+        "published": held.pop("published"),
+        "first_expert": held.pop("first_expert"),
+        "held": {k: held.pop(k) for k in family.HELD_KEYS},
+        "flags": {"algo": "fedavg", "model": "lfm2_tiny", "lm_layers": 6,
+                  "lm_expert_shards": 4, "lm_tensor_shards": 2,
+                  "dataset": "token_shards", "track_personal": 0,
+                  "client_chunk": 1, "batch_size": 1, "epochs": 1, "lr": 0.5,
+                  "momentum": 0.0, "grad_clip": 10.0},
+        "cohort": {"n_sites": 8, "train_per_site": 1, "test_per_site": 1,
+                   "sequence_length": 32},
+        **held}
+    assert set(held) <= family.CONFIG_KEYS
+    path = bench.write_manifest(tmp_path, config, (("longctx", 1),))
+    cell = manifest.load_cell(path, "tiny_shortconv.longctx")
+    assert cell.family is family
+    assert {"short_conv_ms_per_round", "short_conv_roofline",
+            "expert_bias_swap_share"} <= {e["name"] for e, _ in cell.per_layer}
+    algo = harness.build(cell, parse_args(harness.program_flags(cell, 3)), 3)
+    assert algo.data.x_train.shape == (8, 1, 32)
+    assert algo.clients_per_round == 2 and algo.data.class_num == 32
+    state = algo.init_state(jax.random.PRNGKey(3))
+    assert "lm_head" not in state.global_params
+    report = family.reference_check(
+        algo, state.global_params, harness.reference_of(cell), cell.config)
+    assert report["ok"], report
+    assert set(family.TOLERANCE) < set(report)
+    assert report["routing"]["error"] == 0.0
+    for layer in range(2, 6):
+        assert report[f"expert_bias_layer{layer}"] == {
+            "error": 0.0, "tolerance": 0.0, "ok": True}
+    assert report["expert_load"]["expert_bias_swap_share"] > 0.1
+    with pytest.raises(ValueError, match="unknown key"):
+        manifest.load_cell(bench.write_manifest(
+            tmp_path / "bad", {**config, "sa_config": {}},
+            (("longctx", 1),)), "tiny_shortconv.longctx")
+
+
 # ---------------------------------------------------------------------------
 # the static guard
 # ---------------------------------------------------------------------------

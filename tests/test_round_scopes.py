@@ -261,6 +261,52 @@ def test_folding_round_of_the_selecting_decoder_holds_its_scopes():
     assert count(names, "select") == count(names, "attention/select")
 
 
+def test_folding_round_of_the_short_conv_decoder_holds_its_scopes():
+    """The tiny decoder with conv layers, compiled round: ``short_conv``
+    around the whole operator in both passes (the scope
+    ``short_conv_ms_per_round`` and ``short_conv_roofline`` ask for by
+    name), the one attention layer's ``attention/full``, the tied head's
+    product under ``lm_head``, and none of the scopes of layers this model
+    has none of."""
+    import json
+    import os
+
+    from neuroimagedisttraining_tpu.data.tokens import make_token_shards
+    from neuroimagedisttraining_tpu.models import decoder
+
+    share = decoder.Share(6, 4, 2)
+    data = make_token_shards(0, n_clients=4, vocab=32, sequence_length=32,
+                             train_per_client=1)
+    hp = HyperParams(lr=0.05, local_epochs=1, steps_per_epoch=1,
+                     batch_size=1)
+    algo = FedAvg(decoder.decoder("lfm2_tiny", share), data, hp,
+                  loss_type="token_ce", frac=0.5, seed=3, client_chunk=1,
+                  track_personal=False)
+    state = algo.init_state(jax.random.PRNGKey(3))
+    compiled = algo._round_jit.lower(
+        state, jnp.arange(2, dtype=jnp.int32), jnp.asarray(0, jnp.float32),
+        data.x_train, data.y_train, data.n_train).compile()
+    names = op_names(compiled.as_text())
+    for scope in ("local_train", "aggregate"):
+        assert count(names, scope) > 0, scope
+    for scope in ("embed", "short_conv", "attention", "attention/full",
+                  "router", "experts", "dense_mlp", "lm_head"):
+        assert count(names, scope, "fwd") > 0, (scope, "forward")
+        assert count(names, scope, "bwd") > 0, (scope, "backward")
+    # the head's product is the embedding's transpose: still under lm_head
+    assert any(n.rsplit("/", 1)[-1] == "dot_general" for n in names
+               if scopes.under(n, "lm_head"))
+    for scope in ("attention/window", "attention/indexer", "shared_expert"):
+        assert count(names, scope) == 0, scope
+    metrics = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "metrics")
+    for name in ("short_conv_ms_per_round", "short_conv_roofline"):
+        with open(os.path.join(metrics, name + ".json")) as f:
+            args = json.load(f)["args"]
+        assert args["scope"] == "short_conv"
+        assert set(args.get("layers", {}).values()) <= {"short_conv"}
+
+
 @pytest.mark.parametrize("platform,spelling,products", [
     ("cpu", "xla", ("dot_general", "dot_general")),
     ("tpu", "kernel", ("jit(attention_forward)", "jit(attention_backward)"))])
