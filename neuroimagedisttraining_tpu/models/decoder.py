@@ -72,7 +72,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops.masked_attention import SAVED_NAMES, masked_attention
+from ..ops.masked_attention import (
+    OPERAND_NAMES,
+    SAVED_NAMES,
+    kernels_take,
+    masked_attention,
+    ruled_attention,
+)
 
 _PERIOD = ["full_attention"] + ["sliding_attention"] * 3
 # LFM2-8B-A1B's ``layer_types``: two conv layers, then ``full conv conv conv``
@@ -455,11 +461,11 @@ def _attend(q, k, v, seen):
     return jnp.einsum("...ngqk,...knd->...qngd", probs.astype(v.dtype), v)
 
 
-def full_attention(q, k, v, block=FULL_ATTENTION_BLOCK):
-    """Causal attention, ``q [B, S, n, g, d]`` over ``k, v [B, S, n, d]``:
-    blocks of queries, each against the keys up to its own end (the part of
-    the square above the diagonal is never computed), each block's scores
-    computed again in the backward pass."""
+def _causal_blocks(q, k, v, block=FULL_ATTENTION_BLOCK):
+    """Causal attention through :func:`_attend`: blocks of queries, each
+    against the keys up to its own end (the part of the square above the
+    diagonal is never computed), each block's scores computed again in the
+    backward pass."""
     s_len = q.shape[1]
 
     @jax.checkpoint
@@ -474,13 +480,14 @@ def full_attention(q, k, v, block=FULL_ATTENTION_BLOCK):
          for i in range(0, s_len, block)], axis=1)
 
 
-def window_attention(q, k, v, window: int):
-    """Causal attention in which position i sees the keys ``i - window < j
-    <= i``, computed as a band: the sequence in blocks of ``window``, each
+def _attend_positions(q, k, v, window: int):
+    """XLA's spelling of attention under the positions' rule ``0 <= i - j <
+    window`` (``window`` 0: causal): :func:`_causal_blocks`, or through
+    :func:`_attend` as a band: the sequence in blocks of ``window``, each
     block of queries against its own keys and the block before."""
     b, s_len = q.shape[:2]
-    if s_len <= window:
-        return full_attention(q, k, v)
+    if not window or s_len <= window:
+        return _causal_blocks(q, k, v)
     pad = -s_len % window
     if pad:
         q, k, v = (jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
@@ -501,6 +508,25 @@ def window_attention(q, k, v, window: int):
                          | (k_pos >= window)[None])
     out = _attend(q, with_previous(k), with_previous(v), seen)
     return out.reshape((b, nb * window) + out.shape[3:])[:, :s_len]
+
+
+def full_attention(q, k, v):
+    """Causal attention, ``q [B, S, n, g, d]`` over ``k, v [B, S, n, d]``.
+    The mask follows from the positions, so it is a rule and no operand of
+    ``ops/masked_attention.py``'s product: on one TPU, for heads of a
+    multiple of 128 and a sequence that tiles, the flash kernels (scores in
+    VMEM, tiles above the diagonal skipped, no mask applied under it);
+    :func:`_causal_blocks` elsewhere. The output and its rows' log-sum-exp
+    carry names under which the decoder's remat keeps them."""
+    return ruled_attention(q, k, v, 0, _attend_positions, "full")
+
+
+def window_attention(q, k, v, window: int):
+    """Causal attention in which position i sees the keys ``i - window < j
+    <= i``: :func:`full_attention`'s product under the band's rule (the
+    kernels' grid covers only the key tiles a query tile sees);
+    :func:`_attend_positions`' band elsewhere."""
+    return ruled_attention(q, k, v, window, _attend_positions, "window")
 
 
 def index_scores(q_idx, w_idx, k_idx):
@@ -1037,7 +1063,11 @@ class Decoder(nn.Module):
     float32 logits ``[B, S, V_held]``; ``positions [3, S]`` where the rotary
     streams differ (none: text, every stream counts the tokens). Each block
     is rematerialised (``nn.remat``): the backward pass keeps the blocks'
-    inputs and computes one block's activations at a time."""
+    inputs and computes one block's activations at a time, all but what
+    ``ops/masked_attention.py`` names (``SAVED_NAMES``: an attention
+    product's output and its rows' log-sum-exp; ``OPERAND_NAMES``: its q, k
+    and v) where the heads are as wide as its kernels take, and a selecting
+    layer's choice of keys."""
     cfg: Tuple
 
     @property
@@ -1082,15 +1112,23 @@ class Decoder(nn.Module):
             positions = jax.lax.optimization_barrier(jnp.broadcast_to(
                 jnp.arange(tokens.shape[1]), (streams, tokens.shape[1])))
         where = () if positions is None else (positions,)
-        block = nn.remat(Block)
+        # a block's remat keeps what the attention product's backward reads
+        # wherever the product can lower to the kernels: its output and
+        # log-sum-exp (going backward no forward kernel runs again) and its
+        # operands (the rotated q, k and v: no second projection, norm and
+        # rotary of the block's input); Laguna pays 29.6 MB a full layer
+        # and 42.2 a sliding one, less than the blocked float32 scores it
+        # no longer holds. A selecting layer keeps its selection (S^2 bytes
+        # a layer: it neither scores nor chooses again) and the product's
+        # output; a model whose heads the kernels do not take keeps
+        # nothing, and its blocked scores are computed again as before
+        names = ()
         if cfg.get("sa_config"):
-            # a block's remat keeps the selection it made (S^2 bytes a
-            # layer) and what the masked product's backward reads beside
-            # it: going backward it neither scores nor chooses again, nor
-            # runs the forward product again
-            block = nn.remat(Block, policy=jax.checkpoint_policies
-                             .save_only_these_names("selected_keys",
-                                                    *SAVED_NAMES))
+            names = ("selected_keys",) + SAVED_NAMES
+        elif kernels_take(layer_plan(cfg, 0)["head_dim"]):
+            names = SAVED_NAMES + OPERAND_NAMES
+        block = nn.remat(Block, policy=jax.checkpoint_policies
+                         .save_only_these_names(*names) if names else None)
         for i in range(cfg["num_hidden_layers"]):
             x = block(self.cfg, i, name=f"layers_{i}")(x, *where)
         x = rms_norm(x, _norm_weight(self, "final_norm", hidden),

@@ -1,11 +1,17 @@
-"""The masked product of a selecting attention layer
-(ops/masked_attention.py): one meaning, two lowerings.
+"""The attention product under a mask (ops/masked_attention.py), the mask
+data of the run (a selecting layer's) or a rule of the positions (a full or
+a sliding layer's): one meaning, two lowerings.
 
 * the Pallas kernels, interpreted (the spelling one TPU runs), against
   ``decoder._attend`` under the same mask: the output and the gradients of
   ``q``, ``k`` and ``v``, for one query head a KV head and for eight, under
   a selection whose rows leave their first key tiles empty and under the
   plain causal mask;
+* the same kernels under a rule (no mask operand) against XLA's spelling of
+  the rule, ``decoder.full_attention`` and ``decoder.window_attention`` as
+  the CPU lowers them: 1, 6 and 9 heads a KV head, the causal rule and two
+  bands, so that tiles wholly inside, on the edge of and wholly outside the
+  seen region all occur;
 * what selects the lowering: the target, the head width and whether the
   sequence divides into the kernels' tiles, each choice counted in
   ``attention_lowerings``;
@@ -18,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 from jax.interpreters import mlir
 
 from neuroimagedisttraining_tpu.models import decoder, make_apply_fn
@@ -95,6 +102,55 @@ def test_a_row_that_keeps_nothing_reads_zero_not_nan():
     assert not np.asarray(out[:, 200]).any()
 
 
+# 0: causal; a band as wide as a key tile (a query tile sees two key tiles,
+# none wholly) and twice that (three, the middle one wholly)
+RULES = {"causal": 0, "band_of_one_tile": TILES[1],
+         "band_of_two_tiles": 2 * TILES[1]}
+
+
+def test_the_rules_leave_tiles_of_every_kind():
+    """What the cases below are for: under each rule the 4 x 4 tiles hold
+    one wholly unseen and one cut by an edge; the causal rule and the wider
+    band also one wholly seen, and the bands one unseen UNDER the diagonal
+    (the grid does not step over it)."""
+    pos = np.arange(SEQ)
+    dist = pos[:, None] - pos[None, :]
+    for name, window in RULES.items():
+        seen = (dist >= 0) & (dist < (window or SEQ))
+        tiles = seen.reshape(4, TILES[0], 4, TILES[1]).transpose(0, 2, 1, 3)
+        full = tiles.all(axis=(2, 3))
+        none = ~tiles.any(axis=(2, 3))
+        assert none.any() and (~full & ~none).any(), name
+        assert full.any() == (name != "band_of_one_tile"), name
+        assert np.tril(none).any() == (window != 0), name
+        # the grid steps over the key tiles a query tile can see (the query
+        # tiles that see a key tile), not over all four
+        for keys_outer in (False, True):
+            grid = ma._specs(pl, (1, SEQ, 2, 1, WIDTH), TILES, window or SEQ,
+                             keys_outer)[-1]
+            assert grid == (1, 2, 4, window // TILES[1] + 1 if window else 4)
+
+
+@pytest.mark.parametrize("rule", sorted(RULES))
+@pytest.mark.parametrize("group", [1, 6, 9])
+def test_kernels_under_a_rule_against_xlas_spelling_of_it(group, rule):
+    q, k, v, g_out = case(group)
+    window = RULES[rule]
+
+    def spelled(q, k, v):
+        if window:
+            return decoder.window_attention(q, k, v, window)
+        return decoder.full_attention(q, k, v)
+    want, vjp = jax.vjp(spelled, q, k, v)
+    out, lse = ma._forward_pallas(q, k, v, tiles=TILES, window=window,
+                                  interpret=True)
+    close(out, want)
+    got = ma._backward_pallas(q, k, v, None, out, lse, g_out, tiles=TILES,
+                              window=window, interpret=True)
+    for a, b in zip(got, vjp(g_out)):
+        close(a, b)
+
+
 # ---------------------------------------------------------------------------
 # what selects the lowering
 
@@ -110,31 +166,46 @@ def lowerings():
         obs_metrics.set_registry(before)
 
 
-def lowered_grad_text(seq, width, platform, dtype=jnp.bfloat16):
+def lowered_grad_text(seq, width, platform, kind="selected",
+                      dtype=jnp.bfloat16):
     q = jnp.zeros((1, seq, 1, 8, width), dtype)
     k = jnp.zeros((1, seq, 1, width), dtype)
     keep = jnp.ones((1, seq, seq), jnp.int8)
 
     def loss(q, k, v):
-        return ma.masked_attention(q, k, v, keep, decoder._attend,
-                                   "selected").astype(jnp.float32).sum()
+        out = {"selected": lambda: ma.masked_attention(
+                   q, k, v, keep, decoder._attend, "selected"),
+               "full": lambda: decoder.full_attention(q, k, v),
+               "window": lambda: decoder.window_attention(q, k, v, 512)
+               }[kind]()
+        return out.astype(jnp.float32).sum()
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, k, k).lower(
         lowering_platforms=(platform,)).as_text()
 
 
-@pytest.mark.parametrize("seq,width,platform,spelling", [
-    (1024, 128, "tpu", "kernel"), (1024, 256, "tpu", "kernel"),
-    (1024, 128, "cpu", "xla"),
-    (1024 + 128, 128, "tpu", "xla"),    # does not divide into the tiles
-    (192, 128, "tpu", "xla"), (1024, 64, "tpu", "xla")],
+@pytest.mark.parametrize("seq,width,platform,spelling,kind", [
+    (1024, 128, "tpu", "kernel", "selected"),
+    (1024, 256, "tpu", "kernel", "selected"),
+    (1024, 128, "cpu", "xla", "selected"),
+    # does not divide into the tiles
+    (1024 + 128, 128, "tpu", "xla", "selected"),
+    (192, 128, "tpu", "xla", "selected"), (1024, 64, "tpu", "xla", "selected"),
+    (1024, 128, "tpu", "kernel", "full"),
+    (1024, 128, "tpu", "kernel", "window"),
+    (1024, 128, "cpu", "xla", "full"), (1024, 128, "cpu", "xla", "window"),
+    # lfm2_8b_a1b's heads: two would share a lane tile
+    (1024, 64, "tpu", "xla", "full"), (1024, 64, "tpu", "xla", "window"),
+    # a band's narrower tiles divide what the causal rule's do not
+    (1024 + 256, 128, "tpu", "xla", "full"),
+    (1024 + 256, 128, "tpu", "kernel", "window")],
     ids=lambda v: str(v))
 def test_target_and_shapes_select_the_lowering(seq, width, platform, spelling,
-                                               lowerings):
-    text = lowered_grad_text(seq, width, platform)
+                                               kind, lowerings):
+    text = lowered_grad_text(seq, width, platform, kind)
     # the forward and the two backward kernels, or none
     assert text.count("tpu_custom_call") == (3 if spelling == "kernel" else 0)
     assert lowerings() == {
-        f"kind=selected,pass={p},spelling={spelling}": 1.0
+        f"kind={kind},pass={p},spelling={spelling}": 1.0
         for p in ("forward", "backward")}
 
 
@@ -164,6 +235,7 @@ def test_a_partitioned_axis_keeps_xlas_spelling(lowerings):
 def kernels_interpreted(monkeypatch):
     """The TPU's lowering rule, interpreted, in the CPU's place."""
     monkeypatch.setattr(ma, "_TILES", TILES)
+    monkeypatch.setattr(ma, "_BAND_TILES", TILES)
     rules = mlir._platform_specific_lowerings["cpu"]
     for p, backward in PRIMITIVES:
         mlir.register_lowering(p, functools.partial(

@@ -1,5 +1,6 @@
-"""The stem pool's two backward kernels and the selecting attention's three
-(ISSUE 34) compiled by the chip's own compiler
+"""The stem pool's two backward kernels and the attention product's three
+(ISSUE 34: under a selection; ISSUE 36: under the positions' rule) compiled
+by the chip's own compiler
 at the benchmark's shapes, for a v5e that is described, not attached
 (``on-chip-measurement`` guide, section 2.3). Interpret mode passes what
 Mosaic refuses: a strided load of 16-bit data, a DMA slice of a memref whose
@@ -100,6 +101,42 @@ def test_selected_attentions_dq_and_dkv_kernels_compile_for_the_chip(
             *args, tiles=masked_attention._TILES),
         one_chip, a["q"], a["kv"], a["kv"], a["keep"], a["q"], a["lse"],
         a["q"])
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+
+
+# laguna_s21_fed.train: 8,192 tokens on 1 KV head of 128; 6 query heads under
+# the causal rule (full layers), 9 under a window of 512 (sliding layers)
+LAGUNA = {"full": (6, 0), "window": (9, 512)}
+
+
+def laguna_shapes(group):
+    return dict(q=(1, 8192, 1, group, 128), kv=(1, 8192, 1, 128),
+                lse=(1, 1, group, 1, 8192, jnp.float32))
+
+
+@pytest.mark.parametrize("kind", sorted(LAGUNA))
+def test_lagunas_forward_kernel_compiles_for_the_chip(one_chip, kind):
+    """The mask from the positions: no ``keep`` operand, at the tiles the
+    lowering picks for the shape (VMEM at 9 heads a grid step)."""
+    group, window = LAGUNA[kind]
+    a = laguna_shapes(group)
+    text = compiled_text(
+        lambda q, k, v: masked_attention._forward_pallas(
+            q, k, v, tiles=masked_attention.tiles_of(window),
+            window=window),
+        one_chip, a["q"], a["kv"], a["kv"])
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+
+
+@pytest.mark.parametrize("kind", sorted(LAGUNA))
+def test_lagunas_dq_and_dkv_kernels_compile_for_the_chip(one_chip, kind):
+    group, window = LAGUNA[kind]
+    a = laguna_shapes(group)
+    text = compiled_text(
+        lambda q, k, v, *rest: masked_attention._backward_pallas(
+            q, k, v, None, *rest,
+            tiles=masked_attention.tiles_of(window), window=window),
+        one_chip, a["q"], a["kv"], a["kv"], a["q"], a["lse"], a["q"])
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
 
 

@@ -150,7 +150,7 @@ def test_band_equals_masked_full_product(seq, window):
 @pytest.mark.parametrize("block", [8, 12, 64])
 def test_full_attention_in_blocks_of_queries(block):
     q, k, v = _qkv(32, seed=5)
-    _close(decoder.full_attention(q, k, v, block=block),
+    _close(decoder._causal_blocks(q, k, v, block=block),
            _masked_full_product(q, k, v, 32))
 
 

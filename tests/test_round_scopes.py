@@ -358,3 +358,58 @@ def test_selected_attention_keeps_its_names_whatever_its_lowering(
     for scope in ("attention/indexer", "attention/select"):
         assert count(names, scope) > 0, scope
         assert count(names, scope, "bwd") == 0, scope
+
+
+@pytest.mark.parametrize("platform,spelling,products", [
+    ("cpu", "xla", ("dot_general", "dot_general")),
+    ("tpu", "kernel", ("jit(attention_forward)", "jit(attention_backward)"))])
+def test_ruled_attention_keeps_its_names_whatever_its_lowering(
+        platform, spelling, products):
+    """``attention_full_ms_per_round``, ``attention_window_ms_per_round`` and
+    ``attention_roofline`` read ``attention/full`` and ``attention/window``
+    in both passes. Both layer kinds go through ops/masked_attention.py's
+    two primitives under a rule of the positions: in the folding round of a
+    small Laguna share whose heads and sequence the kernels take (128 wide;
+    1024 tokens, a window of 256), each lowering must leave its products
+    under those names, forward and backward, and count itself per kind and
+    pass. On the TPU the block's remat keeps the kernels' output and
+    log-sum-exp by name, so the whole round holds one forward call a layer
+    (two full, three sliding) and no second one going backward."""
+    from neuroimagedisttraining_tpu.data.tokens import make_token_shards
+    from neuroimagedisttraining_tpu.models import decoder
+    from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
+
+    cfg = decoder.held_config("laguna_tiny", decoder.Share(5, 4, 2))
+    cfg = dict(cfg, head_dim=128, sliding_window=256)
+    data = make_token_shards(0, n_clients=4, vocab=32, sequence_length=1024,
+                             train_per_client=1)
+    hp = HyperParams(lr=0.05, local_epochs=1, steps_per_epoch=1,
+                     batch_size=1)
+    algo = FedAvg(decoder.Decoder(decoder._freeze(cfg)), data, hp,
+                  loss_type="token_ce", frac=0.5, seed=3, client_chunk=1,
+                  track_personal=False)
+    state = algo.init_state(jax.random.PRNGKey(3))
+    before = obs_metrics.set_registry(None)
+    try:
+        lowered = algo._round_jit.trace(
+            state, jnp.arange(2, dtype=jnp.int32),
+            jnp.asarray(0, jnp.float32), data.x_train, data.y_train,
+            data.n_train).lower(lowering_platforms=(platform,))
+        counted = obs_metrics.get_registry().snapshot()[
+            "attention_lowerings"]["labeled"]
+    finally:
+        obs_metrics.set_registry(before)
+    assert counted == {f"kind={k},pass={p},spelling={spelling}": 1.0
+                       for k in ("full", "window")
+                       for p in ("forward", "backward")}
+    text = lowered.as_text(debug_info=True)
+    names = op_names(text)
+    for scope in ("attention/full", "attention/window"):
+        for direction, product in zip(("fwd", "bwd"), products):
+            under = {n.rsplit("/", 1)[-1] for n in names
+                     if scopes.under(n, scope)
+                     and scopes.direction(n) == direction}
+            assert product in under, (scope, direction, under)
+    for kernels in ("attention_forward", "attention_backward"):
+        calls = len(re.findall(rf"call @{kernels}(_\d+)?\(", text))
+        assert calls == (5 if spelling == "kernel" else 0), kernels
