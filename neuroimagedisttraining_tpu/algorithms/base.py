@@ -501,8 +501,9 @@ class FedAlgorithm(abc.ABC):
         """The model's parameters for this cohort's sample shape and dtype."""
         from ..models import init_params
 
-        return init_params(self.model, rng, self.init_sample_shape,
-                           self.init_sample_dtype)
+        with obs_trace.span("init_params"):
+            return init_params(self.model, rng, self.init_sample_shape,
+                               self.init_sample_dtype)
 
     def params_template(self):
         """The parameters' shapes and dtypes, nothing computed."""
@@ -742,9 +743,11 @@ class FedAlgorithm(abc.ABC):
         from jax.sharding import NamedSharding, PartitionSpec
 
         everywhere = NamedSharding(mesh, PartitionSpec())
-        return jax.tree_util.tree_map(
-            lambda a: jax.device_put(a, everywhere)
-            if isinstance(a, jax.Array) and not a.committed else a, state)
+        with obs_trace.span("place_state"):
+            return jax.tree_util.tree_map(
+                lambda a: jax.device_put(a, everywhere)
+                if isinstance(a, jax.Array) and not a.committed else a,
+                state)
 
     def _require_plan(self, what: str):
         if self._agg_sparse_plan is None:
@@ -2294,6 +2297,19 @@ class FedAlgorithm(abc.ABC):
         exactly, per-round attribution is ±1 round under the deferred
         fetch.
         """
+        # the whole call is one span: at depth 0 (a caller's block of
+        # rounds) its exit samples the allocator, after the last flush
+        with obs_trace.span("run", {"rounds": comm_rounds,
+                                    "fuse_rounds": fuse_rounds}):
+            return self._run(comm_rounds, eval_every, state, callback,
+                             finalize, fuse_rounds)
+
+    def _run(self, comm_rounds: int, eval_every: int, state: Any, callback,
+             finalize: bool, fuse_rounds: int):
+        """``run`` under its span. A round is a step span ``round`` with
+        the children ``sample``, ``dispatch_round``, ``evaluate`` and
+        ``flush`` (the round before's record, fetched once this round is
+        dispatched; the last one's directly under ``run``)."""
         from ..utils.records import DeferredRecords, to_float
 
         if fuse_rounds > 1:
@@ -2318,21 +2334,24 @@ class FedAlgorithm(abc.ABC):
         try:
             for r in range(comm_rounds):
                 t0 = time.perf_counter()
-                state, train_metrics = self.run_round(state, r)
-                record = {"round": r, **dict(train_metrics)}
-                if eval_every and (r + 1) % eval_every == 0:
-                    ev = self.evaluate(state)
-                    record.update({k: v for k, v in ev.items()
-                                   if not k.startswith("acc_per")})
-                history.append(record)
-                if callback is not None:
-                    for k, v in record.items():
-                        record[k] = to_float(v)
-                    record["round_time_s"] = time.perf_counter() - t0
-                    logger.info("%s round %d: %s", self.name, r, record)
-                    callback(r, state, record)
-                else:
-                    deferred.push(record)
+                with obs_trace.step_span("round", r):
+                    state, train_metrics = self.run_round(state, r)
+                    record = {"round": r, **dict(train_metrics)}
+                    if eval_every and (r + 1) % eval_every == 0:
+                        with obs_trace.span("evaluate"):
+                            ev = self.evaluate(state)
+                        record.update({k: v for k, v in ev.items()
+                                       if not k.startswith("acc_per")})
+                    history.append(record)
+                    if callback is not None:
+                        for k, v in record.items():
+                            record[k] = to_float(v)
+                        record["round_time_s"] = time.perf_counter() - t0
+                        logger.info("%s round %d: %s", self.name, r,
+                                    record)
+                        callback(r, state, record)
+                    else:
+                        deferred.push(record)
         except BaseException:
             deferred.flush_safely()  # emit the last completed round
             raise

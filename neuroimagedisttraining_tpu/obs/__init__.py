@@ -7,6 +7,12 @@ The third leg after ``parallel/`` (comm-efficient aggregation) and
   trace-event JSON (Perfetto-viewable), each span mirrored into
   ``jax.profiler.TraceAnnotation`` so host spans line up with the XLA
   device trace. Module-level null tracer = zero-cost when disabled.
+  One tree per process (ids, parents, the round; its docstring lists
+  the span names): compile durations (:mod:`~.compile`) land in it as
+  children of the span that dispatched them, and the allocator's
+  ``bytes_in_use`` / ``peak_bytes_in_use`` at the exit of every
+  top-level span, beside :mod:`~.memory`'s gauges and the registry's
+  compile distributions, which read as before.
 * :mod:`~.metrics` — typed registry: counters, gauges, streaming
   distributions (count/sum/min/max/p50/p99), labeled children behind a
   bounded-cardinality guard.

@@ -85,9 +85,19 @@ PHASE_OF_SPAN: Dict[str, Optional[str]] = {
     "finalize": "finalize",
     "build": "setup",
     "init_state": "setup",
+    "flush": "train_flush",
+    "place_state": "setup",
+    # containers, and what lies inside a span counted above
     "round": None,
+    "run": None,
+    "evaluate": None,
     "snip_mask": None,
+    "init_params": None,
     "finetune": None,
+    "import_program": None,
+    "compile/trace": None,
+    "compile/lower": None,
+    "compile/backend": None,
 }
 
 #: a round is an outlier when its |round_time_s - median| exceeds this

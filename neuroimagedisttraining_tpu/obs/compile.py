@@ -12,7 +12,12 @@ Two complementary sources:
   fired (``obs.trace.current_span_name()``) — the jitted ENTRY POINT
   being dispatched (``dispatch_round``, ``eval``, ``init_state``,
   ``snip_mask``, ``fused_block_dispatch``, ...), since jax compiles
-  lazily inside the first dispatch. Compilation-cache events
+  lazily inside the first dispatch. Each duration also lands in the
+  active tracer's span tree (``obs/trace.py``) as an event
+  ``compile/trace`` / ``compile/lower`` / ``compile/backend``, a child
+  of that same open span, ending when the listener fired: traces nest
+  (a jitted function traced inside another's trace), so over a phase
+  their union is the time, not their sum. Compilation-cache events
   (``/jax/compilation_cache/...``) land as counters, so persistent-
   cache hit rates are observable per run.
 * :func:`jit_cost_analysis` — explicit AOT ``lower()``/``compile()``
@@ -78,11 +83,18 @@ class CompileWatch:
         if name is None:
             return
         try:
+            now = time.perf_counter_ns()
             entry = obs_trace.current_span_name() or "untraced"
             d = self._registry.distribution(name)
             d.observe(duration_secs)
             d.labels(entry=entry).observe(duration_secs)
             self._registry.counter("compile_events_total").inc()
+            # the same duration in the span tree, under the span that
+            # labels it: "compile_trace_s" -> "compile/trace"
+            dur_ns = int(duration_secs * 1e9)
+            obs_trace.get_tracer().record(
+                "compile/" + name[len("compile_"):-len("_s")],
+                now - dur_ns, dur_ns)
         except Exception:
             # jax.monitoring invokes listeners UNGUARDED inside the
             # compile path — any escape here (a label-cardinality

@@ -16,6 +16,8 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import numpy as np
 
+from ..obs import trace as obs_trace
+
 
 def git_sha(repo_root: Optional[str] = None) -> str:
     """Current commit SHA ('' when git is unavailable — catalog entries
@@ -64,8 +66,11 @@ class DeferredRecords:
         rec, self._pending = self._pending, None
         if rec is None:
             return
-        for k, v in rec.items():
-            rec[k] = to_float(v)
+        # the round driver's one blocking fetch: the host waits here for
+        # the device to finish the record's round
+        with obs_trace.span("flush", {"round": rec.get("round")}):
+            for k, v in rec.items():
+                rec[k] = to_float(v)
         if self._timed:
             t = time.perf_counter()
             rec["round_time_s"] = t - self._last_t
