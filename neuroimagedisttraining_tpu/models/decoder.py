@@ -63,6 +63,7 @@ it no gradient, and it stays as initialised.**
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Tuple
 
@@ -72,6 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import grouped_mlp
 from ..ops.masked_attention import (
     OPERAND_NAMES,
     SAVED_NAMES,
@@ -808,37 +810,85 @@ def routed_part(rows: int, chunks: int, tokens, w, order, slot_weight, sizes,
                 top_k: int):
     """The held experts' part of the routed sum ``[tokens, hidden]``, exact
     for any routing: the slots, sorted by held expert (``order``; ``sizes``
-    slots an expert), pass in chunks of ``rows`` rows, as many as the held
-    slots' count needs and at most ``chunks`` (the worst case). A chunk
-    gathers its rows, passes them through the grouped SwiGLU
-    (``jax.lax.ragged_dot``), weights them and scatter-adds them back. Rows
-    past the held slots go in as zeros and the chunk's last group is
-    stretched over them, so they come out as zeros and no row lies outside
-    every group (the grouped product leaves such rows undefined on a TPU,
-    in both passes). A chunk is thus computed whole: a step's time is a step
-    function of the held slots' count and not a line through it.
+    slots an expert), pass in chunks of ``rows`` slots, as many as the held
+    slots' count needs and at most ``chunks`` (the worst case). A chunk is
+    computed whole, rows past the held slots as zeros: a step's time is a
+    step function of the held slots' count and not a line through it. By the
+    chunk's shape (``ops/grouped_mlp.row_tile``) a chunk is one of two things:
+
+    * laid out in tile-aligned groups (``grouped_mlp.aligned_layout``: every
+      tile of rows belongs to one expert, gaps and tail hold zeros, no row
+      lies outside every group) where every slot knows its row, so nothing
+      is scattered: the chunk's rows are a gather of the tokens (a zero row
+      for an empty one), they pass through the grouped SwiGLU with the slots'
+      weights (``grouped_mlp.forward``: Pallas kernels on a TPU,
+      ``jax.lax.ragged_dot`` elsewhere), and a token's output is the sum of
+      its ``top_k`` slots' rows, the chunk's last row (always empty) for a
+      slot that is not in the chunk. Going backward each transpose is a
+      gather again: the output's gradient by each row's token,
+      ``grouped_mlp.backward`` (the gate and up products computed again, the
+      rows', the row weights' and the weights' gradients), a token's gradient
+      the sum over its slots' rows, a slot weight's its row's;
+    * where aligning the groups would add more than an eighth to the chunk
+      (a chunk that is a small part of the slots), its rows as they are
+      sorted (:func:`_sorted_chunk`, PR 28's spelling): a gather, three
+      ``ragged_dot`` with the last group stretched over the tail, the
+      weights, a scatter-add into the tokens; differentiated by JAX.
 
     The loop over the chunks has its own derivative rule: going backward
     the chunks pass once more, each computing its activations again, and
     the gradients of ``tokens``, ``w`` and ``slot_weight`` add up over them.
     (Differentiated by JAX a ``lax.scan`` keeps one copy of the tokens and
     the weights a chunk: 4 GiB at the published widths.)"""
-    def one(lo, slots, tokens, w, slot_weight):
-        order, ends = slots
-        starts, count = ends - jnp.diff(ends, prepend=0), ends[-1]
-        mine = jax.lax.dynamic_slice(order, (lo,), (rows,))
-        token_of = mine // top_k
-        groups = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo,
-                                                          lo + rows)
-        groups = groups.at[-1].add(rows - jnp.sum(groups))
-        valid = (lo + jnp.arange(rows) < count)[:, None]
-        xs = jnp.where(valid, tokens[token_of], 0)
-        gate = jax.lax.ragged_dot(xs, w["gate_proj"], groups)
-        up = jax.lax.ragged_dot(xs, w["up_proj"], groups)
-        ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w["down_proj"],
-                                groups)
-        ys = ys * slot_weight[mine][:, None].astype(ys.dtype)
-        return jnp.zeros_like(tokens).at[token_of].add(ys)
+    tm = grouped_mlp.row_tile(rows, sizes.shape[0])
+
+    def with_zero_row(a):
+        return jnp.concatenate([a, jnp.zeros_like(a[:1])])
+
+    def chunk(lo, slots, tokens, slot_weight):
+        """An aligned chunk's addressing, its rows and their weights."""
+        order, ends, position = slots
+        tile_expert, slot_of, token_of, place = grouped_mlp.aligned_layout(
+            lo, rows, tm, order, ends, top_k)
+        # slot-major, [top_k, tokens]: a token's slots' rows are then whole
+        # [tokens, hidden] slabs and their sum adds slabs up
+        return (tile_expert, token_of,
+                place(position.reshape(tokens.shape[0], top_k).T),
+                with_zero_row(tokens)[token_of],
+                with_zero_row(slot_weight)[slot_of])
+
+    def slots_sum(rows_of, place):
+        """Each token's sum over its slots' rows, added up in float32."""
+        return jnp.sum(rows_of[place].astype(jnp.float32), axis=0).astype(
+            rows_of.dtype)
+
+    def aligned(lo, slots, tokens, w, slot_weight):
+        tile_expert, _, place, xs, rw = chunk(lo, slots, tokens, slot_weight)
+        return slots_sum(grouped_mlp.forward(xs, rw, w, tile_expert, tm),
+                         place)
+
+    def aligned_backward(lo, slots, tokens, w, slot_weight, g):
+        tile_expert, token_of, place, xs, rw = chunk(lo, slots, tokens,
+                                                     slot_weight)
+        d_xs, d_rw, d_w = grouped_mlp.backward(
+            xs, rw, w, tile_expert, with_zero_row(g)[token_of], tm)
+        return slots_sum(d_xs, place), d_w, d_rw[place].T.reshape(-1)
+
+    ends = jnp.cumsum(sizes)
+    if tm is None:
+        one = functools.partial(_sorted_chunk, rows, top_k)
+
+        def one_backward(lo, slots, tokens, w, slot_weight, g):
+            return jax.vjp(lambda *a: one(lo, slots, *a), tokens, w,
+                           slot_weight)[1](g)
+        slots = (jnp.pad(order, (0, max(0, rows * chunks - order.shape[0]))),
+                 ends)
+    else:
+        one, one_backward = aligned, aligned_backward
+        # where each slot stands in the sorted order
+        slots = (order, ends, jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype),
+            unique_indices=True))
 
     def over_chunks(add_chunk, count, total):
         """``total`` with ``add_chunk(lo, total)`` applied for the first
@@ -846,11 +896,14 @@ def routed_part(rows: int, chunks: int, tokens, w, order, slot_weight, sizes,
         stands outside the loop, where the compiler fuses it with its
         neighbours (inside it a round took 1 % longer: my chip runs, PR 28);
         the loop takes as many turns as the count needs, none at the usual
-        load, and no derivative is taken through it."""
+        load, and no derivative is taken through it; where one chunk holds
+        the worst case there is no loop."""
+        total = add_chunk(0, total)
+        if chunks == 1:
+            return total
         return jax.lax.fori_loop(
             1, (count + rows - 1) // rows,
-            lambda c, t: add_chunk(c * rows, t),
-            add_chunk(0, total))
+            lambda c, t: add_chunk(c * rows, t), total)
 
     @jax.custom_vjp
     def routed(slots, *operands):
@@ -860,19 +913,36 @@ def routed_part(rows: int, chunks: int, tokens, w, order, slot_weight, sizes,
 
     def backward(saved, g):
         slots, operands = saved
-
-        def add_chunk(lo, total):
-            _, vjp = jax.vjp(lambda *a: one(lo, slots, *a), *operands)
-            return jax.tree_util.tree_map(jnp.add, total, vjp(g))
-
         return (None,) + over_chunks(
-            add_chunk, slots[1][-1],
-            jax.tree_util.tree_map(jnp.zeros_like, operands))
+            lambda lo, total: jax.tree_util.tree_map(
+                jnp.add, total, one_backward(lo, slots, *operands, g)),
+            slots[1][-1], jax.tree_util.tree_map(jnp.zeros_like, operands))
 
     routed.defvjp(lambda slots, *operands: (
         routed(slots, *operands), (slots, operands)), backward)
-    order = jnp.pad(order, (0, max(0, rows * chunks - order.shape[0])))
-    return routed((order, jnp.cumsum(sizes)), tokens, w, slot_weight)
+    return routed(slots, tokens, w, slot_weight)
+
+
+def _sorted_chunk(rows: int, top_k: int, lo, slots, tokens, w, slot_weight):
+    """The chunk ``[lo, lo + rows)`` of the sorted slots as it stands (PR 28):
+    gathered, through three ``ragged_dot``, weighted, scatter-added. Rows
+    past the held slots go in as zeros and the chunk's last group is
+    stretched over them, so they come out as zeros and no row lies outside
+    every group (the grouped product leaves such rows undefined on a TPU,
+    in both passes)."""
+    order, ends = slots
+    starts, count = ends - jnp.diff(ends, prepend=0), ends[-1]
+    mine = jax.lax.dynamic_slice(order, (lo,), (rows,))
+    token_of = mine // top_k
+    groups = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+    groups = groups.at[-1].add(rows - jnp.sum(groups))
+    valid = (lo + jnp.arange(rows) < count)[:, None]
+    xs = jnp.where(valid, tokens[token_of], 0)
+    gate = jax.lax.ragged_dot(xs, w["gate_proj"], groups)
+    up = jax.lax.ragged_dot(xs, w["up_proj"], groups)
+    ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w["down_proj"], groups)
+    ys = ys * slot_weight[mine][:, None].astype(ys.dtype)
+    return jnp.zeros_like(tokens).at[token_of].add(ys)
 
 
 def choose_experts(scores, bias, top_k: int):
@@ -896,7 +966,10 @@ class SparseMLP(nn.Module):
     reaches it), renormalised by ``sum + 1e-6``. Of its k slots those that
     fall on the
     experts held here (ids ``first_expert .. first_expert + held``) are
-    computed: slots sorted by held expert, then :func:`routed_part`.
+    computed: slots sorted by held expert, then :func:`routed_part`, which
+    lays a chunk of them out in tile-aligned groups, gathers each row's
+    token, runs the grouped SwiGLU (``ops/grouped_mlp.py``) and sums each
+    token's slots' rows; nothing is scattered, in either pass.
     **No capacity factor, no dropped slot**: the slots pass in chunks of
     ``usual_load`` times the expected number of held slots (``tokens * k *
     held / n_experts``), as many chunks as the count needs, up to the

@@ -1,6 +1,6 @@
-"""The stem pool's two backward kernels and the attention product's three
-(ISSUE 34: under a selection; ISSUE 36: under the positions' rule) compiled
-by the chip's own compiler
+"""The stem pool's two backward kernels, the attention product's three
+(ISSUE 34: under a selection; ISSUE 36: under the positions' rule) and the
+held experts' seven (ISSUE 38) compiled by the chip's own compiler
 at the benchmark's shapes, for a v5e that is described, not attached
 (``on-chip-measurement`` guide, section 2.3). Interpret mode passes what
 Mosaic refuses: a strided load of 16-bit data, a DMA slice of a memref whose
@@ -20,7 +20,11 @@ from jax.sharding import SingleDeviceSharding
 
 from benchmarks.lib import scopes
 from neuroimagedisttraining_tpu.models import decoder
-from neuroimagedisttraining_tpu.ops import masked_attention, pool_vjp
+from neuroimagedisttraining_tpu.ops import (
+    grouped_mlp,
+    masked_attention,
+    pool_vjp,
+)
 
 
 @pytest.fixture(scope="module")
@@ -162,6 +166,65 @@ def test_compiled_kernels_stand_under_the_callers_scope(one_chip):
     assert sorted(scopes.direction(n) for n in names) == ["bwd", "bwd", "fwd"]
     for name in names:
         assert scopes.under(name, "attention/selected"), name
+        assert name.endswith("/pallas_call"), name
+
+
+# the held experts' chunk: (slots, hidden, width, held) of
+# lfm2_8b_a1b_fed.longctx and keye_vl2_fed.longctx (laguna_s21_fed.train's,
+# 10,240 slots on 8 experts, is not laid out in aligned groups)
+EXPERTS = {"lfm2": (65536, 2048, 1792, 8), "keye": (65536, 2048, 768, 16)}
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+@pytest.mark.parametrize("cell", sorted(EXPERTS))
+def test_the_experts_kernels_compile_for_the_chip(one_chip, cell, backward):
+    """At the row tile and the column tiles the shapes pick (VMEM: three
+    weight blocks of 2048 x 896 and two row tiles of 512 x 2048, twice, in
+    ``lfm2``'s backward)."""
+    rows, hidden, width, held = EXPERTS[cell]
+    tm = grouped_mlp.row_tile(rows, held)
+    total = grouped_mlp.aligned_rows(rows, held, tm)
+    kernels = grouped_mlp._pallas(tm)
+    shapes = [(total, hidden), (total, jnp.float32), (held, hidden, width),
+              (held, hidden, width), (held, width, hidden),
+              (total // tm, jnp.int32)] + [(total, hidden)] * backward
+    text = compiled_text(kernels.backward if backward else kernels.forward,
+                         one_chip, *shapes)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == (
+        5 if backward else 2)
+
+
+def test_compiled_experts_kernels_stand_under_the_callers_scope(one_chip):
+    """``experts_ms_per_round`` joins a kernel's time to ``experts`` by the
+    ``op_name`` of the COMPILED program, in both passes (XLA's ``ragged-dot``
+    kernels carried none)."""
+    rows, held, top_k, hidden, width = 16384, 4, 2, 256, 384
+    local = jnp.arange(rows) % (held + 1)
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)[:held]
+    order = jnp.argsort(local, stable=True)
+
+    def grads(tokens, wg, wu, wd, slot_weight):
+        def loss(tokens, w, slot_weight):
+            with jax.named_scope("experts"):
+                out = decoder.routed_part(rows, 1, tokens, w, order,
+                                          slot_weight, sizes, top_k)
+            return out.astype(jnp.float32).sum(), out
+        return jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(
+            tokens, {"gate_proj": wg, "up_proj": wu, "down_proj": wd},
+            slot_weight)
+
+    text = compiled_text(grads, one_chip, (rows // top_k, hidden),
+                         (held, hidden, width), (held, hidden, width),
+                         (held, width, hidden), (rows, jnp.float32))
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # one chunk holds the worst case here: the loop over further ones is gone
+    assert sorted(scopes.direction(n) for n in names) == (
+        ["bwd"] * 5 + ["fwd"] * 2)
+    for name in names:
+        assert scopes.under(name, "experts"), name
         assert name.endswith("/pallas_call"), name
 
 

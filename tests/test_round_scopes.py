@@ -413,3 +413,82 @@ def test_ruled_attention_keeps_its_names_whatever_its_lowering(
     for kernels in ("attention_forward", "attention_backward"):
         calls = len(re.findall(rf"call @{kernels}(_\d+)?\(", text))
         assert calls == (5 if spelling == "kernel" else 0), kernels
+
+
+def scatters(text):
+    """``(op_name, update operand's type)`` of every scatter of a lowered
+    module's text with debug info."""
+    named = dict(re.findall(r'(#loc\d+) = loc\("([^"]*)"', text))
+    return [(named.get(loc, ""), types.split(", ")[-1])
+            for types, loc in re.findall(
+                r'"stablehlo\.scatter"\(.*?\}\) : \(([^)]*)\) -> [^\n]*?'
+                r'loc\((#loc\d+)\)', text, flags=re.S)]
+
+
+@pytest.mark.parametrize("platform,hidden,spelling,products", [
+    ("cpu", 128, "xla", ("ragged_dot_general", "ragged_dot_general")),
+    ("tpu", 128, "kernel", ("jit(experts_forward)", "jit(experts_backward)")),
+    ("cpu", 32, "xla", ("ragged_dot_general", "ragged_dot_general")),
+    ("tpu", 32, "xla", ("ragged_dot_general", "ragged_dot_general"))])
+def test_the_expert_layer_scatters_no_rows_whatever_its_lowering(
+        platform, hidden, spelling, products, monkeypatch):
+    """``experts_ms_per_round`` and ``experts_roofline`` read the scope
+    ``experts`` in both passes. The held experts' chunk is laid out in
+    tile-aligned groups and dispatched and combined by each slot's row
+    (models/decoder.routed_part) wherever the row tile fits the chunk (here
+    a tile of 128, so that 1024 tokens will do): in the folding round of a
+    small Laguna share the lowered program holds no scatter of rows of
+    ``hidden`` numbers
+    under that scope (what is left there places integers: where each slot
+    stands in the sorted order), and the grouped SwiGLU is
+    ops/grouped_mlp.py's two primitives with a lowering per target: at
+    lane-aligned widths and 1024 tokens (a chunk of 4096 slots, row tiles of
+    128) the kernels on a TPU, ``ragged_dot`` everywhere else; each must
+    leave its products under the scope, forward and backward, and count
+    itself per product and pass."""
+    from neuroimagedisttraining_tpu.data.tokens import make_token_shards
+    from neuroimagedisttraining_tpu.models import decoder
+    from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
+    from neuroimagedisttraining_tpu.ops import grouped_mlp
+
+    monkeypatch.setattr(grouped_mlp, "_ROW_TILE", 128)
+    cfg = decoder.held_config("laguna_tiny", decoder.Share(5, 4, 2))
+    cfg = dict(cfg, hidden_size=hidden, moe_intermediate_size=hidden)
+    data = make_token_shards(0, n_clients=4, vocab=32, sequence_length=1024,
+                             train_per_client=1)
+    hp = HyperParams(lr=0.05, local_epochs=1, steps_per_epoch=1,
+                     batch_size=1)
+    algo = FedAvg(decoder.Decoder(decoder._freeze(cfg)), data, hp,
+                  loss_type="token_ce", frac=0.5, seed=3, client_chunk=1,
+                  track_personal=False)
+    state = algo.init_state(jax.random.PRNGKey(3))
+    before = obs_metrics.set_registry(None)
+    try:
+        lowered = algo._round_jit.trace(
+            state, jnp.arange(2, dtype=jnp.int32),
+            jnp.asarray(0, jnp.float32), data.x_train, data.y_train,
+            data.n_train).lower(lowering_platforms=(platform,))
+        counted = obs_metrics.get_registry().snapshot()[
+            "expert_lowerings"]["labeled"]
+    finally:
+        obs_metrics.set_registry(before)
+    assert counted == {
+        f"pass={p},product={product},spelling={spelling}": 1.0
+        for p in ("forward", "backward")
+        for product in grouped_mlp.PRODUCTS[p]}
+    text = lowered.as_text(debug_info=True)
+    found = scatters(text)
+    assert len(found) >= 2      # the embedding's gradient, the slots' places
+    under = [(name, kind) for name, kind in found
+             if scopes.under(name, "experts")]
+    assert under and all(
+        re.fullmatch(r"tensor<\d+xi32>", kind) for _, kind in under), under
+    names = op_names(text)
+    for direction, product in zip(("fwd", "bwd"), products):
+        here = {n.rsplit("/", 1)[-1] for n in names
+                if scopes.under(n, "experts")
+                and scopes.direction(n) == direction}
+        assert product in here, (direction, here)
+    for kernels in ("experts_forward", "experts_backward"):
+        calls = len(re.findall(rf"call @{kernels}(_\d+)?\(", text))
+        assert (calls > 0) == (spelling == "kernel"), (kernels, calls)
