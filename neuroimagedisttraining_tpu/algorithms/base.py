@@ -1070,7 +1070,8 @@ class FedAlgorithm(abc.ABC):
         # (models/alexnet3d.py:phased_stem_stage, inside the model's
         # module); embed, attention with full or window or (a selecting
         # layer's) indexer, select and selected inside it, short_conv (a
-        # conv layer's whole operator), router, experts, shared_expert,
+        # conv layer's whole operator), ssm (a state-space mixer) with
+        # conv, scan and norm inside it, router, experts, shared_expert,
         # dense_mlp, lm_head (models/decoder.py; lm_head also around the
         # per-token CE in core/losses.py).
         # The forward/backward pass needs none: JAX prints it as

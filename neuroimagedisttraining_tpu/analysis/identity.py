@@ -109,6 +109,10 @@ FLAG_CLASSES: Dict[str, Tuple[str, str]] = {
                                      "rows held"),
     "lm_vocab_shards": ("identity", "v<V> after lm...t<T>: vocabulary rows "
                                     "held where heads divide otherwise"),
+    "lm_ssm_shards": ("identity", "s<S> after lm...t<T>: state-space heads "
+                                  "and groups held"),
+    "lm_mlp_shards": ("identity", "m<M> after lm...t<T>: a dense MLP's "
+                                  "columns held"),
     "global_test": ("identity", "'-g' reference-parity tag"),
     "tag": ("identity", "explicit identity suffix"),
     # -- inert (telemetry / logging / placement / scheduling-only) ---------

@@ -59,6 +59,13 @@ def build_parser(algo: Optional[str] = None) -> argparse.ArgumentParser:
                    help="decoder models: chips that share the vocabulary "
                         "rows where that is not --lm_tensor_shards (0: it "
                         "is; a model with fewer KV heads than chips)")
+    p.add_argument("--lm_ssm_shards", type=int, default=0,
+                   help="decoder models: chips that share a state-space "
+                        "mixer's heads, in whole groups (0: as "
+                        "--lm_tensor_shards)")
+    p.add_argument("--lm_mlp_shards", type=int, default=0,
+                   help="decoder models: chips that share a dense MLP's "
+                        "columns, in whole tiles of 128 (0: held whole)")
     p.add_argument("--data_dir", type=str, default="",
                    help="dataset root (ABCD .h5 path or CIFAR batches dir)")
     p.add_argument("--partition_method", type=str, default="dir",
@@ -974,6 +981,11 @@ def run_identity(args: argparse.Namespace, algo: Optional[str] = None,
     vocab_shards = getattr(args, "lm_vocab_shards", 0)
     if vocab_shards and vocab_shards != share[2]:
         parts.append(f"v{vocab_shards}")
+    # a state-space mixer's heads and a dense MLP's columns, where divided
+    if getattr(args, "lm_ssm_shards", 0):
+        parts.append(f"s{args.lm_ssm_shards}")
+    if getattr(args, "lm_mlp_shards", 0):
+        parts.append(f"m{args.lm_mlp_shards}")
     # defense and fine-tune knobs change training behavior — they must
     # split checkpoint/log/stat_info lineages (unlike inert identity tags)
     if algo == "salientgrads" and getattr(args, "stratified_sampling", 0):

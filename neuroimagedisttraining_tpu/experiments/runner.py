@@ -80,7 +80,9 @@ def _decoder_share(args):
         return None, None
     share = dict(layers=args.lm_layers, expert_shards=args.lm_expert_shards,
                  tensor_shards=args.lm_tensor_shards,
-                 vocab_shards=args.lm_vocab_shards)
+                 vocab_shards=args.lm_vocab_shards,
+                 ssm_shards=args.lm_ssm_shards,
+                 mlp_shards=args.lm_mlp_shards)
     return share, decoder.held_config(args.model.lower(),
                                       decoder.Share(**share))
 

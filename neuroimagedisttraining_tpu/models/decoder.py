@@ -1,39 +1,59 @@
 """Decoder language models: causal pre-norm blocks whose layers are read off
-a published ``config.json``. A layer's operator is one of four kinds: full
-attention, sliding-window attention, attention over the keys a learned
-indexer selects (``sa_config``: each query attends the ``topk`` keys its
-indexer scores highest under the causal mask), or a gated short convolution
-(``conv`` in ``layer_types``: two elementwise gates around a causal depthwise
-convolution of ``conv_L_cache`` taps, no nonlinearity, no positions). Heads
-may carry a per-head output gate or a QK-norm, rotary angles may come from
-three position streams (``mrope_section``); an MLP is a dense SwiGLU or a
-sparse one: a router over all published experts, with or without a shared
-expert, that routes by one of two rules: softmax over all logits, the top-k,
-renormalised; or a sigmoid of each logit, the top-k of the scores plus a
-selection bias that enters the choice only, the scores renormalised by ``sum
-+ 1e-6``. Embedding and head are two leaves, or one where the config ties
-them.
+a published ``config.json``. A block has one of two forms: ONE token-mixing
+operator, then the MLP; or (a config with ``mamba_d_ssm`` and no
+``layer_types``) TWO mixers side by side on the one normed input, attention
+and a Mamba-2 state-space mixer, whose outputs are both added into the
+residual, then the MLP. An operator is one of five kinds: full attention,
+sliding-window attention, attention over the keys a learned indexer selects
+(``sa_config``: each query attends the ``topk`` keys its indexer scores
+highest under the causal mask), a gated short convolution (``conv`` in
+``layer_types``: two elementwise gates around a causal depthwise convolution
+of ``conv_L_cache`` taps, no nonlinearity, no positions), or the state-space
+mixer (:class:`StateSpace`: a projection to z, x, B, C and dt, a causal conv
+of ``mamba_d_conv`` taps with a bias and a silu over x, B and C, the
+recurrence ``S_t = exp(dt_t A) S_{t-1} + dt_t outer(x_t, B_t)``, ``y_t = S_t
+C_t + D x_t`` per head computed chunk by chunk (:func:`ssm_scan`), a gate and
+an RMSNorm over each group's channels). Heads may carry a per-head output
+gate or a QK-norm, rotary angles may come from three position streams
+(``mrope_section``); an MLP is a dense SwiGLU or a sparse one: a router over
+all published experts, with or without a shared expert, that routes by one of
+two rules: softmax over all logits, the top-k, renormalised; or a sigmoid of
+each logit, the top-k of the scores plus a selection bias that enters the
+choice only, the scores renormalised by ``sum + 1e-6``. Embedding and head
+are two leaves, or one where the config ties them. The muP multipliers of a
+config (``embedding_multiplier``, ``lm_head_multiplier``,
+``attention_in/out_multiplier``, ``key_multiplier``, ``ssm_in/out_multiplier``,
+``ssm_multipliers``, ``mlp_multipliers``) are constants read as keys that
+default to 1: a config without them lowers to the program it lowered to
+before they were read.
 
-Three families, keys as published: ``CONFIGS["laguna_s"]`` (Laguna-S-2.1,
+Four families, keys as published: ``CONFIGS["laguna_s"]`` (Laguna-S-2.1,
 https://huggingface.co/poolside/Laguna-S-2.1/blob/main/config.json),
 ``CONFIGS["keye_vl2"]`` (the language model of Keye-VL-2.0-30B-A3B,
 https://huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B/blob/main/config.json;
-its vision tower is not here: the catalog has no width of it) and
+its vision tower is not here: the catalog has no width of it),
 ``CONFIGS["lfm2_8b_a1b"]`` (LFM2-8B-A1B,
-https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json);
-``laguna_tiny``, ``keye_tiny`` and ``lfm2_tiny`` keep their structure at a
-size the CPU tests run. What a layer is comes from the keys a config has
-(:func:`layer_plan`), never from a model's name.
+https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json) and
+``CONFIGS["falcon_h1_34b"]`` (Falcon-H1-34B-Instruct,
+https://huggingface.co/tiiuae/Falcon-H1-34B-Instruct/blob/main/config.json);
+``laguna_tiny``, ``keye_tiny``, ``lfm2_tiny`` and ``falcon_h1_tiny`` keep
+their structure at a size the CPU tests run. What a layer is comes from the
+keys a config has (:func:`layer_plan`), never from a model's name.
 What ONE CHIP holds of a model is :class:`Share`: the depth kept (leading
 layers; the rest lie on further chips as pipeline stages), over how many
 chips the routed experts of a layer are divided, and over how many the heads
-and conv channels (``tensor_shards``) and the vocabulary (``vocab_shards``).
+and conv channels (``tensor_shards``), the vocabulary (``vocab_shards``), a
+state-space mixer's heads (``ssm_shards``: whole groups, so that the scan and
+the gated norm over a group stay local) and a dense MLP's columns
+(``mlp_shards``: the chip's part of ``down_proj``'s sum).
 The chip then computes its partial results: its experts' part of the routed
 sum (what the absent experts would add is left out), its heads' part of the
-attention output, its channels' part of a conv operator's output, logits
-over its vocabulary rows. Nothing here stands in for the absent chips or
-their traffic; sums over all shares, the shared expert counted once, give
-the uncut layer (tests/test_decoder.py, tests/test_decoder_lfm2.py).
+attention output, its channels' part of a conv operator's output, its
+state-space heads' part of the mixer's output, its columns' part of a dense
+MLP's, logits over its vocabulary rows. Nothing here stands in for the absent
+chips or their traffic; sums over all shares, the shared expert counted once,
+give the uncut layer (tests/test_decoder.py, tests/test_decoder_lfm2.py,
+tests/test_decoder_falcon_h1.py).
 
 Laguna's config is silent on five things, set by the Qwen-MoE family's
 convention (whose keys it uses) and listed as ``assumed`` in
@@ -59,6 +79,16 @@ stays float32 beside a bfloat16 compute copy: ``float32_leaves``), so the
 fold and a checkpoint carry it, but nothing here maintains it: pre-training's
 load-balancing update outside the gradient is left out, the token loss gives
 it no gradient, and it stays as initialised.**
+Falcon-H1's row gives the keys and not the equations: the block's two mixers
+on one normed input, where each multiplier stands, ``in_proj``'s column order
+(z, x, B, C, dt), the conv over x, B and C together with its bias and silu,
+the softplus of ``dt``, ``D``'s skip and the gate before the grouped norm are
+transformers' ``modeling_falcon_h1.py``'s, listed in
+``benchmarks/reference/falcon_h1.py``. **The mixer's small leaves are drawn
+as Mamba-2 draws them** (``A`` uniform in [1, 16], ``dt`` log-uniform in
+[1e-3, 1e-1], ``D`` ones, taps and bias uniform in +-1/2), so that on a
+seeded model states outlive their chunk; ``A_log``, ``dt_bias`` and ``D``
+stay float32 beside a bfloat16 compute copy.
 """
 from __future__ import annotations
 
@@ -298,6 +328,100 @@ CONFIGS = {
         "use_expert_bias": True,
         "vocab_size": 64,
     },
+    # Falcon-H1-34B-Instruct's config.json, key for key (the catalog's row)
+    "falcon_h1_34b": {
+        "attention_bias": False,
+        "attention_in_multiplier": 1,
+        "attention_out_multiplier": 0.0375,
+        "attn_layer_indices": None,
+        "embedding_multiplier": 5.656854249492381,
+        "head_dim": 128,
+        "hidden_act": "silu",
+        "hidden_size": 5120,
+        "intermediate_size": 21504,
+        "key_multiplier": 0.011048543456039804,
+        "lm_head_multiplier": 0.0078125,
+        "mamba_chunk_size": 128,
+        "mamba_conv_bias": True,
+        "mamba_d_conv": 4,
+        "mamba_d_head": 128,
+        "mamba_d_ssm": 4096,
+        "mamba_d_state": 256,
+        "mamba_expand": 2,
+        "mamba_n_groups": 2,
+        "mamba_n_heads": 32,
+        "mamba_norm_before_gate": False,
+        "mamba_proj_bias": False,
+        "mamba_rms_norm": True,
+        "mamba_use_mlp": True,
+        "max_position_embeddings": 262144,
+        "mlp_bias": False,
+        "mlp_expansion_factor": 8,
+        "mlp_multipliers": [0.1767766952966369, 0.011160714285714284],
+        "model_type": "falcon_h1",
+        "num_attention_heads": 20,
+        "num_hidden_layers": 72,
+        "num_key_value_heads": 4,
+        "num_logits_to_keep": 1,
+        "projectors_bias": False,
+        "rms_norm_eps": 1e-05,
+        "rope_scaling": None,
+        "rope_theta": 100000000000,
+        "ssm_in_multiplier": 0.25,
+        "ssm_multipliers": [0.3535533905932738, 0.25, 0.1767766952966369,
+                            0.5, 0.3535533905932738],
+        "ssm_out_multiplier": 0.08838834764831845,
+        "tie_word_embeddings": False,
+        "vocab_size": 261120,
+    },
+    # the same keys for the CPU tests: 2 KV heads with 4 query heads of 16,
+    # 4 state-space heads of 16 in 2 groups, state 16, chunks of 8, an MLP
+    # whose columns go 8 ways in whole tiles of 128, every multiplier
+    # different from 1
+    "falcon_h1_tiny": {
+        "attention_bias": False,
+        "attention_in_multiplier": 0.9,
+        "attention_out_multiplier": 0.6,
+        "attn_layer_indices": None,
+        "embedding_multiplier": 2.5,
+        "head_dim": 16,
+        "hidden_act": "silu",
+        "hidden_size": 64,
+        "intermediate_size": 1024,
+        "key_multiplier": 0.7,
+        "lm_head_multiplier": 0.5,
+        "mamba_chunk_size": 8,
+        "mamba_conv_bias": True,
+        "mamba_d_conv": 4,
+        "mamba_d_head": 16,
+        "mamba_d_ssm": 64,
+        "mamba_d_state": 16,
+        "mamba_expand": 1,
+        "mamba_n_groups": 2,
+        "mamba_n_heads": 4,
+        "mamba_norm_before_gate": False,
+        "mamba_proj_bias": False,
+        "mamba_rms_norm": True,
+        "mamba_use_mlp": True,
+        "max_position_embeddings": 256,
+        "mlp_bias": False,
+        "mlp_expansion_factor": 16,
+        "mlp_multipliers": [0.8, 0.3],
+        "model_type": "falcon_h1",
+        "num_attention_heads": 4,
+        "num_hidden_layers": 6,
+        "num_key_value_heads": 2,
+        "num_logits_to_keep": 1,
+        "projectors_bias": False,
+        "rms_norm_eps": 1e-05,
+        "rope_scaling": None,
+        "rope_theta": 100,
+        "ssm_in_multiplier": 0.75,
+        "ssm_multipliers": [0.7, 1.5, 0.6, 1.25, 0.8],
+        "ssm_out_multiplier": 0.4,
+        "tie_word_embeddings": False,
+        "vocab_size": 64,
+    },
 }
 # queries of a full layer are scored in blocks of this many, each against the
 # keys up to its own end, so the scores of a long sequence never exist whole
@@ -316,18 +440,34 @@ class Share:
     indexer is held whole: its score sums over all its heads. A conv
     layer's channels go as the heads do: a chip holds the same ``hidden_size
     / tensor_shards`` channels of the three streams of ``in_proj``, of the
-    taps and of ``out_proj``'s rows, and computes its part of the output."""
+    taps and of ``out_proj``'s rows, and computes its part of the output.
+    A state-space mixer's heads go over ``ssm_shards`` chips (0: as the
+    heads), in whole groups: a chip holds ``mamba_n_heads / ssm_shards``
+    heads with the B and C of their ``mamba_n_groups / ssm_shards`` groups
+    (the columns of ``in_proj`` for its z, x, B, C and dt, the same channels
+    of the taps and their bias, its heads' ``A_log``, ``dt_bias`` and ``D``,
+    its groups' norm weights and ``out_proj``'s rows), so the scan and the
+    gated norm over a group are local and its part of the output is a sum's
+    term. A dense MLP's columns go over ``mlp_shards`` chips (0: held
+    whole): the same ``intermediate_size / mlp_shards`` columns of
+    ``gate_proj`` and ``up_proj`` and rows of ``down_proj``, the chip's part
+    of ``down_proj``'s sum."""
     layers: int = 0
     expert_shards: int = 1
     tensor_shards: int = 1
     index: int = 0
     vocab_shards: int = 0
+    ssm_shards: int = 0
+    mlp_shards: int = 0
 
 
 def _sparse(cfg: dict, layer: int) -> bool:
-    """Whether layer ``layer``'s MLP is sparse: ``mlp_layer_types``; without
-    it ``num_dense_layers`` leading dense layers; without that Qwen-MoE's
-    rule of ``mlp_only_layers`` and ``decoder_sparse_step``."""
+    """Whether layer ``layer``'s MLP is sparse: never in a config without
+    ``num_experts``; else ``mlp_layer_types``; without it
+    ``num_dense_layers`` leading dense layers; without that Qwen-MoE's rule
+    of ``mlp_only_layers`` and ``decoder_sparse_step``."""
+    if "num_experts" not in cfg:
+        return False
     if "mlp_layer_types" in cfg:
         return cfg["mlp_layer_types"][layer] == "sparse"
     if "num_dense_layers" in cfg:
@@ -355,24 +495,50 @@ def least_layers(cfg: dict) -> int:
 def held_config(name: str, share: Share = Share()) -> dict:
     """The published configuration ``name`` with the counts ``share`` holds
     in place of the published ones (no width changes), the published counts
-    under ``published`` and the held experts' first id under
-    ``first_expert``; where the model has conv layers (``conv_L_cache``)
-    the channels a chip holds of each under ``conv_channels``."""
+    under ``published`` and, where the model routes, the held experts' first
+    id under ``first_expert``; where the model has conv layers
+    (``conv_L_cache``) the channels a chip holds of each under
+    ``conv_channels``; where a dense MLP's columns are divided
+    (``mlp_shards``) those held under ``mlp_columns``
+    (``intermediate_size``, the width, stays). A state-space mixer's
+    channels are its held heads times ``mamba_d_head``; ``mamba_d_ssm``
+    stays as published."""
     cfg = dict(CONFIGS[name])
     n = share.layers or cfg["num_hidden_layers"]
     t, e = share.tensor_shards, share.expert_shards
-    v = share.vocab_shards or t
-    cut = ("num_hidden_layers", "num_experts", "num_attention_heads",
-           "num_key_value_heads", "vocab_size") + (
-               ("num_local_experts",) if "num_local_experts" in cfg else ())
+    v, m = share.vocab_shards or t, share.ssm_shards or t
+    routed = "num_experts" in cfg
+    cut = ("num_hidden_layers", "num_attention_heads",
+           "num_key_value_heads", "vocab_size") + tuple(
+               key for key in ("num_experts", "num_local_experts")
+               if key in cfg)
     divided = [("num_attention_heads", t), ("num_key_value_heads", t),
-               ("vocab_size", v), ("num_experts", e)]
+               ("vocab_size", v)]
+    if routed:
+        divided.append(("num_experts", e))
     if "conv_L_cache" in cfg:       # a conv layer's channels
         divided.append(("hidden_size", t))
+    if "mamba_n_groups" in cfg:     # a state-space mixer's heads, by groups
+        cut += ("mamba_n_heads", "mamba_n_groups")
+        divided += [("mamba_n_groups", m), ("mamba_n_heads", m)]
+    elif share.ssm_shards:
+        raise ValueError(f"{name}: ssm_shards {share.ssm_shards} on a "
+                         "config without mamba_n_groups: it has no "
+                         "state-space mixer to divide")
     for key, parts in divided:
         if cfg[key] % parts:
             raise ValueError(f"{name}: {key} {cfg[key]} does not divide "
                              f"over {parts} chips")
+    if share.mlp_shards:
+        if routed:
+            raise ValueError(
+                f"{name}: mlp_shards {share.mlp_shards} on a config with "
+                "num_experts: its MLPs are routed, and go by expert_shards")
+        if cfg["intermediate_size"] % (128 * share.mlp_shards):
+            raise ValueError(
+                f"{name}: intermediate_size {cfg['intermediate_size']} does "
+                f"not divide over {share.mlp_shards} chips in whole tiles "
+                "of 128 columns")
     if not 0 < n <= cfg["num_hidden_layers"]:
         raise ValueError(f"{name}: {n} layers of {cfg['num_hidden_layers']}")
     least = least_layers(cfg) if "layer_types" in cfg else 1
@@ -382,10 +548,13 @@ def held_config(name: str, share: Share = Share()) -> dict:
             f"dense layers and one whole period are {least}")
     cfg["published"] = {key: cfg[key] for key in cut}
     cfg.update(
-        num_hidden_layers=n, num_experts=cfg["num_experts"] // e,
+        num_hidden_layers=n,
         num_attention_heads=cfg["num_attention_heads"] // t,
         num_key_value_heads=cfg["num_key_value_heads"] // t,
         vocab_size=cfg["vocab_size"] // v)
+    if routed:
+        cfg["num_experts"] = cfg["num_experts"] // e
+        cfg["first_expert"] = (share.index % e) * cfg["num_experts"]
     if "num_local_experts" in cfg:
         cfg["num_local_experts"] = cfg["num_experts"]
     if "num_attention_heads_per_layer" in cfg:
@@ -397,7 +566,12 @@ def held_config(name: str, share: Share = Share()) -> dict:
     if "conv_L_cache" in cfg:
         cfg["published"]["conv_channels"] = cfg["hidden_size"]
         cfg["conv_channels"] = cfg["hidden_size"] // t
-    cfg["first_expert"] = (share.index % e) * cfg["num_experts"]
+    if "mamba_n_groups" in cfg:
+        cfg["mamba_n_heads"] //= m
+        cfg["mamba_n_groups"] //= m
+    if share.mlp_shards:
+        cfg["published"]["mlp_columns"] = cfg["intermediate_size"]
+        cfg["mlp_columns"] = cfg["intermediate_size"] // share.mlp_shards
     return cfg
 
 
@@ -634,6 +808,12 @@ def _weight(module, name, shape):
     return module.param(name, nn.initializers.normal(0.02), shape)
 
 
+def _times(x, multiplier):
+    """``x * multiplier``, a muP constant of the config; ``x`` itself where
+    it is 1 (a config without the key: no operation is added)."""
+    return x if multiplier == 1 else x * jnp.asarray(multiplier, x.dtype)
+
+
 def rms_norm(x, w, eps: float):
     """``x / sqrt(mean(x^2) + eps) * w``, in float32."""
     x32 = x.astype(jnp.float32)
@@ -696,6 +876,7 @@ class Attention(nn.Module):
     qk_norm: bool = False   # an RMSNorm over each head's q and k features
     eps: float = 1e-6       # of the QK-norm and the indexer's LayerNorm
     indexer: Tuple = ()     # sa_config as sorted items, where it selects
+    key_multiplier: float = 1   # on the keys' projection (muP)
 
     @nn.compact
     def __call__(self, x, positions=None):
@@ -704,7 +885,8 @@ class Attention(nn.Module):
         group = self.q_heads // n
         with jax.named_scope("attention"):
             q = x @ _weight(self, "q_proj", (hidden, self.q_heads * d))
-            k = x @ _weight(self, "k_proj", (hidden, n * d))
+            k = _times(x @ _weight(self, "k_proj", (hidden, n * d)),
+                       self.key_multiplier)
             v = x @ _weight(self, "v_proj", (hidden, n * d))
             if self.gate:
                 gate = jax.nn.sigmoid(
@@ -779,16 +961,198 @@ class ShortConv(nn.Module):
                 self, "out_proj", (self.channels, hidden))
 
 
+def carried_states(own, keep):
+    """The state that ENTERS each chunk, ``[B, chunks, ..., P, N]`` float32,
+    from each chunk's ``own`` end state (what its tokens add from a zero
+    start) and ``keep [B, chunks, ...]``, the share of an entering state
+    that survives the chunk: ``s[0] = 0``, ``s[c + 1] = keep[c] * s[c] +
+    own[c]``, a ``lax.scan`` of as many steps as there are chunks."""
+    def step(state, chunk):
+        own_c, keep_c = chunk
+        return keep_c[..., None, None] * state + own_c, state
+
+    _, entering = jax.lax.scan(
+        step, jnp.zeros_like(own[:, 0]),
+        (jnp.moveaxis(own, 1, 0), jnp.moveaxis(keep, 1, 0)))
+    return jnp.moveaxis(entering, 0, 1)
+
+
+def ssm_scan(x, dt, a, b, c, d_skip, chunk: int):
+    """Mamba-2's recurrence over a sequence, chunk by chunk: per head ``j``
+    of group ``g``, with the state ``S [P, N]`` zero before the sequence,
+    ``S_t = exp(dt_t a_j) S_{t-1} + dt_t outer(x_t, b_t^g)`` and ``y_t = S_t
+    c_t^g + d_skip_j x_t``. ``x [B, S, H, P]``, ``dt [B, S, H]`` (after the
+    softplus, float32), ``a [H]`` (negative, float32), ``b, c [B, S, G,
+    N]``, ``d_skip [H]``; returns ``(y [B, S, H, P] float32, keep [B,
+    chunks, H])``, the second the share of a chunk's entering state that
+    survives it.
+
+    The same numbers as the token-by-token recurrence, in another order
+    (the state-space duality's): inside a chunk of ``chunk`` tokens the
+    quadratic form, ``y_i += sum_{j <= i} (c_i . b_j) exp(sum_{j < k <= i}
+    dt_k a) dt_j x_j``; each chunk's own end state ``sum_j exp(sum_{k > j}
+    dt_k a) dt_j outer(x_j, b_j)``; the state entering a chunk from
+    :func:`carried_states`, read by ``y_i += exp(sum_{k <= i} dt_k a) (S_in
+    c_i)``. Every decay comes from one cumulative sum of ``dt a`` over the
+    chunk, in float32 (differences of it, never a product of factors); the
+    four products run in ``x``'s type with float32 sums; JAX differentiates
+    all of it (a backward of einsums and a scan of as many steps as there
+    are chunks: nothing here keeps a copy per token). A sequence that the
+    chunk does not divide is padded with tokens of ``dt`` 0, which neither
+    decay nor add."""
+    bsz, s_len, heads, p = x.shape
+    groups, n = b.shape[2:]
+    per, dtype = heads // groups, x.dtype
+    pad = -s_len % chunk
+    if pad:
+        x, dt, b, c = (jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
+                       for t in (x, dt, b, c))
+    nc = (s_len + pad) // chunk
+    # [B, chunks, chunk, groups, heads a group, ...]
+    x = x.reshape(bsz, nc, chunk, groups, per, p)
+    dt = dt.reshape(bsz, nc, chunk, groups, per)
+    b, c = (t.reshape(bsz, nc, chunk, groups, n) for t in (b, c))
+    log_decay = jnp.cumsum(dt * a.reshape(groups, per), axis=2)
+    weighted = (x.astype(jnp.float32) * dt[..., None])   # dt_j x_j
+    # inside the chunks: who sees whom, and how much of it is left
+    seen = jnp.tril(jnp.ones((chunk, chunk), bool))[:, :, None, None]
+    left = jnp.exp(jnp.where(
+        seen, log_decay[:, :, :, None] - log_decay[:, :, None], -jnp.inf))
+    scores = jnp.einsum("zclgn,zcsgn->zclsg", c, b,
+                        preferred_element_type=jnp.float32)
+    y = jnp.einsum("zclsgr,zcsgrp->zclgrp",
+                   (scores[..., None] * left).astype(dtype),
+                   weighted.astype(dtype),
+                   preferred_element_type=jnp.float32)
+    # across the chunks: each chunk's own end state, what enters the next
+    to_end = jnp.exp(log_decay[:, :, -1:] - log_decay)
+    own = jnp.einsum("zcsgrp,zcsgn->zcgrpn",
+                     (weighted * to_end[..., None]).astype(dtype), b,
+                     preferred_element_type=jnp.float32)
+    keep = jnp.exp(log_decay[:, :, -1])
+    entering = carried_states(own, keep)
+    y = y + jnp.exp(log_decay)[..., None] * jnp.einsum(
+        "zclgn,zcgrpn->zclgrp", c, entering.astype(dtype),
+        preferred_element_type=jnp.float32)
+    y = y + d_skip.reshape(groups, per, 1) * x.astype(jnp.float32)
+    return (y.reshape(bsz, nc * chunk, heads, p)[:, :s_len],
+            keep.reshape(bsz, nc, heads))
+
+
+def segment_multipliers(multipliers, channels: int, group_states: int,
+                        heads: int):
+    """The constant vector ``m`` over ``in_proj``'s columns: the config's
+    five ``ssm_multipliers`` over the segments z, x, B, C and dt."""
+    return np.repeat(np.asarray(multipliers, np.float32),
+                     [channels, channels, group_states, group_states, heads])
+
+
+def _uniform(low, high):
+    """An initialiser: uniform in ``[low, high]``."""
+    def init(key, shape, dtype=jnp.float32):
+        return jax.random.uniform(key, shape, dtype, low, high)
+    return init
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """Mamba-2's: ``A`` uniform in [1, 16], stored as its logarithm."""
+    return jnp.log(_uniform(1.0, 16.0)(key, shape, dtype))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """Mamba-2's: ``dt`` log-uniform in [1e-3, 1e-1], stored through the
+    inverse of the softplus."""
+    dt = jnp.exp(_uniform(math.log(1e-3), math.log(1e-1))(key, shape, dtype))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class StateSpace(nn.Module):
+    """The held heads' part of one Mamba-2 mixer's output: ``heads`` heads
+    of ``head_dim`` channels in ``groups`` groups that share their B and C
+    of ``state`` features. ``p = ((x * in_multiplier) W_in) * m`` with
+    ``W_in``'s columns in the order z, x, B, C, dt (:func:`segment_multipliers`);
+    ``[x, B, C] = silu(conv([x, B, C]) + bias)``, a causal depthwise
+    convolution of ``taps`` taps over those channels together
+    (:func:`short_conv`); ``dt = softplus(dt + dt_bias)``, ``A =
+    -exp(A_log)``; the recurrence (:func:`ssm_scan`, chunks of ``chunk``)
+    with ``D``'s skip; ``y * silu(z)``, an RMSNorm over each group's
+    channels with a learned weight, ``W_out``. The projection's output, the
+    conv, the decays, the state and the norm are float32; the products run
+    in the input's type. ``A_log``, ``dt_bias`` and ``D`` stay float32
+    beside a compute copy (``Decoder.float32_leaves``) and are drawn as
+    Mamba-2 draws them, so that states outlive their chunk: ``A`` uniform
+    in [1, 16], ``dt`` log-uniform in [1e-3, 1e-1], ``D`` ones; the taps
+    and their bias uniform in +-1/sqrt(taps), as a depthwise ``Conv1d``'s."""
+    heads: int
+    head_dim: int
+    groups: int
+    state: int
+    taps: int
+    chunk: int
+    eps: float
+    in_multiplier: float = 1
+    multipliers: Tuple = (1, 1, 1, 1, 1)
+
+    @nn.compact
+    def __call__(self, x):
+        bsz, s_len, hidden = x.shape
+        h, g, n = self.heads, self.groups, self.state
+        ch, bound = h * self.head_dim, 1 / math.sqrt(self.taps)
+        with jax.named_scope("ssm"):
+            p = jnp.dot(_times(x, self.in_multiplier), _weight(
+                self, "in_proj", (hidden, 2 * ch + 2 * g * n + h)),
+                preferred_element_type=jnp.float32)
+            p = p * segment_multipliers(self.multipliers, ch, g * n, h)
+            z, xbc, dt = jnp.split(p, [ch, 2 * ch + 2 * g * n], axis=-1)
+            with jax.named_scope("conv"):
+                taps = self.param("conv", _uniform(-bound, bound),
+                                  (ch + 2 * g * n, self.taps))
+                bias = self.param("conv_bias", _uniform(-bound, bound),
+                                  (ch + 2 * g * n,))
+                xbc = jax.nn.silu(
+                    short_conv(xbc, taps.astype(jnp.float32))
+                    + bias.astype(jnp.float32))
+            u, b, c = jnp.split(xbc.astype(x.dtype), [ch, ch + g * n],
+                                axis=-1)
+            with jax.named_scope("scan"):
+                dt = jax.nn.softplus(dt + self.param(
+                    "dt_bias", _dt_bias_init, (h,)).astype(jnp.float32))
+                a = -jnp.exp(self.param("A_log", _a_log_init, (h,)).astype(
+                    jnp.float32))
+                y, keep = ssm_scan(
+                    u.reshape(bsz, s_len, h, self.head_dim), dt, a,
+                    b.reshape(bsz, s_len, g, n), c.reshape(bsz, s_len, g, n),
+                    self.param("D", nn.initializers.ones, (h,)).astype(
+                        jnp.float32), self.chunk)
+            # free unless the caller opens the collection (obs/ssm_carry.py)
+            if self.is_mutable_collection(EXPERT_STATS):
+                self.sow(EXPERT_STATS, "ssm_chunk_keep", keep)
+                self.sow(EXPERT_STATS, "ssm_dt", dt)
+            with jax.named_scope("norm"):
+                y = y.reshape(bsz, s_len, g, ch // g) * jax.nn.silu(
+                    z).reshape(bsz, s_len, g, ch // g)
+                y = rms_norm(y, _norm_weight(self, "norm", ch).reshape(
+                    g, ch // g), self.eps).reshape(bsz, s_len, ch)
+            return y.astype(x.dtype) @ _weight(self, "out_proj",
+                                               (ch, hidden))
+
+
 class SwiGLU(nn.Module):
+    """``(silu((x W_gate) * m[0]) * (x W_up)) W_down * m[1]`` over the
+    ``width`` columns held (all of them, or a chip's share of a dense MLP's:
+    then its part of ``W_down``'s sum); ``m`` the config's
+    ``mlp_multipliers``, ones without the key."""
     width: int
+    multipliers: Tuple = (1, 1)
 
     @nn.compact
     def __call__(self, x):
         hidden = x.shape[-1]
-        gate = x @ _weight(self, "gate_proj", (hidden, self.width))
+        gate = _times(x @ _weight(self, "gate_proj", (hidden, self.width)),
+                      self.multipliers[0])
         up = x @ _weight(self, "up_proj", (hidden, self.width))
-        return (jax.nn.silu(gate) * up) @ _weight(
-            self, "down_proj", (self.width, hidden))
+        return _times((jax.nn.silu(gate) * up) @ _weight(
+            self, "down_proj", (self.width, hidden)), self.multipliers[1])
 
 
 class ExpertWeights(nn.Module):
@@ -1069,7 +1433,14 @@ def layer_plan(cfg: dict, layer: int) -> dict:
     balanced by a selection bias, so ``use_expert_bias`` has to be stated
     with it), ``softmax`` over all logits for a config without the key
     (and then without a bias). The two other pairings are refused by the
-    keys' names: no config here needs them and no branch routes so."""
+    keys' names: no config here needs them and no branch routes so.
+    ``ssm``: whether the block feeds its one normed input to a state-space
+    mixer BESIDE the attention and adds both outputs (a config with
+    ``mamba_d_ssm`` and no ``layer_types``: every layer). ``multipliers``:
+    the twelve muP constants, each read as a key that defaults to 1:
+    ``embedding``, ``lm_head``, ``attention_in``, ``attention_out``, ``key``,
+    ``ssm_in``, ``ssm_out`` (``<name>_multiplier``), the five
+    ``ssm_multipliers`` and the two ``mlp_multipliers``."""
     n = cfg["num_hidden_layers"]
     kind = cfg["layer_types"][layer] if "layer_types" in cfg else (
         "selected_attention" if cfg.get("sa_config") else "full_attention")
@@ -1094,7 +1465,14 @@ def layer_plan(cfg: dict, layer: int) -> dict:
         "eps": _norm_eps(cfg),
         "scale": cfg.get("moe_routed_scaling_factor",
                          cfg.get("routed_scaling_factor", 1)),
-        "score": score}
+        "score": score,
+        "ssm": "mamba_d_ssm" in cfg and "layer_types" not in cfg,
+        "multipliers": {
+            **{key: cfg.get(key + "_multiplier", 1) for key in (
+                "embedding", "lm_head", "attention_in", "attention_out",
+                "key", "ssm_in", "ssm_out")},
+            "ssm": tuple(cfg.get("ssm_multipliers", (1,) * 5)),
+            "mlp": tuple(cfg.get("mlp_multipliers", (1, 1)))}}
 
 
 class Block(nn.Module):
@@ -1106,22 +1484,35 @@ class Block(nn.Module):
         cfg = _thaw(self.cfg)
         plan = layer_plan(cfg, self.layer)
         eps, hidden = plan["eps"], x.shape[-1]
+        mult = plan["multipliers"]
         # the norm before the operator keeps its name whatever the operator
         h = rms_norm(x, _norm_weight(self, "attn_norm", hidden), eps)
         if plan["kind"] == "conv":
             x = x + ShortConv(cfg["conv_channels"], cfg["conv_L_cache"],
                               name="conv")(h)
         else:
-            x = x + Attention(
+            mixed = _times(Attention(
                 plan["kind"], plan["heads"], cfg["num_key_value_heads"],
                 plan["head_dim"], cfg.get("sliding_window") or 0,
                 _freeze(plan["rope"]), plan["gate"], plan["qk_norm"], eps,
-                _freeze(cfg.get("sa_config") or {}), name="attention")(
-                    h, positions)
+                _freeze(cfg.get("sa_config") or {}), mult["key"],
+                name="attention")(_times(h, mult["attention_in"]), positions),
+                mult["attention_out"])
+            if plan["ssm"]:
+                # two mixers side by side on the one normed input
+                mixed = mixed + _times(StateSpace(
+                    cfg["mamba_n_heads"], cfg["mamba_d_head"],
+                    cfg["mamba_n_groups"], cfg["mamba_d_state"],
+                    cfg["mamba_d_conv"], cfg["mamba_chunk_size"], eps,
+                    mult["ssm_in"], mult["ssm"], name="ssm")(h),
+                    mult["ssm_out"])
+            x = x + mixed
         h = rms_norm(x, _norm_weight(self, "mlp_norm", hidden), eps)
         if not plan["sparse"]:
             with jax.named_scope("dense_mlp"):
-                return x + SwiGLU(cfg["intermediate_size"], name="mlp")(h)
+                return x + SwiGLU(
+                    cfg.get("mlp_columns", cfg["intermediate_size"]),
+                    mult["mlp"], name="mlp")(h)
         return x + SparseMLP(
             cfg["published"]["num_experts"], cfg["num_experts_per_tok"],
             cfg["norm_topk_prob"], plan["scale"],
@@ -1150,7 +1541,9 @@ class Decoder(nn.Module):
     # leaves the apply closure leaves out of its compute copy
     # (``models.make_apply_fn``): the selection bias is added to float32
     # scores and decides a choice, so it is not rounded with the matrices
-    float32_leaves = ("expert_bias",)
+    # nor are a state-space mixer's ``A_log``, ``dt_bias`` and ``D``: they
+    # set decays that multiply up over a chunk's tokens
+    float32_leaves = ("expert_bias", "A_log", "dt_bias", "D")
 
     @property
     def tpu_compiler_options(self) -> dict:
@@ -1171,9 +1564,11 @@ class Decoder(nn.Module):
     def __call__(self, tokens, train: bool = False, positions=None):
         cfg = _thaw(self.cfg)
         hidden, vocab = cfg["hidden_size"], cfg["vocab_size"]
+        mult = layer_plan(cfg, 0)["multipliers"]
         with jax.named_scope("embed"):
             embed = _weight(self, "embed", (vocab, hidden))
-            x = jnp.take(embed, tokens, axis=0)
+            x = _times(jnp.take(embed, tokens, axis=0),
+                       mult["embedding"])
         if positions is None and "rope_parameters" not in cfg:
             # text: every stream counts the tokens (three where the config
             # divides the frequency pairs into sections, else one). Given
@@ -1210,10 +1605,13 @@ class Decoder(nn.Module):
             if cfg.get("tie_word_embeddings"):
                 # the embedding is the head: one leaf, both uses in its
                 # gradient
-                return jnp.einsum("bsh,vh->bsv", x, embed,
-                                  preferred_element_type=jnp.float32)
-            return jnp.dot(x, _weight(self, "lm_head", (hidden, vocab)),
-                           preferred_element_type=jnp.float32)
+                logits = jnp.einsum("bsh,vh->bsv", x, embed,
+                                    preferred_element_type=jnp.float32)
+            else:
+                logits = jnp.dot(
+                    x, _weight(self, "lm_head", (hidden, vocab)),
+                    preferred_element_type=jnp.float32)
+            return _times(logits, mult["lm_head"])
 
 
 def _freeze(value):

@@ -6,7 +6,8 @@ held expert, every token's chosen experts and, where the router chooses by
 its scores plus a selection bias, the experts the scores alone would choose;
 all free unless a caller opens the collection. :func:`record_expert_load`
 runs one forward of a batch with it open and sets three gauges in a metrics
-registry (and a fourth where a layer selects its keys: :mod:`.selection`):
+registry (and a fourth where a layer selects its keys: :mod:`.selection`;
+two more where a layer has a state-space mixer: :mod:`.ssm_carry`):
 
 ``expert_load_max_over_mean``
     the fullest held expert's slots over the mean of the held experts',
@@ -63,23 +64,28 @@ def record_expert_load(algo, params, registry) -> dict:
     """Gauges of the first training batch of client 0 (``hp.batch_size``
     rows) through ``algo``'s model, from ONE forward with the collection
     open: the three above and, where a layer selects its keys,
-    ``selected_key_share`` (:mod:`.selection`). ``{}`` and no gauge for a
-    model that sows nothing. Returns what it set."""
+    ``selected_key_share`` (:mod:`.selection`); where one has a state-space
+    mixer, ``ssm_chunk_carry`` and ``ssm_dt_mean`` (:mod:`.ssm_carry`).
+    ``{}`` and no gauge for a model that sows nothing. Returns what it
+    set."""
     import jax
 
     from .selection import (key_share, set_selected_key_share,
                             stacked_selection)
+    from .ssm_carry import carry_stats, set_ssm_carry
 
     def gauges(p, x):
         _, sown = algo.apply_fn(p, x, train=False, rng=None,
                                 mutable=[COLLECTION])
         kept = stacked_selection(sown)
-        return stacked_stats(sown), None if kept is None else key_share(kept)
+        return (stacked_stats(sown),
+                None if kept is None else key_share(kept), carry_stats(sown))
 
     x = algo.data.x_train[0, :algo.hp.batch_size]
-    stats, share = jax.jit(gauges)(params, x)
+    stats, share, carry = jax.jit(gauges)(params, x)
     return {**set_expert_load(stats, registry),
-            **set_selected_key_share(share, registry)}
+            **set_selected_key_share(share, registry),
+            **set_ssm_carry(carry, registry)}
 
 
 def set_expert_load(stats: dict, registry) -> dict:

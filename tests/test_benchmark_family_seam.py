@@ -173,6 +173,63 @@ def test_the_short_conv_family_loads_builds_and_checks(tmp_path):
             (("longctx", 1),)), "tiny_shortconv.longctx")
 
 
+def test_the_hybrid_family_loads_builds_and_checks(tmp_path):
+    """One case of the ``tokens_hybrid`` family (the whole rehearsal is
+    benchmarks/tests/test_tokens_hybrid_family.py): a tiny configuration of
+    it under the cell's own traffic mix is found by name, builds through the
+    program's entry points with ``--lm_ssm_shards`` and ``--lm_mlp_shards``,
+    and passes its own reference check (the program's chunked scan against
+    the reference's token-by-token recurrence in the forward pass and in one
+    compiled round), the carry's gauge set on the way."""
+    bench = _bench_conftest()
+    from benchmarks.lib import harness, manifest
+    from neuroimagedisttraining_tpu.experiments import parse_args
+    from neuroimagedisttraining_tpu.models import decoder
+
+    family = manifest.family_of({"family": "tokens_hybrid"})
+    held = decoder.held_config("falcon_h1_tiny",
+                               decoder.Share(4, 1, 2, 0, 4, 2, 2))
+    config = {
+        "name": "tiny_hybrid", "source": "test fixture",
+        "family": "tokens_hybrid", "reference": "falcon_h1",
+        "published": held.pop("published"),
+        "held": {k: held.pop(k) for k in family.HELD_KEYS},
+        "flags": {"algo": "fedavg", "model": "falcon_h1_tiny", "lm_layers": 4,
+                  "lm_tensor_shards": 2, "lm_ssm_shards": 2,
+                  "lm_mlp_shards": 2, "lm_vocab_shards": 4,
+                  "dataset": "token_shards", "track_personal": 0,
+                  "client_chunk": 1, "batch_size": 1, "epochs": 1, "lr": 0.5,
+                  "momentum": 0.0, "grad_clip": 10.0},
+        "cohort": {"n_sites": 8, "train_per_site": 1, "test_per_site": 1,
+                   "sequence_length": 32},
+        **held}
+    assert set(held) <= family.CONFIG_KEYS
+    path = bench.write_manifest(tmp_path, config, (("longctx", 1),))
+    cell = manifest.load_cell(path, "tiny_hybrid.longctx")
+    assert cell.family is family
+    assert {"ssm_ms_per_round", "ssm_scan_ms_per_round", "ssm_roofline",
+            "ssm_scan_roofline", "ssm_chunk_carry"} <= {
+                e["name"] for e, _ in cell.per_layer}
+    argv = harness.program_flags(cell, 3)
+    assert argv[argv.index("--lm_ssm_shards") + 1] == "2"
+    assert argv[argv.index("--lm_mlp_shards") + 1] == "2"
+    algo = harness.build(cell, parse_args(argv), 3)
+    assert algo.data.x_train.shape == (8, 1, 32)
+    assert algo.clients_per_round == 2 and algo.data.class_num == 16
+    state = algo.init_state(jax.random.PRNGKey(3))
+    report = family.reference_check(
+        algo, state.global_params, harness.reference_of(cell), cell.config)
+    assert report["ok"], report
+    assert set(family.TOLERANCE) < set(report)
+    assert report["compared_positions"] == 1
+    assert max(report[n]["error"] for n in family.FOLD_LEAVES) < 1e-3
+    assert 0.1 < report["ssm_carry"]["ssm_chunk_carry"] < 1.0
+    with pytest.raises(ValueError, match="unknown key"):
+        manifest.load_cell(bench.write_manifest(
+            tmp_path / "bad", {**config, "num_experts": 8},
+            (("longctx", 1),)), "tiny_hybrid.longctx")
+
+
 # ---------------------------------------------------------------------------
 # the static guard
 # ---------------------------------------------------------------------------

@@ -307,6 +307,65 @@ def test_folding_round_of_the_short_conv_decoder_holds_its_scopes():
         assert set(args.get("layers", {}).values()) <= {"short_conv"}
 
 
+SSM_SCOPES = ("ssm", "ssm/conv", "ssm/scan", "ssm/norm")
+
+
+def test_folding_round_of_the_hybrid_decoder_holds_its_scopes():
+    """The tiny decoder with a state-space mixer beside attention in every
+    block, compiled round: ``ssm`` around the whole mixer with ``conv``,
+    ``scan`` and ``norm`` inside it in both passes (the scopes the four
+    ``ssm_*`` metric files ask for by name), the attention's
+    ``attention/full`` beside it, the dense MLP of a share's columns, and
+    none of the scopes of layers this model has none of."""
+    import json
+    import os
+
+    from neuroimagedisttraining_tpu.data.tokens import make_token_shards
+    from neuroimagedisttraining_tpu.models import decoder
+
+    share = decoder.Share(4, 1, 2, 0, 4, 2, 2)
+    data = make_token_shards(0, n_clients=4, vocab=16, sequence_length=32,
+                             train_per_client=1)
+    hp = HyperParams(lr=0.05, local_epochs=1, steps_per_epoch=1,
+                     batch_size=1)
+    algo = FedAvg(decoder.decoder("falcon_h1_tiny", share), data, hp,
+                  loss_type="token_ce", frac=0.5, seed=3, client_chunk=1,
+                  track_personal=False)
+    state = algo.init_state(jax.random.PRNGKey(3))
+    compiled = algo._round_jit.lower(
+        state, jnp.arange(2, dtype=jnp.int32), jnp.asarray(0, jnp.float32),
+        data.x_train, data.y_train, data.n_train).compile()
+    names = op_names(compiled.as_text())
+    for scope in ("local_train", "aggregate"):
+        assert count(names, scope) > 0, scope
+    for scope in ("embed", "attention", "attention/full", "dense_mlp",
+                  "lm_head") + SSM_SCOPES:
+        assert count(names, scope, "fwd") > 0, (scope, "forward")
+        assert count(names, scope, "bwd") > 0, (scope, "backward")
+    # the three inner scopes sit inside the mixer's, and the carry's loop
+    # over the chunks inside the scan's
+    for inner in ("conv", "scan", "norm"):
+        assert count(names, inner) == count(names, "ssm/" + inner) > 0
+    assert any("while" in n for n in names if scopes.under(n, "ssm/scan"))
+    for scope in ("attention/window", "attention/indexer", "short_conv",
+                  "router", "experts", "shared_expert"):
+        assert count(names, scope) == 0, scope
+    metrics = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "metrics")
+    asked = set()
+    for name in ("ssm_ms_per_round", "ssm_scan_ms_per_round", "ssm_roofline",
+                 "ssm_scan_roofline"):
+        with open(os.path.join(metrics, name + ".json")) as f:
+            args = json.load(f)["args"]
+        asked |= {args["scope"]} | set(args.get("layers", {}).values())
+    assert asked == set(SSM_SCOPES)
+    # and the table of scopes names them in its one place
+    import inspect
+
+    from neuroimagedisttraining_tpu.algorithms import base
+    assert "ssm (a state-space mixer)" in inspect.getsource(base)
+
+
 @pytest.mark.parametrize("platform,spelling,products", [
     ("cpu", "xla", ("dot_general", "dot_general")),
     ("tpu", "kernel", ("jit(attention_forward)", "jit(attention_backward)"))])
