@@ -103,7 +103,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops import grouped_mlp
+from ..ops import embedding, grouped_mlp
 from ..ops.masked_attention import (
     OPERAND_NAMES,
     SAVED_NAMES,
@@ -1567,7 +1567,7 @@ class Decoder(nn.Module):
         mult = layer_plan(cfg, 0)["multipliers"]
         with jax.named_scope("embed"):
             embed = _weight(self, "embed", (vocab, hidden))
-            x = _times(jnp.take(embed, tokens, axis=0),
+            x = _times(embedding.lookup(embed, tokens),
                        mult["embedding"])
         if positions is None and "rope_parameters" not in cfg:
             # text: every stream counts the tokens (three where the config

@@ -535,6 +535,46 @@ def test_share_cuts_counts_never_widths_and_builds_the_stated_model():
     assert rows["norms"] == 46_080
 
 
+def test_real_width_gradient_lowers_with_the_embeddings_own_backward():
+    """One layer of the cell's share at its real widths, 256 tokens, nothing
+    allocated (shapes only): the gradient program lowers, the ``embed``
+    leaf's gradient has the held table's shape ``[32640, 5120]`` and the
+    master's dtype, and at 5120 columns the lookup's backward is its own:
+    two scatter-adds, 4096 and 1024 columns wide, none of 5120."""
+    import re
+
+    model = create_model("falcon_h1_34b", num_classes=32640, layers=1,
+                         tensor_shards=4, vocab_shards=8, ssm_shards=2,
+                         mlp_shards=8)
+    params = jax.eval_shape(lambda: init_params(
+        model, jax.random.PRNGKey(0), (16,), jnp.int32))
+    assert params["embed"].shape == (32640, 5120)
+    apply_fn = make_apply_fn(model, jnp.bfloat16)
+    tokens = jax.ShapeDtypeStruct((1, 256), jnp.int32)
+
+    def loss(p, x, y):
+        logits = apply_fn(p, x, train=True, rng=jax.random.PRNGKey(0))
+        return jnp.mean(PER_EXAMPLE_LOSSES["token_ce"](logits, y))
+
+    before = obs_metrics.set_registry(None)
+    try:
+        traced = jax.jit(jax.grad(loss)).trace(params, tokens, tokens)
+        counted = obs_metrics.get_registry().snapshot()[
+            "embed_lowerings"]["labeled"]
+    finally:
+        obs_metrics.set_registry(before)
+    grads = traced.out_info
+    assert grads["embed"].shape == (32640, 5120)
+    assert grads["embed"].dtype == jnp.float32
+    assert counted == {"pass=forward,spelling=slabs": 1.0,
+                       "pass=backward,spelling=slabs": 1.0}
+    text = traced.lower().as_text()
+    wide = sorted(int(w) for w in re.findall(
+        r'"stablehlo\.scatter"\(.*?\}\) : \(tensor<32640x(\d+)xbf16>', text,
+        flags=re.S))
+    assert wide == [1024, 4096], wide
+
+
 def test_small_leaves_are_drawn_as_mamba2_draws_them():
     """``A`` in [1, 16], ``dt`` in [1e-3, 1e-1] through the inverse softplus,
     ``D`` ones, taps and bias in +-1/2: states cross chunk boundaries on a

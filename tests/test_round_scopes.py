@@ -366,6 +366,68 @@ def test_folding_round_of_the_hybrid_decoder_holds_its_scopes():
     assert "ssm (a state-space mixer)" in inspect.getsource(base)
 
 
+@pytest.mark.parametrize("widest,spelling,widths", [
+    (48, "slabs", [48, 16]), (None, "take", [64])])
+def test_the_embeddings_backward_keeps_its_scope_whatever_its_spelling(
+        widest, spelling, widths, monkeypatch):
+    """``embed_ms_per_round`` reads the scope ``embed`` in both passes. The
+    token lookup has a backward of its own wherever the table is wider than
+    XLA's scatter takes whole (ops/embedding.py: column slabs behind a
+    ``custom_vjp``; here the tiny hybrid decoder's 64 columns against a
+    widest slab of 48): in the folding round's lowered program the slabs'
+    scatter-adds and their join still stand under ``embed``, going
+    backward, no scatter there is wider than its slab, and the trace counts
+    one forward and one backward under the spelling taken. With the rule as
+    it stands 64 columns are one slab, the lookup is ``jnp.take`` and the
+    one scatter is its own gradient's."""
+    from neuroimagedisttraining_tpu.data.tokens import make_token_shards
+    from neuroimagedisttraining_tpu.models import decoder
+    from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
+    from neuroimagedisttraining_tpu.ops import embedding
+
+    if widest:
+        monkeypatch.setattr(embedding, "_WIDEST", widest)
+    share = decoder.Share(4, 1, 2, 0, 4, 2, 2)
+    data = make_token_shards(0, n_clients=4, vocab=16, sequence_length=32,
+                             train_per_client=1)
+    hp = HyperParams(lr=0.05, local_epochs=1, steps_per_epoch=1,
+                     batch_size=1)
+    algo = FedAvg(decoder.decoder("falcon_h1_tiny", share), data, hp,
+                  loss_type="token_ce", frac=0.5, seed=3, client_chunk=1,
+                  track_personal=False)
+    state = algo.init_state(jax.random.PRNGKey(3))
+    before = obs_metrics.set_registry(None)
+    try:
+        lowered = algo._round_jit.trace(
+            state, jnp.arange(2, dtype=jnp.int32),
+            jnp.asarray(0, jnp.float32), data.x_train, data.y_train,
+            data.n_train).lower()
+        counted = obs_metrics.get_registry().snapshot()[
+            "embed_lowerings"]["labeled"]
+    finally:
+        obs_metrics.set_registry(before)
+    assert counted == {
+        f"pass={p},spelling={spelling}": 1.0
+        for p in (("forward", "backward") if widest else ("forward",))}
+    text = lowered.as_text(debug_info=True)
+    # the only scatters of rows in this model are the embedding's gradient
+    # (the loss's picks one number a token): one a slab, as wide as its slab
+    rows = [int(m.group(1)) for _, kind in scatters(text)
+            for m in [re.fullmatch(r"tensor<1x32x(\d+)xf32>", kind)]
+            if m and int(m.group(1)) > 1]
+    assert rows == widths, scatters(text)
+    # they stand in ``jnp.take``'s own jitted transpose, called under the
+    # scope: the backward's name stack survives the ``custom_vjp``
+    names = op_names(text)
+    for direction in ("fwd", "bwd"):
+        assert count(names, "embed", direction) > 0, direction
+    backward = {n.split("embed/")[-1] for n in names
+                if scopes.under(n, "embed") and scopes.direction(n) == "bwd"}
+    assert "jit(_take)" in backward
+    assert {"slice", "concatenate"} <= backward if widest else not (
+        {"slice", "concatenate"} & backward), backward
+
+
 @pytest.mark.parametrize("platform,spelling,products", [
     ("cpu", "xla", ("dot_general", "dot_general")),
     ("tpu", "kernel", ("jit(attention_forward)", "jit(attention_backward)"))])
