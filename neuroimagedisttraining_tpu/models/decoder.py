@@ -136,7 +136,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops import embedding, grouped_mlp
+from ..ops import delta_rule, embedding, grouped_mlp
 from ..ops.masked_attention import (
     OPERAND_NAMES,
     SAVED_NAMES,
@@ -1307,7 +1307,10 @@ def unit_lower_solve(lower, rhs):
     formed, so correlated keys lose nothing), every product at the highest
     precision; ``C`` padded to a power of two. The same numbers as XLA's
     ``triangular_solve``, and on the chip no faster end to end (-1.7 % in
-    the round: PERF.md sections 6 and 7, PR 41)."""
+    the round: PERF.md sections 6 and 7). It serves XLA's spelling
+    of the scan alone (:func:`_delta_rule_chunks`: the CPU, widths the
+    kernels do not take); ``ops/delta_rule.py``'s kernels invert a chunk's
+    system in VMEM by the same doubling."""
     def times(a, b):
         return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
@@ -1342,16 +1345,32 @@ SPAN = 16
 
 
 def delta_rule_scan(q, k, v, g, beta, chunk: int, span: int = SPAN):
-    """The gated delta rule over a sequence, chunk by chunk: per head, with
-    the state ``S [dk, dv]`` zero before the sequence, ``S' = exp(g_t)
+    """The gated delta rule over a sequence, chunk by chunk: per value head,
+    with the state ``S [dk, dv]`` zero before the sequence, ``S' = exp(g_t)
     S_{t-1}``, ``S_t = S' + k_t outer(beta_t (v_t - S'^T k_t))`` and ``o_t =
-    S_t^T q_t``. ``q, k [B, S, H, dk]`` and ``v [B, S, H, dv]`` (the
-    products' type), ``g, beta [B, S, H]`` float32 (``g <= 0``); returns
-    ``(o [B, S, H, dv]`` in the products' type, ``keep [B, chunks, H])``,
-    the second the share ``exp(sum over the chunk of g)`` of a chunk's
-    entering state that its
+    S_t^T q_t``. ``q, k [B, S, Hk, dk]`` (value head ``j`` reads key head ``j
+    // (H / Hk)``) and ``v [B, S, H, dv]`` (the products' type), ``g, beta
+    [B, S, H]`` float32 (``g <= 0``); returns ``(o [B, S, H, dv]`` in the
+    products' type, ``keep [B, chunks, H])``, the second the share
+    ``exp(sum over the chunk of g)`` of a chunk's entering state that its
     decay leaves (what the delta rule's own corrections take besides is not
-    in it).
+    in it), computed here from ``g``.
+
+    ``o`` is ``ops/delta_rule.delta_rule``: Pallas kernels on one TPU where
+    the widths are multiples of 128 and the chunk of 8, and elsewhere
+    :func:`_delta_rule_chunks`, differentiated by JAX."""
+    o = delta_rule.delta_rule(q, k, v, g, beta, _delta_rule_chunks, chunk,
+                              span)
+    bsz, s_len, heads = g.shape
+    nc = -(-s_len // chunk)
+    g = jnp.pad(g, [(0, 0), (0, nc * chunk - s_len), (0, 0)])
+    return o, jnp.exp(jnp.cumsum(jnp.swapaxes(
+        g.reshape(bsz, nc, chunk, heads), 2, 3), axis=-1)[..., -1])
+
+
+def _delta_rule_chunks(q, k, v, g, beta, chunk: int, span: int):
+    """XLA's spelling of :func:`delta_rule_scan`'s ``o``, with ``states [B,
+    H, steps, dk, dv]``: the float32 state entering every ``span``-th chunk.
 
     The same numbers as the token-by-token recurrence, in another order
     (the WY / UT form of Yang, Kautz & Hatamizadeh, arXiv:2412.06464):
@@ -1373,10 +1392,12 @@ def delta_rule_scan(q, k, v, g, beta, chunk: int, span: int = SPAN):
     float32; JAX differentiates all of it. A sequence that ``chunk * span``
     does not divide is padded with tokens of ``g`` 0 and ``beta`` 0, which
     neither decay nor write."""
-    bsz, s_len, heads, dk = q.shape
-    dv, dtype = v.shape[-1], v.dtype
+    bsz, s_len, heads, dv = v.shape
+    dk, dtype = q.shape[-1], v.dtype
     f32 = jnp.float32
-    span = max(1, min(span, -(-s_len // chunk)))
+    # value head j reads key head j // r
+    q, k = (jnp.repeat(t, heads // t.shape[2], axis=2) for t in (q, k))
+    span = delta_rule.steps_of(s_len, chunk, span)[0]
     pad = -s_len % (chunk * span)
     if pad:
         q, k, v, g, beta = (
@@ -1405,6 +1426,7 @@ def delta_rule_scan(q, k, v, g, beta, chunk: int, span: int = SPAN):
     def step(state, c):
         """``span`` chunks: ``[B, span, H, chunk, ...]`` each."""
         q, k, v, g, beta = c
+        entering_step = state
         log_decay = jnp.cumsum(g, axis=-1)                  # G_t
         # who sees whom inside a chunk, and how much of it is left
         left = jnp.exp(jnp.where(
@@ -1432,15 +1454,15 @@ def delta_rule_scan(q, k, v, g, beta, chunk: int, span: int = SPAN):
             preferred_element_type=f32) + jnp.einsum(
                 "zchts,zchsv->zchtv", scores.astype(dtype),
                 new.astype(dtype), preferred_element_type=f32)
-        return state, (o.astype(dtype), keep)
+        return state, (o.astype(dtype), entering_step)
 
-    _, (o, keep) = jax.lax.scan(step, jnp.zeros((bsz, heads, dk, dv), f32),
-                                tuple(chunked(t) for t in (q, k, v, g, beta)))
+    _, (o, states) = jax.lax.scan(
+        step, jnp.zeros((bsz, heads, dk, dv), f32),
+        tuple(chunked(t) for t in (q, k, v, g, beta)))
     # [steps, B, span, H, chunk, dv] -> [B, S, H, dv]
     o = jnp.swapaxes(jnp.moveaxis(o, 0, 1), 3, 4).reshape(
         bsz, nc * chunk, heads, dv)
-    keep = jnp.moveaxis(keep, 0, 1).reshape(bsz, nc, heads)
-    return o[:, :s_len], keep[:, :-(-s_len // chunk)]
+    return o[:, :s_len], jnp.moveaxis(states, 0, 2)
 
 
 def l2_norm(x, eps: float = 1e-6):
@@ -1518,8 +1540,8 @@ class GatedDeltaNet(nn.Module):
                 beta = jax.nn.sigmoid(b)
                 g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
                     a + dt_bias.astype(f32))
-                # value head j reads key head j // r
-                q, k = (jnp.repeat(t, r, axis=2).astype(x.dtype) for t in (
+                # at the key heads: value head j reads key head j // r
+                q, k = (t.astype(x.dtype) for t in (
                     l2_norm(q) / math.sqrt(dk), l2_norm(k)))
                 return q, k, g, beta
 

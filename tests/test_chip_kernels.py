@@ -1,6 +1,6 @@
 """The stem pool's two backward kernels, the attention product's three
-(ISSUE 34: under a selection; ISSUE 36: under the positions' rule) and the
-held experts' seven (ISSUE 38) compiled by the chip's own compiler
+(under a selection, and under the positions' rule), the held experts' seven
+and the gated delta rule's two compiled by the chip's own compiler
 at the benchmark's shapes, for a v5e that is described, not attached
 (``on-chip-measurement`` guide, section 2.3). Interpret mode passes what
 Mosaic refuses: a strided load of 16-bit data, a DMA slice of a memref whose
@@ -11,6 +11,7 @@ The topology is described inside a fixture of this one file and nowhere at
 import time: only one process may load the TPU's library.
 """
 import contextlib
+import functools
 import re
 
 import jax
@@ -21,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from benchmarks.lib import scopes
 from neuroimagedisttraining_tpu.models import decoder
 from neuroimagedisttraining_tpu.ops import (
+    delta_rule,
     grouped_mlp,
     masked_attention,
     pool_vjp,
@@ -225,6 +227,55 @@ def test_compiled_experts_kernels_stand_under_the_callers_scope(one_chip):
         ["bwd"] * 5 + ["fwd"] * 2)
     for name in names:
         assert scopes.under(name, "experts"), name
+        assert name.endswith("/pallas_call"), name
+
+
+# qwen3_next_80b_fed.longctx: 16,384 tokens, 16 key heads read by 32 value
+# heads, both 128 wide, chunks of 64, the state saved every 16th
+LINEAR = dict(qk=(1, 16384, 16, 128), v=(1, 16384, 32, 128),
+              gates=(1, 16384, 32, jnp.float32),
+              states=(1, 32, 16, 128, 128, jnp.float32))
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+def test_the_delta_rules_kernels_compile_for_the_chip(one_chip, backward):
+    """At the heads a grid step the shapes pick (VMEM: the backward keeps
+    16 chunks' entering states, inverses, ``w_v``, ``w_k`` and ``u`` of 8
+    heads, 22 MiB)."""
+    a = LINEAR
+    shapes = [a["qk"], a["qk"], a["v"], a["gates"], a["gates"]]
+    if backward:
+        fn = functools.partial(delta_rule._backward_pallas, chunk=64,
+                               span=16)
+        shapes += [a["states"], a["v"]]
+    else:
+        fn = functools.partial(delta_rule._forward_pallas, chunk=64, span=16)
+    text = compiled_text(fn, one_chip, *shapes)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+
+
+def test_compiled_delta_rule_kernels_stand_under_the_callers_scope(one_chip):
+    """``linear_scan_ms_per_round`` and ``linear_scan_roofline`` join a
+    kernel's time to ``linear_attention/scan`` by the ``op_name`` of the
+    COMPILED program, in both passes."""
+    def grads(q, k, v, g, beta):
+        def loss(q, k, v, g, beta):
+            with jax.named_scope("linear_attention"), \
+                    jax.named_scope("scan"):
+                o = decoder.delta_rule_scan(q, k, v, g, beta, 64)[0]
+            return o.astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=range(5))(q, k, v, g, beta)
+
+    text = compiled_text(grads, one_chip, (1, 1024, 2, 128),
+                         (1, 1024, 2, 128), (1, 1024, 4, 128),
+                         (1, 1024, 4, jnp.float32), (1, 1024, 4, jnp.float32))
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(scopes.direction(n) for n in names) == ["bwd", "fwd"]
+    for name in names:
+        assert scopes.under(name, "linear_attention/scan"), name
         assert name.endswith("/pallas_call"), name
 
 

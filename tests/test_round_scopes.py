@@ -370,32 +370,80 @@ LINEAR_SCOPES = ("linear_attention", "linear_attention/conv",
                  "linear_attention/scan", "linear_attention/norm")
 
 
-def test_folding_round_of_the_linear_attention_decoder_holds_its_scopes():
+@pytest.fixture
+def delta_rule_kernels_interpreted():
+    """ops/delta_rule.py's TPU lowering rule, its kernels interpreted, in
+    the CPU's place."""
+    import functools
+
+    from jax.interpreters import mlir
+
+    from neuroimagedisttraining_tpu.ops import delta_rule
+
+    primitives = ((delta_rule._forward_p, False),
+                  (delta_rule._backward_p, True))
+    rules = mlir._platform_specific_lowerings["cpu"]
+    for p, backward in primitives:
+        mlir.register_lowering(p, functools.partial(
+            delta_rule._lower, backward=backward, kernels=True,
+            interpret=True), platform="cpu")
+    try:
+        yield
+    finally:
+        for p, _ in primitives:
+            del rules[p]
+
+
+@pytest.mark.parametrize("spelling", ["xla", "kernel"])
+def test_folding_round_of_the_linear_attention_decoder_holds_its_scopes(
+        spelling, request):
     """The tiny decoder with Gated DeltaNet layers among a full one,
     compiled round: ``linear_attention`` around the whole operator with
     ``conv``, ``scan`` and ``norm`` inside it in both passes (the scopes the
     ``linear_*`` metric files ask for by name), the carry's loop over the
     chunks inside the scan's, the full layer's ``attention/full``, the
     router, the held experts and the shared expert beside them, and none of
-    the scopes of layers this model has none of."""
+    the scopes of layers this model has none of. The delta rule is
+    ops/delta_rule.py's two primitives: at the tiny widths XLA's scan; with
+    the linear heads 128 wide and chunks of 64 its kernels, here
+    interpreted (the TPU's rule in the CPU's place), whose jitted functions
+    stand under ``linear_attention/scan`` in both passes (the interpreter
+    inlines the ``pallas_call``: tests/test_chip_kernels.py reads it there
+    out of a compile for the chip), each pass counted in
+    ``linear_scan_lowerings``."""
     import json
     import os
 
     from neuroimagedisttraining_tpu.data.tokens import make_token_shards
     from neuroimagedisttraining_tpu.models import decoder
+    from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
 
     share = decoder.Share(4, 4, 2, 0, 4)
-    data = make_token_shards(0, n_clients=4, vocab=16, sequence_length=32,
+    cfg, seq = decoder.held_config("qwen3_next_tiny", share), 32
+    if spelling == "kernel":
+        request.getfixturevalue("delta_rule_kernels_interpreted")
+        cfg, seq = dict(cfg, linear_key_head_dim=128,
+                        linear_value_head_dim=128, linear_chunk_size=64), 128
+    data = make_token_shards(0, n_clients=4, vocab=16, sequence_length=seq,
                              train_per_client=1)
     hp = HyperParams(lr=0.05, local_epochs=1, steps_per_epoch=1,
                      batch_size=1)
-    algo = FedAvg(decoder.decoder("qwen3_next_tiny", share), data, hp,
+    algo = FedAvg(decoder.Decoder(decoder._freeze(cfg)), data, hp,
                   loss_type="token_ce", frac=0.5, seed=3, client_chunk=1,
                   track_personal=False)
     state = algo.init_state(jax.random.PRNGKey(3))
-    compiled = algo._round_jit.lower(
-        state, jnp.arange(2, dtype=jnp.int32), jnp.asarray(0, jnp.float32),
-        data.x_train, data.y_train, data.n_train).compile()
+    before = obs_metrics.set_registry(None)
+    try:
+        compiled = algo._round_jit.lower(
+            state, jnp.arange(2, dtype=jnp.int32),
+            jnp.asarray(0, jnp.float32), data.x_train, data.y_train,
+            data.n_train).compile()
+        counted = obs_metrics.get_registry().snapshot()[
+            "linear_scan_lowerings"]["labeled"]
+    finally:
+        obs_metrics.set_registry(before)
+    assert counted == {f"pass={p},spelling={spelling}": 1.0
+                       for p in ("forward", "backward")}
     names = op_names(compiled.as_text())
     for scope in ("local_train", "aggregate"):
         assert count(names, scope) > 0, scope
@@ -410,6 +458,12 @@ def test_folding_round_of_the_linear_attention_decoder_holds_its_scopes():
                                             "linear_attention/" + inner) > 0
     assert any("while" in n for n in names
                if scopes.under(n, "linear_attention/scan"))
+    for direction, kernel in (("fwd", "jit(delta_rule_forward)"),
+                              ("bwd", "jit(delta_rule_backward)")):
+        under = [n for n in names if scopes.under(n, "linear_attention/scan")
+                 and scopes.direction(n) == direction]
+        assert any(kernel in n.split("/") for n in under) == (
+            spelling == "kernel"), (direction, sorted(under)[:5])
     for scope in ("attention/window", "attention/indexer", "short_conv",
                   "ssm", "dense_mlp"):
         assert count(names, scope) == 0, scope
