@@ -137,6 +137,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import delta_rule, embedding, grouped_mlp
+from ..ops.index_select import index_select
 from ..ops.masked_attention import (
     OPERAND_NAMES,
     SAVED_NAMES,
@@ -913,10 +914,12 @@ def selected_attention(q, k, v, q_idx, w_idx, k_idx, topk: int,
     """Causal attention in which a query sees the ``topk`` keys its indexer
     scores highest (``q [B, S, n, g, d]`` over ``k, v [B, S, n, d]``; the
     indexer's ``q_idx [B, S, J, e]``, ``w_idx [B, S, J]``, ``k_idx [B, S,
-    e]``), softmax over those keys alone. The keys are chosen in blocks of
-    queries, each against the keys up to its own end; a block whose end is
-    within ``topk`` keeps every visible key and is not scored. The masked
-    product over the assembled selection is ``ops/masked_attention.py``'s:
+    e]``), softmax over those keys alone. The keys are chosen by
+    ``ops/index_select.py``: on one TPU one kernel whose scores stay in VMEM,
+    :func:`_selection_blocks` (blocks of ``block`` queries, each against
+    the keys up to its own end; a block whose end is within ``topk`` keeps
+    every visible key and is not scored) elsewhere. The masked product over
+    the selection ``[B, S, S]`` is ``ops/masked_attention.py``'s:
     on one TPU a kernel whose scores stay in VMEM, :func:`_attend` in the
     same blocks elsewhere; at a quarter of the causal square kept the
     product on the MXU costs a quarter of a gather of 2,048 keys a query
@@ -924,7 +927,19 @@ def selected_attention(q, k, v, q_idx, w_idx, k_idx, topk: int,
     log-sum-exp carry names under which the decoder's remat keeps them.
     Returns the output and, with ``want_kept``, the selection ``[B, S,
     S]``."""
-    b, s_len = q.shape[:2]
+    keep = index_select(q_idx, w_idx, k_idx, topk, _selection_blocks, block)
+    with jax.named_scope("selected"):
+        keep = checkpoint_name(keep, "selected_keys")
+        out = masked_attention(q, k, v, keep, _attend, "selected")
+    return out, keep != 0 if want_kept else None
+
+
+def _selection_blocks(q_idx, w_idx, k_idx, topk: int, block: int):
+    """XLA's spelling of ``ops/index_select.py``'s choice: the queries in
+    blocks, each scored by :func:`index_scores` against the keys up to its
+    own end and chosen by :func:`select_keys`, the blocks' masks padded and
+    joined to ``[B, S, S]`` int8."""
+    b, s_len = q_idx.shape[:2]
     kept = []
     for i in range(0, s_len, block):
         end = min(i + block, s_len)
@@ -939,12 +954,10 @@ def selected_attention(q, k, v, q_idx, w_idx, k_idx, topk: int,
                 keep = select_keys(scores, i, topk)
         kept.append(keep)
     with jax.named_scope("selected"):
-        keep = checkpoint_name(jnp.concatenate([
+        return jnp.concatenate([
             jnp.pad(jnp.broadcast_to(a, (b,) + a.shape[1:]).astype(jnp.int8),
                     [(0, 0), (0, 0), (0, s_len - a.shape[2])])
-            for a in kept], axis=1), "selected_keys")
-        out = masked_attention(q, k, v, keep, _attend, "selected")
-    return out, keep != 0 if want_kept else None
+            for a in kept], axis=1)
 
 
 def _weight(module, name, shape):

@@ -1,6 +1,7 @@
 """The stem pool's two backward kernels, the attention product's three
-(under a selection, and under the positions' rule), the held experts' seven
-and the gated delta rule's two compiled by the chip's own compiler
+(under a selection, and under the positions' rule), the held experts' seven,
+the gated delta rule's two and the indexer's choice of keys compiled by the
+chip's own compiler
 at the benchmark's shapes, for a v5e that is described, not attached
 (``on-chip-measurement`` guide, section 2.3). Interpret mode passes what
 Mosaic refuses: a strided load of 16-bit data, a DMA slice of a memref whose
@@ -24,6 +25,7 @@ from neuroimagedisttraining_tpu.models import decoder
 from neuroimagedisttraining_tpu.ops import (
     delta_rule,
     grouped_mlp,
+    index_select,
     masked_attention,
     pool_vjp,
 )
@@ -277,6 +279,46 @@ def test_compiled_delta_rule_kernels_stand_under_the_callers_scope(one_chip):
     for name in names:
         assert scopes.under(name, "linear_attention/scan"), name
         assert name.endswith("/pallas_call"), name
+
+
+# keye_vl2_fed.longctx's indexer: 16,384 tokens, 16 heads 64 wide, 2,048 keys
+INDEXER = dict(q=(1, 16384, 16, 64), w=(1, 16384, 16, jnp.float32),
+               k=(1, 16384, 64), topk=2048)
+
+
+def test_the_index_select_kernel_compiles_for_the_chip(one_chip):
+    """At the cell's shape and the kernel's tiles, within its VMEM limit
+    (the key order of a query tile, 16 MiB, and the keys' block)."""
+    a = INDEXER
+    assert index_select.kernel_takes(a["q"][1], a["q"][3])
+    text = compiled_text(
+        lambda q, w, k: index_select._select_pallas(q, w, k, topk=a["topk"]),
+        one_chip, a["q"], a["w"], a["k"])
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+
+
+def test_the_compiled_index_select_stands_under_the_select_scope(one_chip):
+    """``attention_select_ms_per_round`` joins the kernel's time to
+    ``attention/select`` by the ``op_name`` of the COMPILED program; the
+    op is forward only, and the selection it returns is all that leaves
+    it (no float32 score array under ``attention/indexer`` or
+    ``attention/select``)."""
+    a = INDEXER
+
+    def choose(q, w, k):
+        with jax.named_scope("attention"):
+            return index_select.index_select(
+                q, w, k, a["topk"], decoder._selection_blocks,
+                decoder.FULL_ATTENTION_BLOCK)
+
+    text = compiled_text(choose, one_chip, a["q"], a["w"], a["k"])
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(names) == 1
+    assert scopes.under(names[0], "attention/select"), names
+    assert names[0].endswith("/pallas_call"), names
+    assert not re.search(r"f32\[1,\d+,\d{4,}\]", text)
 
 
 def test_the_selecting_decoders_compiler_options_are_known_to_the_chip(
