@@ -5,12 +5,11 @@ exp(g_t) S_{t-1}``, ``S_t = S' + k_t outer(beta_t (v_t - S'^T k_t))``, ``o_t
 k, v, g, beta, body, chunk, span)`` is ``o``, a ``custom_vjp`` over a
 forward primitive (``o`` and the state entering every ``span``-th chunk,
 float32, the backward's residual) and a backward primitive (the gradients by
-``q, k, v, g, beta``). The lowering picks the spelling, where the program is
-lowered (as ``ops/masked_attention.py`` does it):
+``q, k, v, g, beta``). The lowering picks the spelling (``ops/lowering.py``
+has the rule):
 
-* Pallas kernels where the target is a TPU, no partitioner will touch the op
-  (one device, or the inside of a ``shard_map`` over every sharded axis),
-  ``dk`` and ``dv`` are multiples of 128 lanes and the chunk of 8 sublanes.
+* Pallas kernels on a TPU with no partitioner in the way, where ``dk``
+  and ``dv`` are multiples of 128 lanes and the chunk of 8 sublanes.
   The forward's grid is (batch, block of value heads, chunk), the chunks in
   order: the float32 state of each head of the block stays in VMEM scratch
   across them, and per chunk, in VMEM alone: ``G`` the chunk's cumulative
@@ -47,12 +46,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.interpreters import mlir
 
-from .masked_attention import _primitive
-from .pool_vjp import _unpartitioned, _vma
+from . import lowering
+from .lowering import LANES, NT, TN
 
-_LANES = 128
 _SUBLANES = 8
 _VMEM_LIMIT = 64 * 2 ** 20
 # value heads a grid step, at most: enough that the chunk's 64-row products
@@ -61,8 +58,6 @@ _VMEM_LIMIT = 64 * 2 ** 20
 _HEADS = 8
 
 _NN = (((1,), (0,)), ((), ()))      # a . b
-_NT = (((1,), (1,)), ((), ()))      # a . b^T
-_TN = (((0,), (0,)), ((), ()))      # a^T . b
 
 
 def steps_of(s_len: int, chunk: int, span: int):
@@ -86,7 +81,7 @@ def head_block(value_heads: int, ratio: int) -> int:
 
 def kernels_take(key_dim: int, value_dim: int, chunk: int) -> bool:
     """Whether these widths and this chunk can lower to the kernels."""
-    return (key_dim % _LANES == 0 and value_dim % _LANES == 0
+    return (key_dim % LANES == 0 and value_dim % LANES == 0
             and chunk % _SUBLANES == 0)
 
 
@@ -191,7 +186,7 @@ def _carry(state, k, g_row, g_col, w_v, w_k, dt, at):
     new = w_v - _mm(w_k.astype(dt), state.astype(dt))
     yield
     k_end = (k.astype(jnp.float32) * jnp.exp(g_last - g_col)).astype(dt)
-    return new, jnp.exp(g_last) * state + _mm(k_end, new.astype(dt), _TN)
+    return new, jnp.exp(g_last) * state + _mm(k_end, new.astype(dt), TN)
 
 
 def _forward_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, saved_ref,
@@ -218,7 +213,7 @@ def _forward_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, saved_ref,
     for kh in range(heads // ratio):
         q = q_ref[:, kh * dk:(kh + 1) * dk]
         k = k_ref[:, kh * dk:(kh + 1) * dk]
-        keys.append((q, k, _mm(k, k, _NT), _mm(q, k, _NT)))
+        keys.append((q, k, _mm(k, k, NT), _mm(q, k, NT)))
 
     def head(h):
         q, k, kk, qk = keys[h // ratio]
@@ -265,7 +260,7 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, saved_ref, do_ref,
         keys = []
         for kh in range(heads // ratio):
             k = k_ref[:, kh * dk:(kh + 1) * dk]
-            keys.append((k, _mm(k, k, _NT)))
+            keys.append((k, _mm(k, k, NT)))
 
         def head(h):
             k, kk = keys[h // ratio]
@@ -296,7 +291,7 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, saved_ref, do_ref,
         for kh in range(heads // ratio):
             q = q_ref[:, kh * dk:(kh + 1) * dk]
             k = k_ref[:, kh * dk:(kh + 1) * dk]
-            keys.append((q, k, _mm(k, k, _NT), _mm(q, k, _NT)))
+            keys.append((q, k, _mm(k, k, NT), _mm(q, k, NT)))
             dq.append(jnp.zeros(q.shape, f32))
             dks.append(jnp.zeros(k.shape, f32))
 
@@ -322,14 +317,14 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, saved_ref, do_ref,
             d_last = jnp.exp(g_last) * jnp.sum(
                 jnp.sum(state * d_out, axis=1, keepdims=True), axis=0,
                 keepdims=True)
-            d_end = _mm(new_t, d_out_t, _NT)
+            d_end = _mm(new_t, d_out_t, NT)
             d_new = _mm((k32 * to_end).astype(dt), d_out_t)
             # o = (e^G q) S + (q k^T * decay) u
             qe = q32 * e_g
-            d_qe = _mm(do, state_t, _NT)
-            d_state += _mm(qe.astype(dt), do, _TN)
-            d_scores = _mm(do, new_t, _NT)
-            d_new += _mm((qk * decay).astype(dt), do, _TN)
+            d_qe = _mm(do, state_t, NT)
+            d_state += _mm(qe.astype(dt), do, TN)
+            d_scores = _mm(do, new_t, NT)
+            d_new += _mm((qk * decay).astype(dt), do, TN)
             yield
             # k_end = e^{G_C - G} k
             dks[kh] += to_end * d_end
@@ -342,18 +337,18 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, saved_ref, do_ref,
             # u = w_v - w_k S
             d_new_t = d_new.astype(dt)
             dq[kh] += _mm(d_qk, k)
-            dks[kh] += _mm(d_qk, q, _TN)
-            d_wk = -_mm(d_new_t, state_t, _NT)
-            d_state -= _mm(w_k.astype(dt), d_new_t, _TN)
+            dks[kh] += _mm(d_qk, q, TN)
+            d_wk = -_mm(d_new_t, state_t, NT)
+            d_state -= _mm(w_k.astype(dt), d_new_t, TN)
             ds_scr[h] = d_state
-            d_rv = _mm(inverse, d_new, _TN, exact=True)
+            d_rv = _mm(inverse, d_new, TN, exact=True)
             yield
             # w = T r: by r, and by L (dT = -T dL T)
-            d_rk = _mm(inverse, d_wk, _TN, exact=True)
+            d_rk = _mm(inverse, d_wk, TN, exact=True)
             yield
             d_lower = jnp.where(
-                at.below, -(_mm(d_rv, w_v, _NT, exact=True)
-                            + _mm(d_rk, w_k, _NT, exact=True)), 0.0)
+                at.below, -(_mm(d_rv, w_v, NT, exact=True)
+                            + _mm(d_rk, w_k, NT, exact=True)), 0.0)
             # r_v = beta v, r_k = beta e^G k
             rk = e_g * jnp.sum(k32 * d_rk, axis=1, keepdims=True)
             db_col = jnp.sum(v.astype(f32) * d_rv, axis=1, keepdims=True) + rk
@@ -366,7 +361,7 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, saved_ref, do_ref,
             db_col += jnp.sum(d_lower * kk * decay, axis=1, keepdims=True)
             d_kk = (d_lower * b_col * decay).astype(dt)
             d_decay += d_lower * b_col * kk
-            dks[kh] += _mm(d_kk, k) + _mm(d_kk, k, _TN)
+            dks[kh] += _mm(d_kk, k) + _mm(d_kk, k, TN)
             # decay = exp(G_t - G_j), j <= t; G_C is G's last entry
             spread = d_decay * decay
             dg_col += jnp.sum(spread, axis=1, keepdims=True)
@@ -427,10 +422,7 @@ def _params(pltpu, last):
 
 
 def _forward_pallas(q, k, v, g, beta, *, chunk, span, interpret=False):
-    # imported here: a second and a half that only a process which lowers
-    # the kernels should pay (no CPU run does)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = lowering.pallas()
 
     b, s_len, nk, dk = q.shape
     nv, dv = v.shape[2:]
@@ -442,7 +434,7 @@ def _forward_pallas(q, k, v, g, beta, *, chunk, span, interpret=False):
     keys, values, rows, saved = _specs(
         pl, (q.shape, v.shape), chunk, heads, ratio,
         lambda b_, hb, c: (b_, c, hb, c // span))
-    vma = _vma(*map(jax.typeof, (q, k, v, g, beta)))
+    vma = lowering.vma(*map(jax.typeof, (q, k, v, g, beta)))
     o, states = pl.pallas_call(
         functools.partial(_forward_kernel, pl=pl, span=span, ratio=ratio),
         grid=(b, nv // heads, nc),
@@ -459,8 +451,7 @@ def _forward_pallas(q, k, v, g, beta, *, chunk, span, interpret=False):
 
 def _backward_pallas(q, k, v, g, beta, states, do, *, chunk, span,
                      interpret=False):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = lowering.pallas()
 
     b, s_len, nk, dk = q.shape
     nv, dv = v.shape[2:]
@@ -490,7 +481,7 @@ def _backward_pallas(q, k, v, g, beta, states, do, *, chunk, span,
                                        ratio, forward_at)
     keys_out, values_out, rows_out, _ = _specs(
         pl, (q.shape, v.shape), chunk, heads, ratio, backward_at)
-    vma = _vma(*map(jax.typeof, (q, k, v, g, beta, states, do)))
+    vma = lowering.vma(*map(jax.typeof, (q, k, v, g, beta, states, do)))
 
     def out(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
@@ -526,21 +517,6 @@ def _backward_pallas(q, k, v, g, beta, states, do, *, chunk, span,
             tokens(dbrows))
 
 
-@functools.lru_cache(maxsize=None)
-def _kernels_of(chunk, span, interpret=False):
-    """The two passes' kernels for one chunk and span, jitted: every layer
-    of a program that holds them at the same shapes traces their bodies
-    once."""
-    opts = dict(chunk=chunk, span=span, interpret=interpret)
-
-    def delta_rule_forward(*operands):
-        return _forward_pallas(*operands, **opts)
-
-    def delta_rule_backward(*operands):
-        return _backward_pallas(*operands, **opts)
-    return jax.jit(delta_rule_forward), jax.jit(delta_rule_backward)
-
-
 # ---------------------------------------------------------------------------
 # XLA's spelling: the caller's body, differentiated by JAX
 
@@ -561,7 +537,7 @@ def _backward_xla(q, k, v, g, beta, states, do, *, body, chunk, span):
 
 def _forward_avals(q, k, v, g, beta, *, chunk, span, **_):
     b, s_len, nv, dv = v.shape
-    vma = _vma(q, k, v, g, beta)
+    vma = lowering.vma(q, k, v, g, beta)
     steps = steps_of(s_len, chunk, span)[1]
     return (v.update(weak_type=False, vma=vma),
             v.update(shape=(b, nv, steps, q.shape[3], dv),
@@ -569,13 +545,13 @@ def _forward_avals(q, k, v, g, beta, *, chunk, span, **_):
 
 
 def _backward_avals(q, k, v, g, beta, states, do, **_):
-    vma = _vma(q, k, v, g, beta, states, do)
+    vma = lowering.vma(q, k, v, g, beta, states, do)
     return tuple(a.update(weak_type=False, vma=vma)
                  for a in (q, k, v, g, beta))
 
 
-_forward_p = _primitive("delta_rule", _forward_avals)
-_backward_p = _primitive("delta_rule_backward", _backward_avals)
+_forward_p = lowering.primitive("delta_rule", _forward_avals)
+_backward_p = lowering.primitive("delta_rule_backward", _backward_avals)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -604,28 +580,24 @@ def _bwd(body, chunk, span, saved, do):
 delta_rule.defvjp(_fwd, _bwd)
 
 
-def _lower(ctx, *args, body, chunk, span, backward, kernels,
-           interpret=False):
-    q, k, v = ctx.avals_in[:3]
-    kernel = (
-        kernels and _unpartitioned(ctx.module_context.axis_context)
-        and q.dtype == k.dtype == v.dtype and v.shape[2] % q.shape[2] == 0
-        and kernels_take(q.shape[3], v.shape[3], chunk))
-    # the choice is made once per lowering, so lowerings are what is counted
-    from ..obs.metrics import get_registry
-    get_registry().counter("linear_scan_lowerings").labels(
-        spelling="kernel" if kernel else "xla",
-        **{"pass": "backward" if backward else "forward"}).inc()
-    if kernel:
-        fn = _kernels_of(chunk, span, interpret)[backward]
-    else:
-        fn = functools.partial(_backward_xla if backward else _forward_xla,
-                               body=body, chunk=chunk, span=span)
-    return mlir.lower_fun(fn, multiple_results=True)(ctx, *args)
+def _takes(avals, *, chunk, **_):
+    q, k, v = avals[:3]
+    return (q.dtype == k.dtype == v.dtype and v.shape[2] % q.shape[2] == 0
+            and kernels_take(q.shape[3], v.shape[3], chunk))
 
 
-for _p, _backward in ((_forward_p, False), (_backward_p, True)):
-    mlir.register_lowering(_p, functools.partial(
-        _lower, backward=_backward, kernels=False))
-    mlir.register_lowering(_p, functools.partial(
-        _lower, backward=_backward, kernels=True), platform="tpu")
+def _kernel_spelling(*, interpret, chunk, span, backward, **_):
+    return lowering.jitted(
+        "delta_rule_backward" if backward else "delta_rule_forward",
+        _backward_pallas if backward else _forward_pallas, chunk=chunk,
+        span=span, interpret=interpret)
+
+
+def _xla_spelling(*, body, chunk, span, backward):
+    return functools.partial(_backward_xla if backward else _forward_xla,
+                             body=body, chunk=chunk, span=span)
+
+
+lowering.register(_forward_p, _backward_p, rule=_takes,
+                  kernel=_kernel_spelling, xla=_xla_spelling,
+                  counter="linear_scan_lowerings")

@@ -35,6 +35,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from . import lowering
+
 
 # the widest slab. 8192 bf16 rows into ``[32640, 5120]``, forward + backward
 # (my chip runs, PR 40): 4096 + 1024 columns 5.6 ms, five slabs of 1024 5.1,
@@ -62,9 +64,8 @@ def slabs_of(width: int):
 
 
 def _count(spelling: str, direction: str) -> None:
-    from ..obs.metrics import get_registry
-    get_registry().counter("embed_lowerings").labels(
-        spelling=spelling, **{"pass": direction}).inc()
+    lowering.count("embed_lowerings", spelling=spelling,
+                   **{"pass": direction})
 
 
 def _take(table, ids):

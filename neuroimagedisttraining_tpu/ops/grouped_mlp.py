@@ -15,11 +15,10 @@ On that layout ``forward(xs, rw, w, tile_expert, tm)`` is, row by row,
 ``rw[r] * (silu(xs[r] @ gate[e]) * (xs[r] @ up[e])) @ down[e]`` with ``e`` the
 row's expert, and ``backward(xs, rw, w, tile_expert, gy, tm)`` the gradients
 of ``<gy, forward>`` by ``xs``, ``rw`` and the three weight stacks. Each is one
-primitive with two lowerings, chosen where the program is lowered (as
-``ops/masked_attention.py`` does it):
+primitive with two lowerings (``ops/lowering.py`` has the rule):
 
-* Pallas kernels where the target is a TPU, no partitioner will touch the op,
-  and ``hidden``, ``width`` and ``tm`` are multiples of 128 lanes. Every
+* Pallas kernels on a TPU with no partitioner in the way, where
+  ``hidden``, ``width`` and ``tm`` are multiples of 128 lanes. Every
   kernel is a dense tiled product whose weight block a scalar-prefetched
   ``tile_expert`` chooses; consecutive tiles of one expert ask for the same
   block, which is then not fetched again. Forward: ``gate | up`` as ONE kernel
@@ -49,12 +48,10 @@ import types
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.interpreters import mlir
 
-from .masked_attention import _primitive
-from .pool_vjp import _unpartitioned, _vma
+from . import lowering
+from .lowering import LANES, NT, TN, wide
 
-_LANES = 128
 _VMEM_LIMIT = 96 * 2 ** 20
 # what a kernel's weight blocks may take of VMEM, both buffers: decides the
 # column tile (:func:`_col_tile`)
@@ -65,9 +62,6 @@ _ACC_VMEM = 8 * 2 ** 20
 PRODUCTS = {"forward": ("gate_up", "down"),
             "backward": ("gate_up_again", "d_rows", "d_gate", "d_up",
                          "d_down")}
-
-_NT = (((1,), (1,)), ((), ()))      # a . b^T
-_TN = (((0,), (0,)), ((), ()))      # a^T . b
 
 
 # the rows of a tile. One layer of ``lfm2_8b_a1b_fed.longctx``'s chunk (65,536
@@ -174,11 +168,6 @@ def _backward_xla(xs, rw, wg, wu, wd, tile_expert, gy, *, tm):
 # [.., tn] is fetched once an expert a column tile, the rows' tiles stream.
 
 
-def _wide(rw, width):
-    """A row's weight, kept on all 128 lanes, as wide as a tile."""
-    return jnp.tile(rw, (1, width // _LANES))
-
-
 def _gate_up_kernel(te_ref, x_ref, wg_ref, wu_ref, a_ref):
     x = x_ref[...]
     gate = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
@@ -188,7 +177,7 @@ def _gate_up_kernel(te_ref, x_ref, wg_ref, wu_ref, a_ref):
 
 def _down_kernel(te_ref, a_ref, wd_ref, rw_ref, y_ref):
     y = jnp.dot(a_ref[...], wd_ref[...], preferred_element_type=jnp.float32)
-    y_ref[...] = (y * _wide(rw_ref[...], y.shape[1])).astype(y_ref.dtype)
+    y_ref[...] = (y * wide(rw_ref[...], y.shape[1])).astype(y_ref.dtype)
 
 
 def _gate_up_again_kernel(te_ref, x_ref, gy_ref, wg_ref, wu_ref, wd_ref,
@@ -199,16 +188,16 @@ def _gate_up_again_kernel(te_ref, x_ref, gy_ref, wg_ref, wu_ref, wd_ref,
     x = x_ref[...]
     gate = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
     up = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
-    u = lax.dot_general(gy_ref[...], wd_ref[...], _NT,
+    u = lax.dot_general(gy_ref[...], wd_ref[...], NT,
                         preferred_element_type=jnp.float32)
     sig = jax.nn.sigmoid(gate)
     silu = gate * sig
     # as the forward rounded it
     a = (silu * up).astype(aw_ref.dtype).astype(jnp.float32)
     ua = u * a
-    drw_ref[...] = sum(ua[:, i:i + _LANES]
-                       for i in range(0, ua.shape[1], _LANES))
-    rw = _wide(rw_ref[...], u.shape[1])
+    drw_ref[...] = sum(ua[:, i:i + LANES]
+                       for i in range(0, ua.shape[1], LANES))
+    rw = wide(rw_ref[...], u.shape[1])
     aw_ref[...] = (a * rw).astype(aw_ref.dtype)
     da = u * rw
     dg_ref[...] = (da * up * (sig + silu * (1.0 - sig))).astype(dg_ref.dtype)
@@ -216,9 +205,9 @@ def _gate_up_again_kernel(te_ref, x_ref, gy_ref, wg_ref, wu_ref, wd_ref,
 
 
 def _d_rows_kernel(te_ref, dg_ref, du_ref, wg_ref, wu_ref, dx_ref):
-    dx = lax.dot_general(dg_ref[...], wg_ref[...], _NT,
+    dx = lax.dot_general(dg_ref[...], wg_ref[...], NT,
                          preferred_element_type=jnp.float32)
-    dx += lax.dot_general(du_ref[...], wu_ref[...], _NT,
+    dx += lax.dot_general(du_ref[...], wu_ref[...], NT,
                           preferred_element_type=jnp.float32)
     dx_ref[...] = dx.astype(dx_ref.dtype)
 
@@ -236,7 +225,7 @@ def _d_weight_kernel(te_ref, x_ref, d_ref, dw_ref, acc_ref, *, pl):
     def _():
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    acc_ref[...] += lax.dot_general(x_ref[...], d_ref[...], _TN,
+    acc_ref[...] += lax.dot_general(x_ref[...], d_ref[...], TN,
                                     preferred_element_type=jnp.float32)
 
     @pl.when(jnp.logical_or(t == tiles - 1,
@@ -248,22 +237,19 @@ def _d_weight_kernel(te_ref, x_ref, d_ref, dw_ref, acc_ref, *, pl):
 def _col_tile(n: int, bytes_a_column: int, budget: int) -> int:
     """The widest tile of ``n`` columns, a multiple of 128 lanes that divides
     ``n``, whose ``bytes_a_column`` a column stay inside ``budget``."""
-    fits = [c for c in range(_LANES, n + 1, _LANES)
+    fits = [c for c in range(LANES, n + 1, LANES)
             if n % c == 0 and c * bytes_a_column <= budget]
-    return max(fits, default=_LANES)
+    return max(fits, default=LANES)
 
 
 def _pallas(tm, interpret=False):
     """The five kernels at row tile ``tm``, one function a product, and the
     two passes made of them."""
-    # imported here: a second and a half that only a process which lowers
-    # the kernels should pay (no CPU run does)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = lowering.pallas()
 
     def call(kernel, tile_expert, operands, in_specs, out_specs, out_shape,
              grid, scratch=(), reduces=False):
-        vma = _vma(*map(jax.typeof, operands))
+        vma = lowering.vma(*map(jax.typeof, operands))
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -290,7 +276,7 @@ def _pallas(tm, interpret=False):
         return pl.BlockSpec((None, tn, k), lambda j, t, te: (te[t], j, 0))
 
     def lanes(rw):
-        return jnp.broadcast_to(rw[:, None], (rw.shape[0], _LANES))
+        return jnp.broadcast_to(rw[:, None], (rw.shape[0], LANES))
 
     def gate_up(xs, wg, wu, tile_expert):
         (total, hidden), width = xs.shape, wg.shape[2]
@@ -305,7 +291,7 @@ def _pallas(tm, interpret=False):
         (total, width), hidden = a.shape, wd.shape[2]
         tn = _col_tile(hidden, 2 * width * a.dtype.itemsize, _WEIGHT_VMEM)
         return call(_down_kernel, tile_expert, (a, wd, lanes(rw)),
-                    [rows(width), block(width, tn), rows(_LANES)],
+                    [rows(width), block(width, tn), rows(LANES)],
                     [rows_at(tn)], [((total, hidden), a.dtype)],
                     (hidden // tn, total // tm))[0]
 
@@ -317,11 +303,11 @@ def _pallas(tm, interpret=False):
             _gate_up_again_kernel, tile_expert,
             (xs, gy, wg, wu, wd, lanes(rw)),
             [rows(hidden), rows(hidden), block(hidden, tn), block(hidden, tn),
-             block_t(tn, hidden), rows(_LANES)],
+             block_t(tn, hidden), rows(LANES)],
             [rows_at(tn)] * 3 + [pl.BlockSpec(
-                (None, tm, _LANES), lambda j, t, te: (j, t, 0))],
+                (None, tm, LANES), lambda j, t, te: (j, t, 0))],
             [((total, width), xs.dtype)] * 3
-            + [((width // tn, total, _LANES), jnp.float32)],
+            + [((width // tn, total, LANES), jnp.float32)],
             (width // tn, total // tm))
         return aw, dg, du, jnp.sum(drw, axis=(0, 2)).astype(rw.dtype)
 
@@ -362,18 +348,9 @@ def _pallas(tm, interpret=False):
         d_rows=d_rows, d_weight=d_weight, forward=forward, backward=backward)
 
 
-@functools.lru_cache(maxsize=None)
-def _kernels_of(tm, interpret=False):
-    """The two passes on the kernels at one row tile, jitted: every layer of
-    a program that holds them at the same shapes traces their bodies once."""
+def _pass(*operands, tm, backward, interpret):
     kernels = _pallas(tm, interpret)
-
-    def experts_forward(*operands):
-        return kernels.forward(*operands)
-
-    def experts_backward(*operands):
-        return kernels.backward(*operands)
-    return jax.jit(experts_forward), jax.jit(experts_backward)
+    return (kernels.backward if backward else kernels.forward)(*operands)
 
 
 # ---------------------------------------------------------------------------
@@ -382,17 +359,17 @@ def _kernels_of(tm, interpret=False):
 
 def _forward_avals(xs, rw, wg, wu, wd, tile_expert, **_):
     return (xs.update(weak_type=False,
-                      vma=_vma(xs, rw, wg, wu, wd, tile_expert)),)
+                      vma=lowering.vma(xs, rw, wg, wu, wd, tile_expert)),)
 
 
 def _backward_avals(xs, rw, wg, wu, wd, tile_expert, gy, **_):
-    vma = _vma(xs, rw, wg, wu, wd, tile_expert, gy)
+    vma = lowering.vma(xs, rw, wg, wu, wd, tile_expert, gy)
     return tuple(a.update(weak_type=False, vma=vma)
                  for a in (xs, rw, wg, wu, wd))
 
 
-_forward_p = _primitive("grouped_swiglu", _forward_avals)
-_backward_p = _primitive("grouped_swiglu_backward", _backward_avals)
+_forward_p = lowering.primitive("grouped_swiglu", _forward_avals)
+_backward_p = lowering.primitive("grouped_swiglu_backward", _backward_avals)
 
 
 def forward(xs, rw, w, tile_expert, tm: int):
@@ -414,32 +391,31 @@ def backward(xs, rw, w, tile_expert, gy, tm: int):
 
 def kernels_take(hidden: int, width: int, tm: int) -> bool:
     """Whether these widths and this row tile can lower to the kernels."""
-    return all(n % _LANES == 0 for n in (hidden, width, tm))
+    return all(n % LANES == 0 for n in (hidden, width, tm))
 
 
-def _lower(ctx, *args, tm, backward, kernels, interpret=False):
-    xs, _, wg, wu, wd = ctx.avals_in[:5]
-    kernel = (
-        kernels and _unpartitioned(ctx.module_context.axis_context)
-        and xs.dtype == wg.dtype == wu.dtype == wd.dtype
-        and kernels_take(xs.shape[1], wg.shape[2], tm))
-    # the choice is made once per lowering, so lowerings are what is counted
-    from ..obs.metrics import get_registry
-    name = "backward" if backward else "forward"
-    for product in PRODUCTS[name]:
-        get_registry().counter("expert_lowerings").labels(
-            spelling="kernel" if kernel else "xla", product=product,
-            **{"pass": name}).inc()
-    if kernel:
-        fn = _kernels_of(tm, interpret)[backward]
-    else:
-        fn = functools.partial(_backward_xla if backward else _forward_xla,
-                               tm=tm)
-    return mlir.lower_fun(fn, multiple_results=True)(ctx, *args)
+def _takes(avals, *, tm, **_):
+    xs, _, wg, wu, wd = avals[:5]
+    return (xs.dtype == wg.dtype == wu.dtype == wd.dtype
+            and kernels_take(xs.shape[1], wg.shape[2], tm))
 
 
-for _p, _backward in ((_forward_p, False), (_backward_p, True)):
-    mlir.register_lowering(_p, functools.partial(
-        _lower, backward=_backward, kernels=False))
-    mlir.register_lowering(_p, functools.partial(
-        _lower, backward=_backward, kernels=True), platform="tpu")
+def _kernel_spelling(*, interpret, tm, backward):
+    return lowering.jitted(
+        "experts_backward" if backward else "experts_forward", _pass, tm=tm,
+        backward=backward, interpret=interpret)
+
+
+def _xla_spelling(*, tm, backward):
+    return functools.partial(_backward_xla if backward else _forward_xla,
+                             tm=tm)
+
+
+def _labels(*, backward, **_):
+    return [{"product": product}
+            for product in PRODUCTS["backward" if backward else "forward"]]
+
+
+lowering.register(_forward_p, _backward_p, rule=_takes,
+                  kernel=_kernel_spelling, xla=_xla_spelling,
+                  counter="expert_lowerings", labels=_labels)

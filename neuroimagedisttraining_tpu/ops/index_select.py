@@ -6,12 +6,10 @@ the lower ``k``. The scores are ``sum_j w[j] * relu(q[j] . k) / sqrt(J *
 e)`` in float32 for the indexer's ``q_idx [B, S, J, e]``, ``w_idx [B, S,
 J]`` (float32) and ``k_idx [B, S, e]``, the heads added in order. The op
 is forward only: nothing differentiates the indexer's choice. The lowering
-picks the spelling, where the program is lowered (as
-``ops/masked_attention.py`` does it):
+picks the spelling (``ops/lowering.py`` has the rule):
 
-* a Pallas kernel where the target is a TPU, no partitioner will touch the
-  op (one device, or the inside of a ``shard_map`` over every sharded
-  axis), the indexer's width is a multiple of 64 and the sequence divides
+* a Pallas kernel on a TPU with no partitioner in the way, where the
+  indexer's width is a multiple of 64 and the sequence divides
   into the mask's column blocks (:func:`kernel_takes`, by shape alone). Its
   grid is (batch, query tile, column block of the mask). At a tile's first
   column block the float32 scores against every key up to the tile's last
@@ -43,10 +41,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.interpreters import mlir
 
-from .masked_attention import _LANES, _as_col, _primitive, _wide
-from .pool_vjp import _unpartitioned, _vma
+from . import lowering
+from .lowering import LANES, NT, as_col, wide
 
 # queries a grid step: their rows of the key order live in VMEM scratch,
 # ``_TQ x S`` int32 (16 MiB at 16,384 tokens)
@@ -65,14 +62,12 @@ _SCRATCH_BUDGET = 64 * 2 ** 20
 _SIGN = -2 ** 31        # int32 0x80000000: the key the causal mask hides
 _WIDTH = 64             # the indexer's width must be a multiple of this
 
-_NT = (((1,), (1,)), ((), ()))      # a . b^T
-
 
 def kernel_takes(s_len: int, width: int) -> bool:
     """Whether an indexer of this width over this many tokens can lower to
     the kernel at all (shape alone; the target and the partitioner are the
     lowering's to judge)."""
-    scratch = s_len * (_TQ * 4 + 2 * 2 * _LANES)
+    scratch = s_len * (_TQ * 4 + 2 * 2 * LANES)
     return (width % _WIDTH == 0 and s_len % _TO == 0
             and scratch <= _SCRATCH_BUDGET)
 
@@ -99,16 +94,16 @@ def _score(pl, q_ref, w_ref, k_ref, keys_scr, w_scr, *, first, chunks, scale):
     e = q_ref.shape[1] // heads
     tc = keys_scr.shape[2]
     for j in range(heads):
-        w_scr[j] = _as_col(w_ref[j], tq)
+        w_scr[j] = as_col(w_ref[j], tq)
     row = first + lax.broadcasted_iota(jnp.int32, (tq, tc), 0)
 
     def chunk(c, carry):
         k = k_ref[_at(pl, c * tc, tc), :]
         total = 0.0
         for j in range(heads):
-            dot = lax.dot_general(q_ref[:, j * e:(j + 1) * e], k, _NT,
+            dot = lax.dot_general(q_ref[:, j * e:(j + 1) * e], k, NT,
                                   preferred_element_type=jnp.float32)
-            total = total + _wide(w_scr[j], tc) * jax.nn.relu(dot)
+            total = total + wide(w_scr[j], tc) * jax.nn.relu(dot)
         col = c * tc + lax.broadcasted_iota(jnp.int32, (tq, tc), 1)
         keys_scr[c] = jnp.where(col <= row, ordered(total / scale), _SIGN)
         return carry
@@ -121,19 +116,19 @@ def _counts(pl, keys_scr, r0, chunks, preds):
     many keys of the first ``chunks`` chunks meet each ``pred(keys,
     cols)`` (both ``[_ROWS, 128]``)."""
     tc = keys_scr.shape[2]
-    lane = lax.broadcasted_iota(jnp.int32, (_ROWS, _LANES), 1)
+    lane = lax.broadcasted_iota(jnp.int32, (_ROWS, LANES), 1)
 
     def chunk(c, accs):
         accs = list(accs)
-        for lane_tile in range(tc // _LANES):
-            at = lane_tile * _LANES
-            keys = keys_scr[c, _at(pl, r0, _ROWS), at:at + _LANES]
+        for lane_tile in range(tc // LANES):
+            at = lane_tile * LANES
+            keys = keys_scr[c, _at(pl, r0, _ROWS), at:at + LANES]
             cols = c * tc + at + lane
             for n, pred in enumerate(preds):
                 accs[n] = accs[n] + jnp.where(pred(keys, cols), 1.0, 0.0)
         return tuple(accs)
 
-    zero = jnp.zeros((_ROWS, _LANES), jnp.float32)
+    zero = jnp.zeros((_ROWS, LANES), jnp.float32)
     accs = lax.fori_loop(0, chunks, chunk, (zero,) * len(preds))
     return [jnp.sum(a, axis=1, keepdims=True) for a in accs]
 
@@ -160,7 +155,7 @@ def _choose(pl, keys_scr, kth_scr, last_scr, *, chunks, topk, s_len):
 
         kth, _ = lax.fori_loop(
             0, 32, settle,
-            (jnp.zeros((_ROWS, _LANES), jnp.int32), jnp.int32(_SIGN)))
+            (jnp.zeros((_ROWS, LANES), jnp.int32), jnp.int32(_SIGN)))
         kth = kth ^ _SIGN
         above, equal = _counts(pl, keys_scr, r0, chunks,
                                [lambda keys, _: keys > kth,
@@ -170,7 +165,7 @@ def _choose(pl, keys_scr, kth_scr, last_scr, *, chunks, topk, s_len):
         # ones equal it
         crowded = jnp.logical_and(equal > wanted, kth != _SIGN)
         kth_scr[_at(pl, r0, _ROWS)] = kth
-        last_scr[_at(pl, r0, _ROWS)] = jnp.full((_ROWS, _LANES), s_len,
+        last_scr[_at(pl, r0, _ROWS)] = jnp.full((_ROWS, LANES), s_len,
                                               jnp.int32)
 
         @pl.when(jnp.max(jnp.where(crowded, 1, 0)) > 0)
@@ -188,7 +183,7 @@ def _choose(pl, keys_scr, kth_scr, last_scr, *, chunks, topk, s_len):
 
             cut, _ = lax.fori_loop(
                 0, nbits, narrow,
-                (jnp.zeros((_ROWS, _LANES), jnp.int32),
+                (jnp.zeros((_ROWS, LANES), jnp.int32),
                  jnp.int32(1 << (nbits - 1))))
             last_scr[_at(pl, r0, _ROWS)] = jnp.where(crowded, cut, s_len)
         return carry
@@ -233,8 +228,8 @@ def _kernel(q_ref, w_ref, k_ref, out_ref, keys_scr, w_scr, kth_scr,
             g = c * (to // tc) + m
             for r0 in range(0, tq, _ROWS):
                 keys = keys_scr[g, r0:r0 + _ROWS, :]
-                kth = _wide(kth_scr[r0:r0 + _ROWS], tc)
-                last = _wide(last_scr[r0:r0 + _ROWS], tc)
+                kth = wide(kth_scr[r0:r0 + _ROWS], tc)
+                last = wide(last_scr[r0:r0 + _ROWS], tc)
                 col = g * tc + lax.broadcasted_iota(jnp.int32, (_ROWS, tc), 1)
                 row = first + r0 + lax.broadcasted_iota(
                     jnp.int32, (_ROWS, tc), 0)
@@ -250,15 +245,12 @@ def _kernel(q_ref, w_ref, k_ref, out_ref, keys_scr, w_scr, kth_scr,
 
 
 def _select_pallas(q_idx, w_idx, k_idx, *, topk, interpret=False):
-    # imported here: a second and a half that only a process which lowers
-    # the kernel should pay (no CPU run does)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = lowering.pallas()
 
     b, s_len, heads, e = q_idx.shape
     q = q_idx.reshape(b, s_len, heads * e)          # the heads side by side
     w = w_idx.transpose(0, 2, 1)[:, :, None]        # [B, J, 1, S]
-    vma = _vma(*map(jax.typeof, (q_idx, w_idx, k_idx)))
+    vma = lowering.vma(*map(jax.typeof, (q_idx, w_idx, k_idx)))
     return pl.pallas_call(
         functools.partial(_kernel, pl=pl, topk=topk,
                           scale=math.sqrt(heads * e)),
@@ -272,23 +264,13 @@ def _select_pallas(q_idx, w_idx, k_idx, *, topk, interpret=False):
         out_shape=jax.ShapeDtypeStruct((b, s_len, s_len), jnp.int8, vma=vma),
         scratch_shapes=[
             pltpu.VMEM((s_len // _TC, _TQ, _TC), jnp.int32),
-            pltpu.VMEM((heads, _TQ, _LANES), jnp.float32),
-            pltpu.VMEM((_TQ, _LANES), jnp.int32),
-            pltpu.VMEM((_TQ, _LANES), jnp.int32)],
+            pltpu.VMEM((heads, _TQ, LANES), jnp.float32),
+            pltpu.VMEM((_TQ, LANES), jnp.int32),
+            pltpu.VMEM((_TQ, LANES), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret)(q, w, k_idx)
-
-
-@functools.lru_cache(maxsize=None)
-def _kernel_of(topk, interpret=False):
-    """The kernel for one ``topk``, jitted: every layer of a program that
-    holds it at the same shapes traces its body once."""
-    def index_select(q_idx, w_idx, k_idx):
-        return _select_pallas(q_idx, w_idx, k_idx, topk=topk,
-                              interpret=interpret)
-    return jax.jit(index_select)
 
 
 # ---------------------------------------------------------------------------
@@ -298,10 +280,11 @@ def _kernel_of(topk, interpret=False):
 def _avals(q_idx, w_idx, k_idx, **_):
     b, s_len = q_idx.shape[:2]
     return (q_idx.update(shape=(b, s_len, s_len), dtype=jnp.dtype(jnp.int8),
-                         weak_type=False, vma=_vma(q_idx, w_idx, k_idx)),)
+                         weak_type=False,
+                         vma=lowering.vma(q_idx, w_idx, k_idx)),)
 
 
-_select_p = _primitive("index_select", _avals)
+_select_p = lowering.primitive("index_select", _avals)
 
 
 def index_select(q_idx, w_idx, k_idx, topk: int, blocks, block: int):
@@ -311,24 +294,25 @@ def index_select(q_idx, w_idx, k_idx, topk: int, blocks, block: int):
                           block=block)[0]
 
 
-def _lower(ctx, *args, topk, blocks, block, kernels, interpret=False):
-    q_idx = ctx.avals_in[0]
-    kernel = (kernels and _unpartitioned(ctx.module_context.axis_context)
-              and kernel_takes(q_idx.shape[1], q_idx.shape[3]))
-    # the choice is made once per lowering, so lowerings are what is counted
-    from ..obs.metrics import get_registry
-    get_registry().counter("selection_lowerings").labels(
-        spelling="kernel" if kernel else "blocks").inc()
-    if kernel:
-        def fn(*operands):
-            with jax.named_scope("select"):
-                return (_kernel_of(topk, interpret)(*operands),)
-    else:
-        def fn(*operands):
-            return (blocks(*operands, topk, block),)
-    return mlir.lower_fun(fn, multiple_results=True)(ctx, *args)
+def _takes(avals, **_):
+    q_idx = avals[0]
+    return kernel_takes(q_idx.shape[1], q_idx.shape[3])
 
 
-mlir.register_lowering(_select_p, functools.partial(_lower, kernels=False))
-mlir.register_lowering(_select_p, functools.partial(_lower, kernels=True),
-                       platform="tpu")
+def _kernel_spelling(*, interpret, topk, **_):
+    def fn(*operands):
+        with jax.named_scope("select"):
+            return (lowering.jitted("index_select", _select_pallas, topk=topk,
+                                    interpret=interpret)(*operands),)
+    return fn
+
+
+def _xla_spelling(*, topk, blocks, block):
+    def fn(*operands):
+        return (blocks(*operands, topk, block),)
+    return fn
+
+
+lowering.register(_select_p, rule=_takes, kernel=_kernel_spelling,
+                  xla=_xla_spelling, counter="selection_lowerings",
+                  xla_spelling="blocks")

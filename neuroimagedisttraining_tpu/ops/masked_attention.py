@@ -13,13 +13,11 @@ for the second product. The mask comes one of two ways:
   window)``, attention where ``0 <= q - k < window`` (``window`` 0: ``k <=
   q`` alone). There is no mask array at all.
 
-The forward and the backward are one primitive each with two lowerings,
-chosen where the program is lowered, by what the compiler can run there (as
-``ops/pool_vjp.py`` does it):
+The forward and the backward are one primitive each with two lowerings
+(``ops/lowering.py`` has the rule):
 
-* Pallas kernels where the target is a TPU, no partitioner will touch the op
-  (one device, or the inside of a ``shard_map`` over every sharded axis),
-  the head width is a multiple of 128 lanes and the sequence divides into
+* Pallas kernels on a TPU with no partitioner in the way, where the head
+  width is a multiple of 128 lanes and the sequence divides into
   the kernels' tiles (:func:`tiles_of`, by the mask's shape). The scores and
   probabilities of a (query tile, key tile) pair live in VMEM and never
   reach HBM: the forward keeps a running maximum and sum per row and a
@@ -51,12 +49,10 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax._src import dispatch
 from jax.ad_checkpoint import checkpoint_name
-from jax.extend.core import Primitive
-from jax.interpreters import mlir
 
-from .pool_vjp import _unpartitioned, _vma
+from . import lowering
+from .lowering import LANES, NT, as_col, wide
 
 # queries a block of XLA's spelling (models/decoder.FULL_ATTENTION_BLOCK)
 BLOCK = 1024
@@ -72,7 +68,6 @@ _MASKED = -1e30
 # key tile which gives a row nothing adds exp(-1e30 - floor) = 0 to it and
 # not exp(-1e30 - -1e30) = 1; under every real score
 _FLOOR = -0.5e30
-_LANES = 128
 # (query tile, key tile) of the kernels, multiples of 128 lanes (my chip
 # runs, PR 34, a layer forward + backward: 256 x 512 4.14 + 12.10 ms, 512 x
 # 512 3.87 + 11.49, 512 x 1024 3.94 + 11.61, 128 x 512 5.99 + 13.32)
@@ -86,8 +81,6 @@ _TILES = (512, 512)
 # 0.836 + 2.391, 256 x 512 0.836 + 2.468, 256 x 256 1.085 + 2.750)
 _BAND_TILES = (256, 256)
 _VMEM_LIMIT = 64 * 2 ** 20
-
-_NT = (((1,), (1,)), ((), ()))      # a . b^T
 
 
 def _bias(keep_ref):
@@ -147,16 +140,6 @@ def _on_tile(pl, keep_ref, live, i, j, tiles, window, tile, transposed=False):
                        _MASKED))
 
 
-def _wide(col, width):
-    """A per-row statistic kept on all 128 lanes, as wide as a tile."""
-    return jnp.tile(col, (1, width // _LANES))
-
-
-def _as_col(row, rows):
-    """``row [1, rows]`` as ``[rows, 128]``, the value on every lane."""
-    return jnp.broadcast_to(row, (_LANES, rows)).T
-
-
 def _forward_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref, m_scr,
                     l_scr, acc_scr, *, pl, tiles, scale, window):
     """Grid (batch, KV head, query tile ``i``, step ``j`` over the key tiles
@@ -180,17 +163,17 @@ def _forward_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref, m_scr,
     def tile(bias):
         k, v = k_ref[...], v_ref[...]
         for h in range(heads):
-            s = lax.dot_general(q_ref[h], k, _NT,
+            s = lax.dot_general(q_ref[h], k, NT,
                                 preferred_element_type=jnp.float32) * scale
             if bias is not None:
                 s = s + bias
             m_prev = m_scr[h]
             m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - _wide(m_next, tk))
+            p = jnp.exp(s - wide(m_next, tk))
             alpha = jnp.exp(m_prev - m_next)
             l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=1, keepdims=True)
             m_scr[h] = m_next
-            acc_scr[h] = acc_scr[h] * _wide(alpha, d) + jnp.dot(
+            acc_scr[h] = acc_scr[h] * wide(alpha, d) + jnp.dot(
                 p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
     # up to the tile that holds the diagonal
@@ -202,7 +185,7 @@ def _forward_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref, m_scr,
         for h in range(heads):
             # every row keeps a key; one that kept none reads 0, not NaN
             total = jnp.where(l_scr[h] == 0.0, 1.0, l_scr[h])
-            o_ref[h] = (acc_scr[h] / _wide(total, d)).astype(o_ref.dtype)
+            o_ref[h] = (acc_scr[h] / wide(total, d)).astype(o_ref.dtype)
             lse_ref[h] = (m_scr[h] + jnp.log(total)).T[:1]
 
 
@@ -219,20 +202,20 @@ def _dq_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref, di_ref,
     def _():
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
         for h in range(heads):
-            lse_scr[h] = _as_col(lse_ref[h], tq)
-            di_scr[h] = _as_col(di_ref[h], tq)
+            lse_scr[h] = as_col(lse_ref[h], tq)
+            di_scr[h] = as_col(di_ref[h], tq)
 
     def tile(bias):
         k, v = k_ref[...], v_ref[...]
         for h in range(heads):
-            s = lax.dot_general(q_ref[h], k, _NT,
+            s = lax.dot_general(q_ref[h], k, NT,
                                 preferred_element_type=jnp.float32) * scale
             if bias is not None:
                 s = s + bias
-            p = jnp.exp(s - _wide(lse_scr[h], tk))
-            dp = lax.dot_general(do_ref[h], v, _NT,
+            p = jnp.exp(s - wide(lse_scr[h], tk))
+            dp = lax.dot_general(do_ref[h], v, NT,
                                  preferred_element_type=jnp.float32)
-            ds = p * (dp - _wide(di_scr[h], tk))
+            ds = p * (dp - wide(di_scr[h], tk))
             acc_scr[h] += jnp.dot(ds.astype(k.dtype), k,
                                   preferred_element_type=jnp.float32)
 
@@ -268,14 +251,14 @@ def _dkv_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref, di_ref,
         k, v = k_ref[...], v_ref[...]
         for h in range(heads):
             q, do = q_ref[h], do_ref[h]
-            s = lax.dot_general(k, q, _NT,
+            s = lax.dot_general(k, q, NT,
                                 preferred_element_type=jnp.float32) * scale
             if bias is not None:
                 s = s + bias
             p = jnp.exp(s - lse_ref[h])
             dv_scr[...] += jnp.dot(p.astype(do.dtype), do,
                                    preferred_element_type=jnp.float32)
-            dp = lax.dot_general(v, do, _NT,
+            dp = lax.dot_general(v, do, NT,
                                  preferred_element_type=jnp.float32)
             ds = p * (dp - di_ref[h])
             dk_scr[...] += jnp.dot(ds.astype(q.dtype), q,
@@ -353,7 +336,7 @@ def _call(kernel, pl, pltpu, specs, rest_specs, out_specs, out_shape, scratch,
     else:
         body = functools.partial(kernel, pl=pl, **statics)
         operands = operands[:3] + (keep,) + operands[3:]
-    vma = _vma(*map(jax.typeof, operands))
+    vma = lowering.vma(*map(jax.typeof, operands))
     return pl.pallas_call(
         body, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct(s, t, vma=vma) for s, t in out_shape],
@@ -366,10 +349,7 @@ def _call(kernel, pl, pltpu, specs, rest_specs, out_specs, out_shape, scratch,
 
 def _forward_pallas(q, k, v, keep=None, *, tiles, window=0, interpret=False):
     """``keep`` None: the mask is ``0 <= q - k < window`` (0: causal)."""
-    # imported here: a second and a half that only a process which lowers
-    # the kernel should pay (no CPU run does)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = lowering.pallas()
 
     b, s_len, n, g, d = q.shape
     window = window or s_len
@@ -378,7 +358,7 @@ def _forward_pallas(q, k, v, keep=None, *, tiles, window=0, interpret=False):
     out, lse = _call(
         _forward_kernel, pl, pltpu, specs, [], [rows, stat],
         [((b, n, g, s_len, d), q.dtype), ((b, n, g, 1, s_len), jnp.float32)],
-        [(g, tiles[0], _LANES)] * 2 + [(g, tiles[0], d)],
+        [(g, tiles[0], LANES)] * 2 + [(g, tiles[0], d)],
         (_heads_major(q), _kv_major(k), _kv_major(v)), keep,
         interpret=interpret, tiles=tiles, scale=1.0 / math.sqrt(d),
         window=window)
@@ -387,8 +367,7 @@ def _forward_pallas(q, k, v, keep=None, *, tiles, window=0, interpret=False):
 
 def _backward_pallas(q, k, v, keep, out, lse, g_out, *, tiles, window=0,
                      interpret=False):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = lowering.pallas()
 
     b, s_len, n, g, d = q.shape
     tq, tk = tiles
@@ -404,7 +383,7 @@ def _backward_pallas(q, k, v, keep, out, lse, g_out, *, tiles, window=0,
     dq, = _call(
         _dq_kernel, pl, pltpu, specs, [rows, stat, stat], [rows],
         [((b, n, g, s_len, d), q.dtype)],
-        [(g, tq, _LANES)] * 2 + [(g, tq, d)], operands, keep, **statics)
+        [(g, tq, LANES)] * 2 + [(g, tq, d)], operands, keep, **statics)
     specs = _specs(pl, q.shape, tiles, window, keys_outer=True)
     rows, keys, _, stat, _ = specs
     dk, dv = _call(
@@ -414,20 +393,9 @@ def _backward_pallas(q, k, v, keep, out, lse, g_out, *, tiles, window=0,
     return _tokens_major(dq), _kv_major(dk), _kv_major(dv)
 
 
-@functools.lru_cache(maxsize=None)
-def _kernels_of(tiles, window, interpret=False):
-    """The two passes' kernels for one tiling and one mask (``window`` None:
-    the ``keep`` operand; 0: causal; else the band), jitted: every layer of
-    a program that holds them at the same shapes traces their bodies once."""
-    opts = dict(tiles=tiles, window=window or 0, interpret=interpret)
-
-    def attention_forward(q, k, v, *keep):
-        return _forward_pallas(q, k, v, *keep, **opts)
-
-    def attention_backward(q, k, v, *rest):     # [keep,] out, lse, g_out
-        return _backward_pallas(q, k, v, *(rest[:-3] or (None,)), *rest[-3:],
-                                **opts)
-    return jax.jit(attention_forward), jax.jit(attention_backward)
+def _backward_of(q, k, v, *rest, **opts):     # [keep,] out, lse, g_out
+    return _backward_pallas(q, k, v, *(rest[:-3] or (None,)), *rest[-3:],
+                            **opts)
 
 
 # ---------------------------------------------------------------------------
@@ -484,27 +452,19 @@ def _backward_ruled_xla(q, k, v, out, lse, g_out, *, attend, window):
 
 def _forward_avals(q, k, v, *keep, **_):
     b, s_len, n, g, _ = q.shape
-    vma = _vma(q, k, v, *keep)
+    vma = lowering.vma(q, k, v, *keep)
     return (q.update(weak_type=False, vma=vma),
             q.update(shape=(b, n, g, 1, s_len), dtype=jnp.dtype(jnp.float32),
                      weak_type=False, vma=vma))
 
 
 def _backward_avals(q, k, v, *rest, **_):
-    vma = _vma(q, k, v, *rest)
+    vma = lowering.vma(q, k, v, *rest)
     return tuple(a.update(weak_type=False, vma=vma) for a in (q, k, v))
 
 
-def _primitive(name, avals):
-    p = Primitive(name)
-    p.multiple_results = True
-    p.def_impl(functools.partial(dispatch.apply_primitive, p))
-    p.def_abstract_eval(avals)
-    return p
-
-
-_forward_p = _primitive("masked_attention", _forward_avals)
-_backward_p = _primitive("masked_attention_backward", _backward_avals)
+_forward_p = lowering.primitive("masked_attention", _forward_avals)
+_backward_p = lowering.primitive("masked_attention_backward", _backward_avals)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
@@ -554,7 +514,7 @@ def ruled_attention(q, k, v, window: int, attend, kind: str):
 def kernels_take(head_dim: int) -> bool:
     """Whether heads of this width can lower to the kernels at all (a
     caller's remat keeps ``SAVED_NAMES`` where they can)."""
-    return head_dim % _LANES == 0
+    return head_dim % LANES == 0
 
 
 def tiles_of(window):
@@ -568,33 +528,29 @@ def tiles_of(window):
     return _TILES
 
 
-def _lower(ctx, *args, attend, kind, window, backward, kernels,
-           interpret=False):
-    q, k, v = ctx.avals_in[:3]
-    tiles = tiles_of(window)
-    kernel = (
-        kernels and _unpartitioned(ctx.module_context.axis_context)
-        and q.dtype == k.dtype == v.dtype and kernels_take(q.shape[-1])
-        and all(q.shape[1] % t == 0 for t in tiles))
-    # the choice is made once per lowering, so lowerings are what is counted
-    from ..obs.metrics import get_registry
-    get_registry().counter("attention_lowerings").labels(
-        spelling="kernel" if kernel else "xla", kind=kind,
-        **{"pass": "backward" if backward else "forward"}).inc()
-    if kernel:
-        fn = _kernels_of(tiles, window, interpret)[backward]
-    elif window is None:
-        fn = functools.partial(_backward_xla if backward else _forward_xla,
-                               attend=attend)
-    else:
-        fn = functools.partial(
-            _backward_ruled_xla if backward else _forward_ruled_xla,
-            attend=attend, window=window)
-    return mlir.lower_fun(fn, multiple_results=True)(ctx, *args)
+def _takes(avals, *, window, **_):
+    q, k, v = avals[:3]
+    return (q.dtype == k.dtype == v.dtype and kernels_take(q.shape[-1])
+            and all(q.shape[1] % t == 0 for t in tiles_of(window)))
 
 
-for _p, _backward in ((_forward_p, False), (_backward_p, True)):
-    mlir.register_lowering(_p, functools.partial(
-        _lower, backward=_backward, kernels=False))
-    mlir.register_lowering(_p, functools.partial(
-        _lower, backward=_backward, kernels=True), platform="tpu")
+def _kernel_spelling(*, interpret, window, backward, **_):
+    return lowering.jitted(
+        "attention_backward" if backward else "attention_forward",
+        _backward_of if backward else _forward_pallas,
+        tiles=tiles_of(window), window=window, interpret=interpret)
+
+
+def _xla_spelling(*, attend, window, backward, **_):
+    if window is None:
+        return functools.partial(_backward_xla if backward else _forward_xla,
+                                 attend=attend)
+    return functools.partial(
+        _backward_ruled_xla if backward else _forward_ruled_xla,
+        attend=attend, window=window)
+
+
+lowering.register(_forward_p, _backward_p, rule=_takes,
+                  kernel=_kernel_spelling, xla=_xla_spelling,
+                  counter="attention_lowerings",
+                  labels=lambda kind, **_: [{"kind": kind}])

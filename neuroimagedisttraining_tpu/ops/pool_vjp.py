@@ -21,18 +21,13 @@ way the kernel's gain is lost again. Given ``c`` and ``bias`` the kernel adds
 in VMEM, with the rounding of XLA's own add, and the saved ``c`` is the one
 tensor the conv fusion writes.
 
-The backward is one primitive with two lowerings, chosen where the program
-is lowered, by what the compiler can run there:
-
-* a Pallas kernel where the target is a TPU, no partitioner will touch the
-  op (GSPMD cannot partition a Mosaic kernel) and its blocks fit VMEM: a
-  program for one device, or the inside of a ``shard_map`` that is manual
-  over every mesh axis of more than one device, which is how the
-  clients-mesh round trains each chip's sites (``algorithms/base.py:
-  _train_clients``, ISSUE 29);
-* ``lax.reduce_window``'s own VJP everywhere else: the CPU, and whatever
-  GSPMD partitions (a vmapped client axis sharded over a mesh outside any
-  ``shard_map``, a ``space`` axis left automatic).
+The backward is one primitive with two lowerings (``ops/lowering.py`` has
+the rule): a Pallas kernel where its blocks fit VMEM on a TPU with no
+partitioner in the way, as where the clients-mesh round trains each chip's
+sites (``algorithms/base.py:_train_clients``, ISSUE 29);
+``lax.reduce_window``'s own VJP everywhere else (the CPU, a vmapped client
+axis sharded over a mesh outside any ``shard_map``, a ``space`` axis left
+automatic).
 
 Every spelling of the mask in XLA's own ops lost to ``select-and-scatter`` on
 the chip (PERF.md section 6 has each one's milliseconds): XLA fuses neither
@@ -50,9 +45,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax._src import dispatch, sharding_impls
-from jax.extend.core import Primitive
-from jax.interpreters import batching, mlir
+from jax.interpreters import batching
+
+from . import lowering
 
 # what a v5e core offers a kernel, less room for Mosaic's own scratch
 _VMEM_BUDGET = 96 * 2 ** 20
@@ -159,20 +154,12 @@ def _kernel_vmem_bytes(shape, dtype, window) -> int:
     return 2 * 2 * window[0] * window[1] * w * tile
 
 
-def _vma(*avals):
-    """The manual mesh axes of an enclosing ``shard_map`` over which any of
-    ``avals`` varies: the result varies over them as the operands do."""
-    return frozenset().union(*(a.vma for a in avals))
+def _voxel_major(a):        # a bitcast on the chip: D, H, W are untiled
+    return a.transpose(1, 2, 3, 0, 4)
 
 
 def _scatter_pallas(c, bias, m, g, *, window, interpret=False):
-    # imported here: a second and a half that only a process which lowers
-    # the kernel should pay (no CPU run does)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    def voxel_major(a):     # a bitcast on the chip: D, H, W are untiled
-        return a.transpose(1, 2, 3, 0, 4)
+    pl, pltpu = lowering.pallas()
 
     n, d, h, w, ch = c.shape
     pooled = m.shape[1:4]
@@ -191,14 +178,14 @@ def _scatter_pallas(c, bias, m, g, *, window, interpret=False):
         out_specs=block,
         out_shape=jax.ShapeDtypeStruct(
             (d, h, w, n, ch), c.dtype,
-            vma=_vma(*map(jax.typeof, (c, bias, m, g)))),
+            vma=lowering.vma(*map(jax.typeof, (c, bias, m, g)))),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=min(_VMEM_BUDGET, max(
                 16 * 2 ** 20,
                 2 * _kernel_vmem_bytes(c.shape, c.dtype, window)))),
         interpret=interpret,
-    )(voxel_major(c), bias.reshape(1, ch), voxel_major(m), voxel_major(g))
+    )(_voxel_major(c), bias.reshape(1, ch), _voxel_major(m), _voxel_major(g))
     return dz.transpose(3, 0, 1, 2, 4)
 
 
@@ -583,11 +570,7 @@ def _windows_kernel(z_ref, g_ref, o_ref, zring, rm, pm, gd, gh, *, pl, pltpu,
 
 def _windows_pallas(z, m, g, *, window, strides, padding, interpret=False):
     del m   # the maxima are the kernel's own: it needs them plane by plane
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    def voxel_major(a):     # a bitcast on the chip: D, H, W are untiled
-        return a.transpose(1, 2, 3, 0, 4)
+    pl, pltpu = lowering.pallas()
 
     n, d, h, w, ch = z.shape
     oc, hc, nq, vmem = _windows_plan(z.shape, z.dtype, window, strides,
@@ -620,7 +603,8 @@ def _windows_pallas(z, m, g, *, window, strides, padding, interpret=False):
         out_specs=pl.BlockSpec(block(1, oc, w), lambda j, t: (
             jnp.clip(t - lag, 0, d - 1), rows(j)[0], 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(
-            (d, h, w, n, ch), z.dtype, vma=_vma(*map(jax.typeof, (z, g)))),
+            (d, h, w, n, ch), z.dtype,
+            vma=lowering.vma(*map(jax.typeof, (z, g)))),
         scratch_shapes=[
             pltpu.VMEM((live, hc, sw * (half - 1) + kw, n, lanes),
                        z.dtype),                                    # zring
@@ -633,7 +617,7 @@ def _windows_pallas(z, m, g, *, window, strides, padding, interpret=False):
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem + 8 * 2 ** 20),
         interpret=interpret,
-    )(voxel_major(z), voxel_major(g))
+    )(_voxel_major(z), _voxel_major(g))
     return dz.transpose(3, 0, 1, 2, 4)
 
 
@@ -642,10 +626,11 @@ def _windows_pallas(z, m, g, *, window, strides, padding, interpret=False):
 # are (c, bias, m, g) where the caller told of the sum, else (z, m, g)
 
 
-_scatter_p = Primitive("max_pool_first_match_scatter")
-_scatter_p.def_impl(functools.partial(dispatch.apply_primitive, _scatter_p))
-_scatter_p.def_abstract_eval(
-    lambda z, *rest, **_: z.update(weak_type=False, vma=_vma(z, *rest)))
+_scatter_p = lowering.primitive(
+    "max_pool_first_match_scatter",
+    lambda z, *rest, **_: z.update(weak_type=False,
+                                   vma=lowering.vma(z, *rest)),
+    multiple_results=False)
 
 
 def first_match_scatter(c, bias, m, g, window):
@@ -663,27 +648,6 @@ def _scatter_batch(args, dims, *, batch_dims, **geometry):
 
 
 batching.primitive_batchers[_scatter_p] = _scatter_batch
-
-
-def _lower_as(spelling):
-    def rule(ctx, *args, window, batch_dims):
-        fn = functools.partial(spelling, window=window)
-        for _ in range(batch_dims):
-            fn = jax.vmap(fn)
-        return mlir.lower_fun(fn, multiple_results=False)(ctx, *args)
-    return rule
-
-
-@functools.lru_cache(maxsize=None)
-def _windows_kernel_of(window, strides, padding):
-    """``_windows_pallas`` for one geometry, jitted: every program of a
-    process that holds the kernel at the same shapes (the SNIP pass and the
-    round) then traces its body once, a third of a second of every start on
-    the chip's host that the second would pay again."""
-    def pool_backward(z, m, g):
-        return _windows_pallas(z, m, g, window=window, strides=strides,
-                               padding=padding)
-    return jax.jit(pool_backward)
 
 
 def _one_at_a_time(spelling, batch_dims):
@@ -704,51 +668,49 @@ def _one_at_a_time(spelling, batch_dims):
     return mapped
 
 
-def _unpartitioned(context) -> bool:
-    """Whether an op lowered under ``context`` reaches the compiler as it is:
-    the program is one device's, or every mesh axis of more than one device
-    is manual (the inside of a ``shard_map`` over all of them)."""
-    if isinstance(context, sharding_impls.ShardingContext):
-        return context.num_devices == 1
-    if isinstance(context, sharding_impls.SPMDAxisContext):
-        return all(size == 1 or name in context.manual_axes
-                   for name, size in context.mesh.shape.items())
-    return False
+def _vmapped(fn, batch_dims):
+    for _ in range(batch_dims):
+        fn = jax.vmap(fn)
+    return fn
 
 
-def _count(spelling, window, strides, padding):
-    # the choice is made once per lowering, so lowerings are what is counted
-    from ..obs.metrics import get_registry
-    get_registry().counter("pool_bwd_lowerings").labels(
-        spelling=spelling, geometry="_".join(
-            "x".join(map(str, sorted(set(v), reverse=True)))
-            for v in (window, strides, padding))).inc()
-
-
-def _lower(ctx, *args, window, strides, padding, batch_dims, kernels):
-    told_of_sum = len(args) == 4
-    z, m = ctx.avals_in[0], ctx.avals_in[-2]
-    geometry = dict(window=window, strides=strides, padding=padding)
+def _takes(avals, *, window, strides, padding, batch_dims):
+    z, m = avals[0], avals[-2]
     shape = z.shape[batch_dims:]
-    kernel = (
-        kernels and min(m.shape) > 0
-        and _unpartitioned(ctx.module_context.axis_context)
-        and (2 * _kernel_vmem_bytes(shape, z.dtype, window) <= _VMEM_BUDGET
-             if told_of_sum else
-             _windows_plan(shape, z.dtype, **geometry) is not None))
-    _count("kernel" if kernel else "xla", **geometry)
-    if told_of_sum:
-        return _lower_as(_scatter_pallas if kernel else _scatter_xla)(
-            ctx, *args, window=window, batch_dims=batch_dims)
-    if kernel:
-        fn = _one_at_a_time(_windows_kernel_of(**geometry), batch_dims)
-    else:
-        fn = functools.partial(_windows_xla, **geometry)
-        for _ in range(batch_dims):
-            fn = jax.vmap(fn)
-    return mlir.lower_fun(fn, multiple_results=False)(ctx, *args)
+    if min(m.shape) == 0:
+        return False
+    if len(avals) == 4:     # told of the sum
+        return 2 * _kernel_vmem_bytes(shape, z.dtype, window) <= _VMEM_BUDGET
+    return _windows_plan(shape, z.dtype, window, strides, padding) is not None
 
 
-mlir.register_lowering(_scatter_p, functools.partial(_lower, kernels=False))
-mlir.register_lowering(_scatter_p, functools.partial(_lower, kernels=True),
-                       platform="tpu")
+def _kernel_spelling(*, interpret, window, strides, padding, batch_dims):
+    def spelled(*args):
+        if len(args) == 4:
+            return _vmapped(functools.partial(
+                _scatter_pallas, window=window, interpret=interpret),
+                batch_dims)(*args)
+        return _one_at_a_time(lowering.jitted(
+            "pool_backward", _windows_pallas, window=window, strides=strides,
+            padding=padding, interpret=interpret), batch_dims)(*args)
+    return spelled
+
+
+def _xla_spelling(*, window, strides, padding, batch_dims):
+    def spelled(*args):
+        if len(args) == 4:
+            return _scatter_xla(*args, window=window)
+        return _windows_xla(*args, window=window, strides=strides,
+                            padding=padding)
+    return _vmapped(spelled, batch_dims)
+
+
+def _geometry(**geometry):
+    return [{"geometry": "_".join(
+        "x".join(map(str, sorted(set(geometry[k]), reverse=True)))
+        for k in ("window", "strides", "padding"))}]
+
+
+lowering.register(_scatter_p, rule=_takes, kernel=_kernel_spelling,
+                  xla=_xla_spelling, counter="pool_bwd_lowerings",
+                  labels=_geometry)

@@ -71,6 +71,20 @@ def pytest_collection_modifyitems(config, items):
         items[:] = [i for i in items if not i.get_closest_marker("tpu")]
 
 
+@pytest.fixture
+def lowerings(request):
+    """The process's metric registry, fresh for the test: ``lowerings()``
+    reads the counter that the test module names ``COUNTER`` by labels,
+    ``lowerings(name)`` the counter ``name``."""
+    from neuroimagedisttraining_tpu.obs import metrics
+    before = metrics.set_registry(None)
+    try:
+        yield lambda name=None: metrics.get_registry().snapshot().get(
+            name or request.module.COUNTER, {}).get("labeled", {})
+    finally:
+        metrics.set_registry(before)
+
+
 @pytest.fixture(scope="session")
 def eight_devices():
     devices = jax.devices()

@@ -25,14 +25,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.experimental import pallas as pl
-from jax.interpreters import mlir
 
 from neuroimagedisttraining_tpu.models import decoder, make_apply_fn
-from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
+from neuroimagedisttraining_tpu.ops import lowering
 from neuroimagedisttraining_tpu.ops import masked_attention as ma
 
 SEQ, WIDTH, TILES = 512, 128, (128, 128)
-PRIMITIVES = ((ma._forward_p, False), (ma._backward_p, True))
+COUNTER = "attention_lowerings"
 
 
 def nearest_keys(topk):
@@ -155,17 +154,6 @@ def test_kernels_under_a_rule_against_xlas_spelling_of_it(group, rule):
 # what selects the lowering
 
 
-@pytest.fixture
-def lowerings():
-    """The process's registry, fresh: ``attention_lowerings`` by labels."""
-    before = obs_metrics.set_registry(None)
-    try:
-        yield lambda: obs_metrics.get_registry().snapshot().get(
-            "attention_lowerings", {}).get("labeled", {})
-    finally:
-        obs_metrics.set_registry(before)
-
-
 def lowered_grad_text(seq, width, platform, kind="selected",
                       dtype=jnp.bfloat16):
     q = jnp.zeros((1, seq, 1, 8, width), dtype)
@@ -209,43 +197,8 @@ def test_target_and_shapes_select_the_lowering(seq, width, platform, spelling,
         for p in ("forward", "backward")}
 
 
-def test_a_partitioned_axis_keeps_xlas_spelling(lowerings):
-    """GSPMD cannot partition a Mosaic kernel: a mesh of more than one
-    device outside any ``shard_map`` gets ``_attend``."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    mesh = Mesh(np.asarray(jax.devices()[:2]), ("clients",))
-    q = jnp.zeros((2, 1024, 1, 8, 128), jnp.bfloat16)
-    k = jnp.zeros((2, 1024, 1, 128), jnp.bfloat16)
-    keep = jnp.ones((2, 1024, 1024), jnp.int8)
-    sharded = NamedSharding(mesh, P("clients"))
-    text = jax.jit(
-        lambda q, k, v, keep: ma.masked_attention(
-            q, k, v, keep, decoder._attend, "selected"),
-        in_shardings=(sharded,) * 4).trace(q, k, k, keep).lower(
-            lowering_platforms=("tpu",)).as_text()
-    assert "tpu_custom_call" not in text
-    assert lowerings() == {"kind=selected,pass=forward,spelling=xla": 1.0}
-
-
 # ---------------------------------------------------------------------------
 # the whole decoder on the kernels
-
-
-@pytest.fixture
-def kernels_interpreted(monkeypatch):
-    """The TPU's lowering rule, interpreted, in the CPU's place."""
-    monkeypatch.setattr(ma, "_TILES", TILES)
-    monkeypatch.setattr(ma, "_BAND_TILES", TILES)
-    rules = mlir._platform_specific_lowerings["cpu"]
-    for p, backward in PRIMITIVES:
-        mlir.register_lowering(p, functools.partial(
-            ma._lower, backward=backward, kernels=True, interpret=True),
-            platform="cpu")
-    try:
-        yield
-    finally:
-        for p, _ in PRIMITIVES:
-            del rules[p]
 
 
 def selecting_decoder():
@@ -271,20 +224,20 @@ def selecting_decoder():
 
 
 def test_the_decoder_on_the_kernels_is_the_decoder_on_attend(
-        kernels_interpreted, lowerings):
-    params, step = selecting_decoder()
-    (loss, logits), grads = step(params)
+        monkeypatch, lowerings):
+    """The TPU's lowering rule, interpreted, in the CPU's place, against the
+    CPU's own."""
+    monkeypatch.setattr(ma, "_TILES", TILES)
+    monkeypatch.setattr(ma, "_BAND_TILES", TILES)
+    with lowering.interpreted(ma._forward_p, ma._backward_p):
+        params, step = selecting_decoder()
+        (loss, logits), grads = step(params)
     # a program's layers share one lowering; init's 16 tokens do not tile
     assert lowerings() == {
         "kind=selected,pass=forward,spelling=kernel": 1.0,
         "kind=selected,pass=backward,spelling=kernel": 1.0,
         "kind=selected,pass=forward,spelling=xla": 1.0}
-    rules = mlir._platform_specific_lowerings["cpu"]
-    saved = {p: rules.pop(p) for p, _ in PRIMITIVES}
-    try:
-        (want_loss, want_logits), want = selecting_decoder()[1](params)
-    finally:
-        rules.update(saved)
+    (want_loss, want_logits), want = selecting_decoder()[1](params)
     close(loss, want_loss)
     close(logits, want_logits)
     flat, want_flat = (dict(jax.tree_util.tree_leaves_with_path(t))

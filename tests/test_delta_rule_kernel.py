@@ -12,47 +12,25 @@ two lowerings.
 * what selects the lowering: the target, the widths and the chunk, each pass
   counted in ``linear_scan_lowerings``.
 """
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.interpreters import mlir
 
 from benchmarks.reference import qwen3_next as ref
 from neuroimagedisttraining_tpu.models import decoder
-from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
 from neuroimagedisttraining_tpu.ops import delta_rule as dr
+from neuroimagedisttraining_tpu.ops import lowering
 
-PRIMITIVES = ((dr._forward_p, False), (dr._backward_p, True))
 CHUNK = 64
-
-
-@pytest.fixture
-def lowerings():
-    """The process's registry, fresh: ``linear_scan_lowerings`` by labels."""
-    before = obs_metrics.set_registry(None)
-    try:
-        yield lambda: obs_metrics.get_registry().snapshot().get(
-            "linear_scan_lowerings", {}).get("labeled", {})
-    finally:
-        obs_metrics.set_registry(before)
+COUNTER = "linear_scan_lowerings"
 
 
 @pytest.fixture
 def kernels_interpreted():
     """The TPU's lowering rule, interpreted, in the CPU's place."""
-    rules = mlir._platform_specific_lowerings["cpu"]
-    for p, backward in PRIMITIVES:
-        mlir.register_lowering(p, functools.partial(
-            dr._lower, backward=backward, kernels=True, interpret=True),
-            platform="cpu")
-    try:
+    with lowering.interpreted(dr._forward_p, dr._backward_p):
         yield
-    finally:
-        for p, _ in PRIMITIVES:
-            del rules[p]
 
 
 def counted(spelling, passes=("forward", "backward")):

@@ -10,7 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
 from neuroimagedisttraining_tpu.ops import embedding
 
 ROWS, TOKENS = 48, 40
@@ -133,7 +132,8 @@ def test_the_tied_heads_leaf_gets_both_uses(width):
 @pytest.mark.parametrize("width,slabs", [
     (32, 1), (128, 1), (2048, 1), (3072, 1), (4096, 1), (2560, 2),
     (5120, 2), (5000, 4), (6144, 2)])
-def test_one_slab_is_jnp_take_itself_and_more_are_a_custom_vjp(width, slabs):
+def test_one_slab_is_jnp_take_itself_and_more_are_a_custom_vjp(width, slabs,
+                                                               lowerings):
     """The rule hangs on the table's width alone. One slab: the jaxpr of the
     lookup and of its gradient are ``jnp.take``'s, character for character.
     More: a ``custom_vjp`` whose backward holds one scatter-add a slab, each
@@ -157,13 +157,8 @@ def test_one_slab_is_jnp_take_itself_and_more_are_a_custom_vjp(width, slabs):
             look(t, ids).astype(jnp.float32))))(table)
         return str(value), str(grad)
 
-    before = obs_metrics.set_registry(None)
-    try:
-        mine = programs(embedding.lookup)
-        counted = obs_metrics.get_registry().snapshot()[
-            "embed_lowerings"]["labeled"]
-    finally:
-        obs_metrics.set_registry(before)
+    mine = programs(embedding.lookup)
+    counted = lowerings("embed_lowerings")
     if slabs == 1:
         assert mine == programs(_take)
         assert "custom_vjp" not in mine[0] + mine[1]

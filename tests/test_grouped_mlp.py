@@ -12,32 +12,19 @@ ISSUE 38): one meaning, two lowerings.
 * what selects the lowering: the target, the widths and the row tile, each
   product counted in ``expert_lowerings``.
 """
-import functools
 import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.interpreters import mlir
 
 from neuroimagedisttraining_tpu.models import decoder
-from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
 from neuroimagedisttraining_tpu.ops import grouped_mlp as gm
+from neuroimagedisttraining_tpu.ops import lowering
 
 TM = 128
-PRIMITIVES = ((gm._forward_p, False), (gm._backward_p, True))
-
-
-@pytest.fixture
-def lowerings():
-    """The process's registry, fresh: ``expert_lowerings`` by labels."""
-    before = obs_metrics.set_registry(None)
-    try:
-        yield lambda: obs_metrics.get_registry().snapshot().get(
-            "expert_lowerings", {}).get("labeled", {})
-    finally:
-        obs_metrics.set_registry(before)
+COUNTER = "expert_lowerings"
 
 
 def counted(spelling, passes=("forward", "backward")):
@@ -51,16 +38,8 @@ def kernels_interpreted(monkeypatch):
     tile the kernels take (the chunks here are too small for the rule of
     shape to give one)."""
     monkeypatch.setattr(gm, "row_tile", lambda rows, held: TM)
-    rules = mlir._platform_specific_lowerings["cpu"]
-    for p, backward in PRIMITIVES:
-        mlir.register_lowering(p, functools.partial(
-            gm._lower, backward=backward, kernels=True, interpret=True),
-            platform="cpu")
-    try:
+    with lowering.interpreted(gm._forward_p, gm._backward_p):
         yield
-    finally:
-        for p, _ in PRIMITIVES:
-            del rules[p]
 
 
 def close(got, want, tol=1e-4):
@@ -260,7 +239,9 @@ def test_each_pass_on_the_kernels_is_xlas_on_the_same_layout():
     wg, wu = (jax.random.normal(k, (held, hidden, width)) * 0.1
               for k in keys[3:5])
     wd = jax.random.normal(keys[5], (held, width, hidden)) * 0.1
-    forward, backward = gm._kernels_of(TM, True)
+    forward, backward = (
+        gm._kernel_spelling(interpret=True, tm=TM, backward=b)
+        for b in (False, True))
     args = (xs, rw, wg, wu, wd, tile_expert)
     close(forward(*args)[0], gm._forward_xla(*args, tm=TM)[0])
     for got, want in zip(backward(*args, gy),

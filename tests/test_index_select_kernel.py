@@ -19,25 +19,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.interpreters import mlir
 
 from neuroimagedisttraining_tpu.models import decoder
-from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
 from neuroimagedisttraining_tpu.ops import index_select as isel
+from neuroimagedisttraining_tpu.ops import lowering
 
 # (query tile, key chunk, column block, rows a count) for the interpreter
 TILES = dict(_TQ=64, _TC=128, _TO=256, _ROWS=32)
-
-
-@pytest.fixture
-def lowerings():
-    """The process's registry, fresh: ``selection_lowerings`` by labels."""
-    before = obs_metrics.set_registry(None)
-    try:
-        yield lambda: obs_metrics.get_registry().snapshot().get(
-            "selection_lowerings", {}).get("labeled", {})
-    finally:
-        obs_metrics.set_registry(before)
+COUNTER = "selection_lowerings"
 
 
 @pytest.fixture
@@ -46,15 +35,8 @@ def kernel_interpreted(monkeypatch):
     place."""
     for name, value in TILES.items():
         monkeypatch.setattr(isel, name, value)
-    isel._kernel_of.cache_clear()
-    rules = mlir._platform_specific_lowerings["cpu"]
-    mlir.register_lowering(isel._select_p, functools.partial(
-        isel._lower, kernels=True, interpret=True), platform="cpu")
-    try:
+    with lowering.interpreted(isel._select_p):
         yield
-    finally:
-        del rules[isel._select_p]
-        isel._kernel_of.cache_clear()
 
 
 def select(q_idx, w_idx, k_idx, topk, block=128):
@@ -157,7 +139,6 @@ def test_the_op_on_the_kernel_is_the_op_on_the_blocks(kernel_interpreted,
     ops = operands(2, 512, kind="integers")
     got = np.asarray(select(*ops, 128))
     assert lowerings() == {"spelling=kernel": 1.0}
-    isel._kernel_of.cache_clear()
     want = np.asarray(decoder._selection_blocks(*ops, 128, 128))
     np.testing.assert_array_equal(got, want)
 
@@ -191,24 +172,6 @@ def test_target_and_shapes_select_the_lowering(seq, width, platform, spelling,
     text = lowered_text(seq, width, platform)
     assert text.count("tpu_custom_call") == (spelling == "kernel")
     assert lowerings() == {f"spelling={spelling}": 1.0}
-
-
-def test_a_partitioned_axis_keeps_the_blocks(lowerings):
-    """GSPMD cannot partition a Mosaic kernel: a mesh of more than one
-    device outside any ``shard_map`` gets the blocks."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    mesh = Mesh(np.asarray(jax.devices()[:2]), ("clients",))
-    sharded = NamedSharding(mesh, P("clients"))
-    q = jnp.zeros((2, 4096, 16, 64), jnp.bfloat16)
-    w = jnp.zeros((2, 4096, 16), jnp.float32)
-    k = jnp.zeros((2, 4096, 64), jnp.bfloat16)
-    text = jax.jit(
-        lambda q, w, k: isel.index_select(q, w, k, 2048,
-                                          decoder._selection_blocks, 1024),
-        in_shardings=(sharded,) * 3).trace(q, w, k).lower(
-            lowering_platforms=("tpu",)).as_text()
-    assert "tpu_custom_call" not in text
-    assert lowerings() == {"spelling=blocks": 1.0}
 
 
 @pytest.mark.parametrize("seq,width,takes", [
@@ -247,22 +210,20 @@ def selecting_decoder(seq=256):
 
 
 def test_the_decoder_on_the_kernel_is_the_decoder_on_the_blocks(
-        kernel_interpreted, lowerings, monkeypatch):
+        lowerings, monkeypatch):
     """The tiny preset's indexer is 8 wide, which the kernel's rule leaves
     to the blocks: with the rule's width patched to 8 the decoder's loss,
-    gradients and selections on the kernel equal those on the blocks."""
-    monkeypatch.setattr(isel, "_WIDTH", 8)
-    params, step = selecting_decoder()
-    (loss, kept), grads = step(params)
+    gradients and selections on the kernel (interpreted at small tiles, in
+    the CPU's place) equal those on the blocks."""
+    for name, value in dict(TILES, _WIDTH=8).items():
+        monkeypatch.setattr(isel, name, value)
+    with lowering.interpreted(isel._select_p):
+        params, step = selecting_decoder()
+        (loss, kept), grads = step(params)
     # the program's two layers share one lowering; init's 16 tokens do not
     # tile
     assert lowerings() == {"spelling=kernel": 1.0, "spelling=blocks": 1.0}
-    rules = mlir._platform_specific_lowerings["cpu"]
-    saved = rules.pop(isel._select_p)
-    try:
-        (want_loss, want_kept), want = selecting_decoder()[1](params)
-    finally:
-        rules[isel._select_p] = saved
+    (want_loss, want_kept), want = selecting_decoder()[1](params)
     assert float(loss) == float(want_loss)
     for a, b in zip(jax.tree_util.tree_leaves(kept),
                     jax.tree_util.tree_leaves(want_kept)):

@@ -39,7 +39,6 @@ from neuroimagedisttraining_tpu.algorithms.base import FedAlgorithm
 from neuroimagedisttraining_tpu.core.state import HyperParams
 from neuroimagedisttraining_tpu.data import make_synthetic_federated
 from neuroimagedisttraining_tpu.models import create_model
-from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
 from neuroimagedisttraining_tpu.ops.s2d import phased_sample_shape
 from neuroimagedisttraining_tpu.parallel import make_mesh
 from neuroimagedisttraining_tpu.parallel.mesh import shard_federated_hybrid
@@ -257,18 +256,13 @@ def one_chip_round_for_the_tpu(**model):
     return lowered_round(algo, shapes, platform="tpu")
 
 
-def test_one_chip_rounds_lowered_for_the_tpu_hold_both_pool_kernels():
+def test_one_chip_rounds_lowered_for_the_tpu_hold_both_pool_kernels(
+        lowerings):
     """``resnet3d_abcd.protocol``'s and ``alexnet3d_abcd.train``'s rounds at
     CI size (33^3, 69^3), as one chip lowers them."""
-    before = obs_metrics.set_registry(None)
-    try:
-        resnet = one_chip_round_for_the_tpu(**RESNET).as_text(
-            debug_info=True)
-        alexnet = one_chip_round_for_the_tpu(**ALEXNET).as_text()
-        counted = obs_metrics.get_registry().snapshot()[
-            "pool_bwd_lowerings"]["labeled"]
-    finally:
-        obs_metrics.set_registry(before)
+    resnet = one_chip_round_for_the_tpu(**RESNET).as_text(debug_info=True)
+    alexnet = one_chip_round_for_the_tpu(**ALEXNET).as_text()
+    counted = lowerings("pool_bwd_lowerings")
     # ResNet_l3: its one pool is the stem's, (3, 2, 1) on the conv output
     assert "select_and_scatter" not in resnet
     assert len(re.findall(r"custom_call @tpu_custom_call", resnet)) == 1

@@ -43,11 +43,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from neuroimagedisttraining_tpu.models.alexnet3d import S2DStemStage
 from neuroimagedisttraining_tpu.models.layers import max_pool3d
 from neuroimagedisttraining_tpu.models.resnet3d import ResNet3DL3, S2DResNetStem
-from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
 from neuroimagedisttraining_tpu.ops import pool_vjp
 from neuroimagedisttraining_tpu.ops.s2d import phased_sample_shape
 from neuroimagedisttraining_tpu.parallel import make_mesh
 
+COUNTER = "pool_bwd_lowerings"
 DTYPES = {"bf16": jnp.bfloat16, "f32": jnp.float32}
 # (D, H, W): with a floor-dropped remainder for window 3 / without one
 EXTENTS = {"remainder": (11, 14, 11), "whole": (9, 12, 9)}
@@ -379,17 +379,6 @@ def spelling(text):
              if name in text}
     assert len(found) == 1, found
     return found.pop()
-
-
-@pytest.fixture
-def lowerings():
-    """The process's registry, fresh: ``pool_bwd_lowerings`` by label set."""
-    before = obs_metrics.set_registry(None)
-    try:
-        yield lambda: obs_metrics.get_registry().snapshot().get(
-            "pool_bwd_lowerings", {}).get("labeled", {})
-    finally:
-        obs_metrics.set_registry(before)
 
 
 @pytest.mark.parametrize("platform", ["cpu", "tpu"])

@@ -374,24 +374,10 @@ LINEAR_SCOPES = ("linear_attention", "linear_attention/conv",
 def delta_rule_kernels_interpreted():
     """ops/delta_rule.py's TPU lowering rule, its kernels interpreted, in
     the CPU's place."""
-    import functools
+    from neuroimagedisttraining_tpu.ops import delta_rule, lowering
 
-    from jax.interpreters import mlir
-
-    from neuroimagedisttraining_tpu.ops import delta_rule
-
-    primitives = ((delta_rule._forward_p, False),
-                  (delta_rule._backward_p, True))
-    rules = mlir._platform_specific_lowerings["cpu"]
-    for p, backward in primitives:
-        mlir.register_lowering(p, functools.partial(
-            delta_rule._lower, backward=backward, kernels=True,
-            interpret=True), platform="cpu")
-    try:
+    with lowering.interpreted(delta_rule._forward_p, delta_rule._backward_p):
         yield
-    finally:
-        for p, _ in primitives:
-            del rules[p]
 
 
 @pytest.mark.parametrize("spelling", ["xla", "kernel"])
