@@ -1068,11 +1068,13 @@ class FedAlgorithm(abc.ABC):
         # batch_gather, optimizer (core/trainer.py, inside
         # local_train); stem with conv, norm, pool inside it
         # (models/alexnet3d.py:phased_stem_stage, inside the model's
-        # module); embed, attention with full or window or (a selecting
-        # layer's) indexer, select and selected inside it, short_conv (a
-        # conv layer's whole operator), ssm (a state-space mixer) and
+        # module); embed, attention with full or window or cross (a
+        # layer that reads another's K and V) or (a selecting layer's)
+        # indexer, select and selected inside it, short_conv (a conv
+        # layer's whole operator), ssm (a state-space mixer) and
         # linear_attention (a Gated DeltaNet layer) each with conv, scan
-        # and norm inside it, router, experts, shared_expert,
+        # and norm inside it, mamba (a Mamba-1 mixer) with scan inside it,
+        # gmu (a gated memory unit), router, experts, shared_expert,
         # dense_mlp, lm_head (models/decoder.py; lm_head also around the
         # per-token CE in core/losses.py).
         # The forward/backward pass needs none: JAX prints it as

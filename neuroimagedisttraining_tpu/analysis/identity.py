@@ -113,6 +113,8 @@ FLAG_CLASSES: Dict[str, Tuple[str, str]] = {
                                   "and groups held"),
     "lm_mlp_shards": ("identity", "m<M> after lm...t<T>: a dense MLP's "
                                   "columns held"),
+    "lm_first_layer": ("identity", "f<F> after lm...t<T>: the stage's "
+                                   "first layer"),
     "global_test": ("identity", "'-g' reference-parity tag"),
     "tag": ("identity", "explicit identity suffix"),
     # -- inert (telemetry / logging / placement / scheduling-only) ---------

@@ -66,6 +66,9 @@ def build_parser(algo: Optional[str] = None) -> argparse.ArgumentParser:
     p.add_argument("--lm_mlp_shards", type=int, default=0,
                    help="decoder models: chips that share a dense MLP's "
                         "columns, in whole tiles of 128 (0: held whole)")
+    p.add_argument("--lm_first_layer", type=int, default=0,
+                   help="decoder models: the first layer of the stage this "
+                        "chip keeps (0: the leading layers)")
     p.add_argument("--data_dir", type=str, default="",
                    help="dataset root (ABCD .h5 path or CIFAR batches dir)")
     p.add_argument("--partition_method", type=str, default="dir",
@@ -986,6 +989,8 @@ def run_identity(args: argparse.Namespace, algo: Optional[str] = None,
         parts.append(f"s{args.lm_ssm_shards}")
     if getattr(args, "lm_mlp_shards", 0):
         parts.append(f"m{args.lm_mlp_shards}")
+    if getattr(args, "lm_first_layer", 0):
+        parts.append(f"f{args.lm_first_layer}")
     # defense and fine-tune knobs change training behavior — they must
     # split checkpoint/log/stat_info lineages (unlike inert identity tags)
     if algo == "salientgrads" and getattr(args, "stratified_sampling", 0):

@@ -82,7 +82,8 @@ def _decoder_share(args):
                  tensor_shards=args.lm_tensor_shards,
                  vocab_shards=args.lm_vocab_shards,
                  ssm_shards=args.lm_ssm_shards,
-                 mlp_shards=args.lm_mlp_shards)
+                 mlp_shards=args.lm_mlp_shards,
+                 first_layer=args.lm_first_layer)
     return share, decoder.held_config(args.model.lower(),
                                       decoder.Share(**share))
 

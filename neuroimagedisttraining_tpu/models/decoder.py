@@ -18,7 +18,16 @@ an RMSNorm over each group's channels), or Gated DeltaNet linear attention
 ``full_attention_interval``-th: :class:`GatedDeltaNet`, the gated delta rule
 ``S_t = exp(g_t) S_{t-1} + k_t outer(beta_t (v_t - exp(g_t) S_{t-1}^T
 k_t))``, ``o_t = S_t^T q_t`` per value head, computed chunk by chunk with a
-triangular solve a chunk: :func:`delta_rule_scan`). Heads may carry a
+triangular solve a chunk: :func:`delta_rule_scan`), or SambaY's kinds
+(``mb_per_layer``: :func:`sambay_layer_types`): a Mamba-1 mixer
+(:class:`Mamba`: a decay per channel and state, ``h_t = exp(delta_t A)
+h_{t-1} + delta_t B_t u_t``, computed chunk by chunk by
+:func:`selective_scan`), the same mixer whose scan output is the memory
+later layers read, differential attention in window, full and cross form
+(:class:`DiffAttention`: two softmax maps a head pair, ``(A1 - lam A2) V``;
+the full layer's K and V are what the cross layers read) and a gated
+memory unit (:class:`GatedMemory`: ``(m * silu(x W_1)) W_2``); blocks pass
+the memory and the K and V on (``Block``'s ``shared``). Heads may carry a
 per-head or an elementwise output gate or a QK-norm, rotary angles may come
 from three position streams
 (``mrope_section``); an MLP is a dense SwiGLU or a sparse one: a router over
@@ -33,7 +42,7 @@ config (``embedding_multiplier``, ``lm_head_multiplier``,
 default to 1: a config without them lowers to the program it lowered to
 before they were read.
 
-Five families, keys as published: ``CONFIGS["laguna_s"]`` (Laguna-S-2.1,
+Six families, keys as published: ``CONFIGS["laguna_s"]`` (Laguna-S-2.1,
 https://huggingface.co/poolside/Laguna-S-2.1/blob/main/config.json),
 ``CONFIGS["keye_vl2"]`` (the language model of Keye-VL-2.0-30B-A3B,
 https://huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B/blob/main/config.json;
@@ -43,13 +52,16 @@ https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json) and
 ``CONFIGS["falcon_h1_34b"]`` (Falcon-H1-34B-Instruct,
 https://huggingface.co/tiiuae/Falcon-H1-34B-Instruct/blob/main/config.json)
 and ``CONFIGS["qwen3_next_80b"]`` (Qwen3-Next-80B-A3B-Instruct,
-https://huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct/blob/main/config.json);
-``laguna_tiny``, ``keye_tiny``, ``lfm2_tiny``, ``falcon_h1_tiny`` and
-``qwen3_next_tiny`` keep
+https://huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct/blob/main/config.json)
+and ``CONFIGS["phi4_mini_flash"]`` (Phi-4-mini-flash-reasoning,
+https://huggingface.co/microsoft/Phi-4-mini-flash-reasoning/blob/main/config.json);
+``laguna_tiny``, ``keye_tiny``, ``lfm2_tiny``, ``falcon_h1_tiny``,
+``qwen3_next_tiny`` and ``phi4_flash_tiny`` keep
 their structure at a size the CPU tests run. What a layer is comes from the
 keys a config has (:func:`layer_plan`), never from a model's name.
-What ONE CHIP holds of a model is :class:`Share`: the depth kept (leading
-layers; the rest lie on further chips as pipeline stages), over how many
+What ONE CHIP holds of a model is :class:`Share`: the depth kept (a stage
+of layers, the leading ones unless it says where it starts; the rest lie on
+further chips as pipeline stages), over how many
 chips the routed experts of a layer are divided, and over how many the heads
 and conv channels and a linear-attention layer's key heads with their
 value heads (``tensor_shards``), the vocabulary (``vocab_shards``), a
@@ -63,7 +75,7 @@ state-space heads' part of the mixer's output, its columns' part of a dense
 MLP's, logits over its vocabulary rows. Nothing here stands in for the absent
 chips or their traffic; sums over all shares, the shared expert counted once,
 give the uncut layer (tests/test_decoder.py, tests/test_decoder_lfm2.py,
-tests/test_decoder_falcon_h1.py).
+tests/test_decoder_falcon_h1.py, tests/test_decoder_phi4_flash.py).
 
 Laguna's config is silent on five things, set by the Qwen-MoE family's
 convention (whose keys it uses) and listed as ``assumed`` in
@@ -122,6 +134,17 @@ file is silent on, read as keys as Keye's ``qk_norm`` is:
 model's). **``A_log`` and ``dt_bias`` are drawn as Mamba-2 draws them** and
 stay float32, as Falcon-H1's do; the multi-token-prediction module is left
 out (no key of the row states it).
+Phi-4-mini-flash's row gives the keys and not the equations, which are the
+SambaY family's published description and modelling code, listed in
+``benchmarks/reference/phi4_flash.py`` (``ASSUMED``) and in the
+configuration's file: the index rule of the layer kinds, the Mamba-1
+mixer's sizes (stated as ``mamba_d_state``, ``mamba_d_conv``,
+``mamba_expand``, ``mamba_dt_rank``, the family's defaults, with the scan's
+``mamba_chunk_size``), the memory as the scan's output after D's skip and
+before the z gate, and differential attention's pairs, norm and window
+(``differential_attention``); every norm a LayerNorm with a bias, no
+rotary position. **``A_log`` is S4D-real's ``log(1 .. state)``** and stays
+float32 with ``dt_bias``, ``D`` and the four lambda vectors.
 """
 from __future__ import annotations
 
@@ -544,6 +567,71 @@ CONFIGS = {
         "vocab_size": 64,
         "zero_centered_norm": True,
     },
+    # Phi-4-mini-flash-reasoning's config.json, key for key (the catalog's
+    # row); ``layer_types`` follows from ``mb_per_layer`` (:func:`held_config`)
+    "phi4_mini_flash": {
+        # ``differential_attention`` and the five ``mamba_*`` keys are no
+        # keys of the row, which is silent on them: stated here as Keye's
+        # ``qk_norm`` is (SambaY's attention is differential; the Mamba
+        # mixer's sizes are the family's configuration defaults: state 16,
+        # conv 4, expansion 2, dt rank ceil(hidden / 16)) and, for
+        # ``mamba_chunk_size``, the scan's chunk: the algorithm's, not the
+        # model's
+        "differential_attention": True,
+        "embd_pdrop": 0,
+        "hidden_act": "silu",
+        "hidden_size": 2560,
+        "intermediate_size": 10240,
+        "layer_norm_eps": 1e-05,
+        "lm_head_bias": False,
+        "mamba_chunk_size": 64,
+        "mamba_d_conv": 4,
+        "mamba_d_state": 16,
+        "mamba_dt_rank": 160,
+        "mamba_expand": 2,
+        "max_position_embeddings": 262144,
+        "mb_per_layer": 2,
+        "mlp_bias": False,
+        "model_type": "phi4flash",
+        "num_attention_heads": 40,
+        "num_hidden_layers": 32,
+        "num_key_value_heads": 20,
+        "resid_pdrop": 0,
+        "sliding_window": 512,
+        "tie_word_embeddings": True,
+        "vocab_size": 200064,
+    },
+    # the same keys for the CPU tests: 24 layers by the published rule, so
+    # that layers 8-17 are the published stage's pattern (Mamba and window
+    # attention twice, the memory's Mamba, full attention, GMU and cross
+    # attention twice); 8 query and 4 KV heads of 8; a mixer of 1024 channels
+    # (expansion 16, so that the GMU's channels go 8 ways in whole tiles of
+    # 128), state 8, chunks of 8, window 8
+    "phi4_flash_tiny": {
+        "differential_attention": True,
+        "embd_pdrop": 0,
+        "hidden_act": "silu",
+        "hidden_size": 64,
+        "intermediate_size": 1024,
+        "layer_norm_eps": 1e-05,
+        "lm_head_bias": False,
+        "mamba_chunk_size": 8,
+        "mamba_d_conv": 4,
+        "mamba_d_state": 8,
+        "mamba_dt_rank": 4,
+        "mamba_expand": 16,
+        "max_position_embeddings": 256,
+        "mb_per_layer": 2,
+        "mlp_bias": False,
+        "model_type": "phi4flash",
+        "num_attention_heads": 8,
+        "num_hidden_layers": 24,
+        "num_key_value_heads": 4,
+        "resid_pdrop": 0,
+        "sliding_window": 8,
+        "tie_word_embeddings": True,
+        "vocab_size": 64,
+    },
 }
 # queries of a full layer are scored in blocks of this many, each against the
 # keys up to its own end, so the scores of a long sequence never exist whole
@@ -579,7 +667,15 @@ class Share:
     ``in_proj_qkvz`` and ``in_proj_ba`` grouped under them, their q, k and v
     channels of the taps, their value heads' ``A_log`` and ``dt_bias`` and
     ``out_proj``'s rows; the gated norm's one weight whole), so the scan is
-    local and its part of the output is a sum's term."""
+    local and its part of the output is a sum's term. A gated memory unit's
+    channels go as a dense MLP's columns do (``mlp_shards``): a chip holds
+    ``mamba_expand * hidden_size / mlp_shards`` columns of its ``in_proj``
+    and rows of its ``out_proj`` and reads the same channels of the memory
+    (those from ``index``'s on), which the Mamba mixer that writes it holds
+    whole: a Mamba-1 mixer is never divided (``x_proj`` sums over all its
+    channels before the softplus, B and C). The stage kept is the
+    ``layers`` layers from ``first_layer`` on (0: the leading ones); one
+    that leaves a kind of layer out is refused."""
     layers: int = 0
     expert_shards: int = 1
     tensor_shards: int = 1
@@ -587,6 +683,7 @@ class Share:
     vocab_shards: int = 0
     ssm_shards: int = 0
     mlp_shards: int = 0
+    first_layer: int = 0
 
 
 def _sparse(cfg: dict, layer: int) -> bool:
@@ -608,16 +705,46 @@ def least_layers(cfg: dict) -> int:
     """The fewest leading layers that are still the model of a config with
     ``layer_types``: its leading dense layers and one whole period of the
     kinds that follow (the shortest stretch that holds every kind of the
-    list and comes again right after itself, as far as the list goes)."""
+    list and comes again right after itself, as far as the list goes). A
+    list with no such period (a dense model's whose kinds change at its
+    middle) needs the leading layers up to the first of its last kind."""
     kinds = cfg["layer_types"]
     dense = next((i for i in range(len(kinds)) if _sparse(cfg, i)),
-                 len(kinds))
+                 len(kinds)) if "num_experts" in cfg else 0
     rest = kinds[dense:]
     for period in range(1, len(rest)):
         if set(rest[:period]) == set(kinds) and all(
                 a == b for a, b in zip(rest[:period], rest[period:])):
             return dense + period
-    return len(kinds)
+    return next(i + 1 for i in range(len(kinds))
+                if set(kinds[:i + 1]) == set(kinds))
+
+
+def sambay_layer_types(n: int, every: int) -> list:
+    """The kinds of a SambaY decoder of ``n`` layers (the rule of
+    Phi-4-mini-flash's modelling code, ``mb_per_layer`` ``every``): a layer
+    is a Mamba layer where ``i % every == 0`` and attention elsewhere; the
+    first half is the self-decoder (Mamba, window attention); layer ``n //
+    2`` is a Mamba layer whose scan output is the memory the later GMUs
+    read, layer ``n // 2 + 1`` full attention whose K and V the later cross
+    attention reads; after it the cross-decoder (a GMU where a Mamba layer
+    would stand, cross attention elsewhere)."""
+    half = n // 2
+    if half % every or (half + 1) % every == 0:
+        raise ValueError(f"{n} layers with mb_per_layer {every}: layer "
+                         f"{half} must be a Mamba layer and {half + 1} not")
+
+    def kind(i):
+        mamba = i % every == 0
+        if i < half:
+            return "mamba" if mamba else "sliding_attention"
+        if i == half:
+            return "mamba_memory"
+        if i == half + 1:
+            return "full_attention"
+        return "gmu" if mamba else "cross_attention"
+
+    return [kind(i) for i in range(n)]
 
 
 def held_config(name: str, share: Share = Share()) -> dict:
@@ -633,15 +760,23 @@ def held_config(name: str, share: Share = Share()) -> dict:
     stays as published. A config that gives ``full_attention_interval``
     and no ``layer_types`` gets its list here, once (transformers'
     ``Qwen3NextConfig``'s rule: layer ``i`` is full attention where ``i +
-    1`` is a multiple of the interval, linear attention elsewhere), so that
-    every later reader sees a list."""
+    1`` is a multiple of the interval, linear attention elsewhere), and one
+    that gives ``mb_per_layer`` gets SambaY's (:func:`sambay_layer_types`),
+    so that every later reader sees a list. The stage's first published
+    layer stands under ``first_layer``; where the model has gated memory
+    units, the channels a chip holds of each under ``gmu_channels`` and the
+    first of them under ``first_gmu_channel``."""
     cfg = dict(CONFIGS[name])
     if "full_attention_interval" in cfg and "layer_types" not in cfg:
         every = cfg["full_attention_interval"]
         cfg["layer_types"] = [
             "linear_attention" if (i + 1) % every else "full_attention"
             for i in range(cfg["num_hidden_layers"])]
-    n = share.layers or cfg["num_hidden_layers"]
+    if "mb_per_layer" in cfg and "layer_types" not in cfg:
+        cfg["layer_types"] = sambay_layer_types(cfg["num_hidden_layers"],
+                                                cfg["mb_per_layer"])
+    first = share.first_layer
+    n = share.layers or cfg["num_hidden_layers"] - first
     t, e = share.tensor_shards, share.expert_shards
     v, m = share.vocab_shards or t, share.ssm_shards or t
     routed = "num_experts" in cfg
@@ -680,13 +815,30 @@ def held_config(name: str, share: Share = Share()) -> dict:
                 f"{name}: intermediate_size {cfg['intermediate_size']} does "
                 f"not divide over {share.mlp_shards} chips in whole tiles "
                 "of 128 columns")
-    if not 0 < n <= cfg["num_hidden_layers"]:
-        raise ValueError(f"{name}: {n} layers of {cfg['num_hidden_layers']}")
-    least = least_layers(cfg) if "layer_types" in cfg else 1
+    gmu = "gmu" in cfg.get("layer_types", ())
+    gmu_width = cfg["mamba_expand"] * cfg["hidden_size"] if gmu else 0
+    if gmu and share.mlp_shards and gmu_width % (128 * share.mlp_shards):
+        raise ValueError(
+            f"{name}: the GMU's {gmu_width} channels do not divide over "
+            f"{share.mlp_shards} chips in whole tiles of 128")
+    if cfg.get("differential_attention") and any(
+            (cfg[key] // t) % 2 for key in ("num_attention_heads",
+                                            "num_key_value_heads")):
+        raise ValueError(f"{name}: differential attention's heads go in "
+                         f"pairs, which {t} chips would split")
+    if not (0 <= first and 0 < n and first + n <= cfg["num_hidden_layers"]):
+        raise ValueError(f"{name}: {n} layers of {cfg['num_hidden_layers']}"
+                         + (f" from layer {first}" if first else ""))
+    least = least_layers(cfg) if "layer_types" in cfg and not first else 1
     if n < least:
         raise ValueError(
             f"{name}: {n} layers cut a period of layer_types: the leading "
             f"dense layers and one whole period are {least}")
+    left_out = set(cfg.get("layer_types", ())) - set(
+        cfg.get("layer_types", ())[first:first + n])
+    if left_out:
+        raise ValueError(f"{name}: layers {first}-{first + n - 1} leave out "
+                         f"the kind(s) {sorted(left_out)}")
     cfg["published"] = {key: cfg[key] for key in cut}
     cfg.update(
         num_hidden_layers=n,
@@ -700,10 +852,18 @@ def held_config(name: str, share: Share = Share()) -> dict:
         cfg["num_local_experts"] = cfg["num_experts"]
     if "num_attention_heads_per_layer" in cfg:
         cfg["num_attention_heads_per_layer"] = [
-            h // t for h in cfg["num_attention_heads_per_layer"][:n]]
+            h // t for h in cfg["num_attention_heads_per_layer"][
+                first:first + n]]
     for key in ("layer_types", "mlp_layer_types", "gating_types"):
         if key in cfg:
-            cfg[key] = cfg[key][:n]
+            cfg[key] = cfg[key][first:first + n]
+    if first:
+        cfg["first_layer"] = first
+    if gmu:
+        parts = share.mlp_shards or 1
+        cfg["published"]["gmu_channels"] = gmu_width
+        cfg["gmu_channels"] = gmu_width // parts
+        cfg["first_gmu_channel"] = (share.index % parts) * cfg["gmu_channels"]
     if "conv_L_cache" in cfg:
         cfg["published"]["conv_channels"] = cfg["hidden_size"]
         cfg["conv_channels"] = cfg["hidden_size"] // t
@@ -1575,6 +1735,243 @@ class GatedDeltaNet(nn.Module):
                 self, "out_proj", (nv * dv, hidden))
 
 
+def scan_token(h, decay, inflow):
+    """One token of :func:`selective_scan`'s recurrence: the state decayed,
+    plus what the token adds. A function of its own so that a control can
+    change the state's precision without a second copy of the scan
+    (``benchmarks/tests/control_flash.py``)."""
+    return decay * h + inflow
+
+
+def selective_scan(u, delta, a, b, c, d_skip, chunk: int):
+    """Mamba-1's recurrence over a sequence, chunk by chunk: per channel
+    ``d`` and state ``n``, with the state zero before the sequence, ``h_t[d,
+    n] = exp(delta_t[d] a[d, n]) h_{t-1}[d, n] + delta_t[d] b_t[n] u_t[d]``
+    and ``y_t[d] = sum_n h_t[d, n] c_t[n] + d_skip[d] u_t[d]``. ``u, delta
+    [B, S, D]``, ``a [D, N]`` (negative), ``b, c [B, S, N]``, ``d_skip
+    [D]``; all of it float32. Returns ``(y [B, S, D]`` float32, ``keep [B,
+    chunks]``), the second the mean over channels and states of ``exp(a sum
+    over the chunk of delta)``, the share of a chunk's entering state that
+    survives it.
+
+    The decay differs per channel and per state, so there is no matrix form
+    (no state-space duality): it is vector work, sequential in time. A
+    ``lax.scan`` over chunks of ``chunk`` tokens carries the state ``[B, N,
+    D]`` (channels on the lanes); inside a chunk a ``lax.scan`` over its
+    tokens, a multiply-add a token. Each chunk is computed again going
+    backward, so that of the per-token states ([B, S, N, D]: 5.4 GB a layer
+    at the published widths and 16,384 tokens) only the states entering the
+    chunks are kept. (The tokens of a chunk as ``lax.associative_scan``
+    instead took 638 ms for 39 a layer's forward and backward at chunk 128
+    and 390 for 43 at chunk 64: PERF.md section 5.) A sequence the chunk
+    does not divide is padded with tokens of ``delta`` 0, which neither
+    decay nor add."""
+    bsz, s_len, channels = u.shape
+    pad = -s_len % chunk
+    if pad:
+        u, delta, b, c = (jnp.pad(t, [(0, 0), (0, pad), (0, 0)])
+                          for t in (u, delta, b, c))
+    nc = (s_len + pad) // chunk
+    a_t = a.T                                               # [N, D]
+
+    def tokens_of_chunks(t):        # [B, S, ...] -> [chunks, chunk, B, ...]
+        return jnp.moveaxis(t.reshape((bsz, nc, chunk) + t.shape[2:]),
+                            (1, 2), (0, 1))
+
+    def token(h, t):
+        u_t, dt_t, b_t, c_t = t
+        h = scan_token(h, jnp.exp(dt_t[:, None, :] * a_t),
+                       (dt_t * u_t)[:, None, :] * b_t[..., None])
+        return h, jnp.sum(h * c_t[..., None], axis=1)
+
+    @jax.checkpoint
+    def one(h, xs):
+        return jax.lax.scan(token, h, xs)
+
+    _, y = jax.lax.scan(one, jnp.zeros((bsz,) + a_t.shape, jnp.float32),
+                        tuple(tokens_of_chunks(t) for t in (u, delta, b, c)))
+    y = jnp.moveaxis(y, 2, 0).reshape(bsz, nc * chunk, channels)[:, :s_len]
+    keep = jnp.mean(jnp.exp(
+        jnp.sum(delta.reshape(bsz, nc, chunk, channels), axis=2)[..., None]
+        * a), axis=(-2, -1))
+    return y + d_skip * u[:, :s_len], keep
+
+
+def _s4d_real(key, shape, dtype=jnp.float32):
+    """S4D-real's ``A_log [D, N]``: ``log(1 .. N)`` for every channel."""
+    del key
+    return jnp.broadcast_to(jnp.log(jnp.arange(1, shape[1] + 1, dtype=dtype)),
+                            shape)
+
+
+class Mamba(nn.Module):
+    """One Mamba-1 mixer, held whole: ``[u, z] = x W_in`` of ``channels``
+    each; ``u = silu(conv(u) + b_conv)``, a causal depthwise convolution of
+    ``taps`` taps (:func:`short_conv`); ``[delta, B, C] = u W_x`` (``rank``,
+    ``state``, ``state`` columns); ``delta = softplus(delta W_dt + dt_bias)``,
+    ``A = -exp(A_log) [channels, state]``; the recurrence
+    (:func:`selective_scan`, chunks of ``chunk``) with ``D``'s skip gives
+    ``y``; the output is ``(y * silu(z)) W_out``. Returns the output and
+    ``y`` (the memory a later GMU reads, where the layer is the memory's) in
+    the input's type. The products run in the input's type; the conv, the
+    decays, ``delta`` and the state are float32. ``A_log``, ``dt_bias`` and
+    ``D`` stay float32 beside a compute copy (``Decoder.float32_leaves``)
+    and are drawn so that states outlive a chunk: ``A_log`` S4D-real's
+    ``log(1 .. state)``, ``delta`` log-uniform in [1e-3, 1e-1] through the
+    inverse softplus, ``D`` ones; the taps and their bias uniform in +-1/2."""
+    channels: int
+    state: int
+    taps: int
+    rank: int
+    chunk: int
+
+    @nn.compact
+    def __call__(self, x):
+        bsz, s_len, hidden = x.shape
+        d, n, r = self.channels, self.state, self.rank
+        f32 = jnp.float32
+        with jax.named_scope("mamba"):
+            u, z = jnp.split(x @ _weight(self, "in_proj", (hidden, 2 * d)),
+                             2, axis=-1)
+            taps = self.param("conv", _uniform(-0.5, 0.5), (d, self.taps))
+            bias = self.param("conv_bias", _uniform(-0.5, 0.5), (d,))
+            u = jax.nn.silu(short_conv(u.astype(f32), taps.astype(f32))
+                            + bias.astype(f32)).astype(x.dtype)
+            delta, b, c = jnp.split(jnp.dot(
+                u, _weight(self, "x_proj", (d, r + 2 * n)),
+                preferred_element_type=f32), [r, r + n], axis=-1)
+            with jax.named_scope("scan"):
+                delta = jax.nn.softplus(jnp.dot(
+                    delta.astype(x.dtype), _weight(self, "dt_proj", (r, d)),
+                    preferred_element_type=f32) + self.param(
+                        "dt_bias", _dt_bias_init, (d,)).astype(f32))
+                a = -jnp.exp(self.param("A_log", _s4d_real, (d, n)).astype(
+                    f32))
+                y, keep = selective_scan(
+                    u.astype(f32), delta, a, b, c,
+                    self.param("D", nn.initializers.ones, (d,)).astype(f32),
+                    self.chunk)
+            # free unless the caller opens the collection
+            # (obs/mamba_carry.py)
+            if self.is_mutable_collection(EXPERT_STATS):
+                self.sow(EXPERT_STATS, "mamba_chunk_keep", keep)
+            out = (y * jax.nn.silu(z.astype(f32))).astype(x.dtype) @ _weight(
+                self, "out_proj", (d, hidden))
+            return out, y.astype(x.dtype)
+
+
+class GatedMemory(nn.Module):
+    """A gated memory unit's held channels' part of its output: ``(m *
+    silu(x W_1)) W_2`` over the ``channels`` columns of ``W_1`` held, ``m``
+    the same channels (from ``first`` on) of the memory an earlier Mamba
+    layer wrote; the gate in float32."""
+    channels: int
+    first: int
+
+    @nn.compact
+    def __call__(self, x, memory):
+        hidden = x.shape[-1]
+        with jax.named_scope("gmu"):
+            gate = jnp.dot(
+                x, _weight(self, "in_proj", (hidden, self.channels)),
+                preferred_element_type=jnp.float32)
+            held = memory[..., self.first:self.first + self.channels]
+            return (held.astype(jnp.float32) * jax.nn.silu(gate)).astype(
+                x.dtype) @ _weight(self, "out_proj", (self.channels, hidden))
+
+
+def lambda_init(layer: int) -> float:
+    """Differential attention's ``0.8 - 0.6 exp(-0.3 layer)``, of the
+    layer's published index."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer)
+
+
+def differential_maps(q, k, v, lam, window: int, kind: str):
+    """``A1 v - lam A2 v`` ``[B, S, P, g, 2d]`` float32 for query pairs ``q
+    [B, S, P, g, 2, d]`` (any type; ``g`` pairs to a KV pair) over key pairs
+    ``k [B, S, P, 2, d]`` and their values ``v [B, S, P, 2d]`` (the pair's
+    two heads side by side), where map ``i`` is the causal softmax of
+    ``q[.., i, :] . k[.., i, :] / sqrt(d)`` under the positions' rule
+    ``window`` (0: causal). Each map is ONE ``ruled_attention`` product, so
+    it reaches the flash kernels where they take the operands: q and k
+    zero-padded from ``d`` to ``2d`` lanes (zero features add nothing to a
+    score), q times ``sqrt(2)`` (in float32, then rounded once to ``v``'s
+    type) so that the product's scale ``(2d)^-1/2`` is the published
+    ``d^-1/2``."""
+    d = q.shape[-1]
+    q = (q.astype(jnp.float32) * math.sqrt(2)).astype(v.dtype)
+
+    def padded(t):
+        return jnp.pad(t, [(0, 0)] * (t.ndim - 1) + [(0, d)])
+
+    first, second = (
+        ruled_attention(padded(q[..., i, :]), padded(k[..., i, :]), v, window,
+                        _attend_positions, kind).astype(jnp.float32)
+        for i in (0, 1))
+    return first - lam * second
+
+
+class DiffAttention(nn.Module):
+    """The held heads' part of one differential attention layer's output
+    (SambaY's): query heads pair up (2p, 2p + 1), and so do KV heads; query
+    pair ``p`` of ``q_heads / 2`` reads KV pair ``p // (q_heads /
+    kv_heads)``. Its output ``(A1 - lam A2) [v_2j | v_2j+1]``
+    (:func:`differential_maps`) goes through an RMSNorm over the pair's
+    ``2 * head_dim`` channels (one learned weight) times ``1 -
+    lambda_init(layer)``, then ``W_o``; ``lam = exp(lq1 . lk1) - exp(lq2 .
+    lk2) + lambda_init(layer)`` from four learned float32 vectors of
+    ``head_dim``, drawn normal(0, 0.1). No bias, no rotary. ``kind``:
+    ``sliding_attention`` (the keys ``i - window < j <= i``),
+    ``full_attention`` (causal; its k and v are returned, for later cross
+    attention) or ``cross_attention`` (only ``q = x W_q``, causal over the
+    ``kv`` given)."""
+    kind: str
+    q_heads: int
+    kv_heads: int
+    head_dim: int
+    window: int
+    layer: int      # the published index: it sets lambda_init
+    eps: float
+
+    @nn.compact
+    def __call__(self, x, kv=None):
+        b, s_len, hidden = x.shape
+        d, pairs = self.head_dim, self.kv_heads // 2
+        group = self.q_heads // self.kv_heads
+        start = lambda_init(self.layer)
+
+        def lam_vector(name):
+            return self.param(name, nn.initializers.normal(0.1), (d,)).astype(
+                jnp.float32)
+
+        with jax.named_scope("attention"):
+            q = jnp.dot(x, _weight(self, "q_proj", (hidden, self.q_heads * d)),
+                        preferred_element_type=jnp.float32)
+            if self.kind != "cross_attention":
+                kv = tuple(
+                    (x @ _weight(self, name, (hidden, self.kv_heads * d))
+                     ).reshape(b, s_len, self.kv_heads, d)
+                    for name in ("k_proj", "v_proj"))
+            lam = jnp.exp(jnp.dot(lam_vector("lambda_q1"),
+                                  lam_vector("lambda_k1"))) - jnp.exp(
+                jnp.dot(lam_vector("lambda_q2"), lam_vector("lambda_k2"))) \
+                + start
+            k, v = kv
+            scope, window = {"sliding_attention": ("window", self.window),
+                             "full_attention": ("full", 0),
+                             "cross_attention": ("cross", 0)}[self.kind]
+            with jax.named_scope(scope):
+                out = differential_maps(
+                    q.reshape(b, s_len, pairs, group, 2, d),
+                    k.reshape(b, s_len, pairs, 2, d),
+                    v.reshape(b, s_len, pairs, 2 * d), lam, window, scope)
+            out = rms_norm(out, _norm_weight(self, "subln", 2 * d),
+                           self.eps) * (1 - start)
+            out = out.reshape(b, s_len, -1).astype(x.dtype) @ _weight(
+                self, "o_proj", (self.q_heads * d, hidden))
+            return out, (kv if self.kind == "full_attention" else None)
+
+
 class SwiGLU(nn.Module):
     """``(silu((x W_gate) * m[0]) * (x W_up)) W_down * m[1]`` over the
     ``width`` columns held (all of them, or a chip's share of a dense MLP's:
@@ -1859,8 +2256,12 @@ class SparseMLP(nn.Module):
 
 
 def _norm_eps(cfg: dict) -> float:
-    """The RMSNorms' epsilon: ``rms_norm_eps``, or LFM2's ``norm_eps``."""
-    return cfg["rms_norm_eps"] if "rms_norm_eps" in cfg else cfg["norm_eps"]
+    """The norms' epsilon: ``rms_norm_eps``, LFM2's ``norm_eps`` or, where
+    every norm is a LayerNorm, ``layer_norm_eps``."""
+    for key in ("rms_norm_eps", "norm_eps"):
+        if key in cfg:
+            return cfg[key]
+    return cfg["layer_norm_eps"]
 
 
 def layer_plan(cfg: dict, layer: int) -> dict:
@@ -1891,7 +2292,12 @@ def layer_plan(cfg: dict, layer: int) -> dict:
     the twelve muP constants, each read as a key that defaults to 1:
     ``embedding``, ``lm_head``, ``attention_in``, ``attention_out``, ``key``,
     ``ssm_in``, ``ssm_out`` (``<name>_multiplier``), the five
-    ``ssm_multipliers`` and the two ``mlp_multipliers``."""
+    ``ssm_multipliers`` and the two ``mlp_multipliers``. ``rope``: None
+    for a config with no rotary key (SambaY: no positions at all);
+    ``layer_norm``: every norm a LayerNorm with a bias (a config with
+    ``layer_norm_eps``); ``differential``: every attention layer is
+    differential (``differential_attention``); ``published_layer``: the
+    layer's index in the published model (``first_layer`` + ``layer``)."""
     n = cfg["num_hidden_layers"]
     kind = cfg["layer_types"][layer] if "layer_types" in cfg else (
         "selected_attention" if cfg.get("sa_config") else "full_attention")
@@ -1912,13 +2318,16 @@ def layer_plan(cfg: dict, layer: int) -> dict:
         else {"rope_theta": cfg["rope_theta"], "rope_type": "default",
               **(cfg.get("rope_scaling") or {}),
               **{key: cfg[key] for key in ("partial_rotary_factor",)
-                 if key in cfg}},
+                 if key in cfg}} if "rope_theta" in cfg else None,
         "gate": cfg.get("gating_types", [None] * n)[layer] == "per_head",
         "elementwise_gate": bool(cfg.get("attn_output_gate")),
         "qk_norm": cfg.get("qk_norm", False),
         "centered": bool(cfg.get("zero_centered_norm")),
         "shared_gate": bool(cfg.get("shared_expert_gate")),
         "eps": _norm_eps(cfg),
+        "layer_norm": "layer_norm_eps" in cfg,
+        "differential": bool(cfg.get("differential_attention")),
+        "published_layer": cfg.get("first_layer", 0) + layer,
         "scale": cfg.get("moe_routed_scaling_factor",
                          cfg.get("routed_scaling_factor", 1)),
         "score": score,
@@ -1931,20 +2340,59 @@ def layer_plan(cfg: dict, layer: int) -> dict:
             "mlp": tuple(cfg.get("mlp_multipliers", (1, 1)))}}
 
 
+def _block_norm(module, x, name: str, plan: dict):
+    """The norm ``name`` of ``module`` (a block, or the decoder's final
+    one): a LayerNorm with a bias (a config with ``layer_norm_eps``) or an
+    RMSNorm."""
+    w = _norm_weight(module, name, x.shape[-1], plan["centered"])
+    if plan["layer_norm"]:
+        return layer_norm(x, w, module.param(
+            name + "_bias", nn.initializers.zeros, (x.shape[-1],)),
+            plan["eps"])
+    return rms_norm(x, w, plan["eps"])
+
+
 class Block(nn.Module):
+    """One decoder block. ``shared`` (SambaY's cross-layer state, a dict,
+    or None for a model with none) holds what earlier blocks left for later
+    ones: the memory a ``mamba_memory`` layer writes (``"memory"``, its
+    scan's output) and the K and V a ``full_attention`` layer computes
+    (``"kv"``); a ``gmu`` layer reads the first, a ``cross_attention``
+    layer the second. Returns the output and, given ``shared``, ``shared``
+    with what this block adds."""
     cfg: Tuple      # held_config(...) as nested sorted items (hashable)
     layer: int
 
     @nn.compact
-    def __call__(self, x, positions=None):
+    def __call__(self, x, positions=None, shared=None):
         cfg = _thaw(self.cfg)
         plan = layer_plan(cfg, self.layer)
-        eps, hidden = plan["eps"], x.shape[-1]
+        eps = plan["eps"]
         mult = plan["multipliers"]
         # the norm before the operator keeps its name whatever the operator
-        h = rms_norm(x, _norm_weight(self, "attn_norm", hidden,
-                                     plan["centered"]), eps)
-        if plan["kind"] == "conv":
+        h = _block_norm(self, x, "attn_norm", plan)
+        if plan["kind"] in ("mamba", "mamba_memory"):
+            mixed, memory = Mamba(
+                cfg["mamba_expand"] * cfg["hidden_size"],
+                cfg["mamba_d_state"], cfg["mamba_d_conv"],
+                cfg["mamba_dt_rank"], cfg["mamba_chunk_size"],
+                name="mamba")(h)
+            x = x + mixed
+            if plan["kind"] == "mamba_memory":
+                shared = dict(shared, memory=memory)
+        elif plan["kind"] == "gmu":
+            x = x + GatedMemory(cfg["gmu_channels"], cfg["first_gmu_channel"],
+                                name="gmu")(h, shared["memory"])
+        elif plan["differential"]:
+            mixed, kv = DiffAttention(
+                plan["kind"], plan["heads"], cfg["num_key_value_heads"],
+                plan["head_dim"], cfg.get("sliding_window") or 0,
+                plan["published_layer"], eps, name="attention")(
+                    h, shared.get("kv"))
+            x = x + mixed
+            if kv is not None:
+                shared = dict(shared, kv=kv)
+        elif plan["kind"] == "conv":
             x = x + ShortConv(cfg["conv_channels"], cfg["conv_L_cache"],
                               name="conv")(h)
         elif plan["kind"] == "linear_attention":
@@ -1971,21 +2419,22 @@ class Block(nn.Module):
                     mult["ssm_in"], mult["ssm"], name="ssm")(h),
                     mult["ssm_out"])
             x = x + mixed
-        h = rms_norm(x, _norm_weight(self, "mlp_norm", hidden,
-                                     plan["centered"]), eps)
+        h = _block_norm(self, x, "mlp_norm", plan)
         if not plan["sparse"]:
             with jax.named_scope("dense_mlp"):
-                return x + SwiGLU(
+                x = x + SwiGLU(
                     cfg.get("mlp_columns", cfg["intermediate_size"]),
                     mult["mlp"], name="mlp")(h)
-        return x + SparseMLP(
-            cfg["published"]["num_experts"], cfg["num_experts_per_tok"],
-            cfg["norm_topk_prob"], plan["scale"],
-            cfg["first_expert"], cfg["num_experts"],
-            cfg["moe_intermediate_size"],
-            cfg.get("shared_expert_intermediate_size", 0),
-            score=plan["score"], shared_gate=plan["shared_gate"],
-            name="mlp")(h)
+        else:
+            x = x + SparseMLP(
+                cfg["published"]["num_experts"], cfg["num_experts_per_tok"],
+                cfg["norm_topk_prob"], plan["scale"],
+                cfg["first_expert"], cfg["num_experts"],
+                cfg["moe_intermediate_size"],
+                cfg.get("shared_expert_intermediate_size", 0),
+                score=plan["score"], shared_gate=plan["shared_gate"],
+                name="mlp")(h)
+        return x if shared is None else (x, shared)
 
 
 class Decoder(nn.Module):
@@ -2008,8 +2457,11 @@ class Decoder(nn.Module):
     # (``models.make_apply_fn``): the selection bias is added to float32
     # scores and decides a choice, so it is not rounded with the matrices
     # nor are a state-space mixer's ``A_log``, ``dt_bias`` and ``D``: they
-    # set decays that multiply up over a chunk's tokens
-    float32_leaves = ("expert_bias", "A_log", "dt_bias", "D")
+    # set decays that multiply up over a chunk's tokens; nor differential
+    # attention's four lambda vectors, whose products' exponentials set the
+    # weight of the second map
+    float32_leaves = ("expert_bias", "A_log", "dt_bias", "D", "lambda_q1",
+                      "lambda_k1", "lambda_q2", "lambda_k2")
 
     @property
     def tpu_compiler_options(self) -> dict:
@@ -2030,12 +2482,14 @@ class Decoder(nn.Module):
     def __call__(self, tokens, train: bool = False, positions=None):
         cfg = _thaw(self.cfg)
         hidden, vocab = cfg["hidden_size"], cfg["vocab_size"]
-        mult = layer_plan(cfg, 0)["multipliers"]
+        plan = layer_plan(cfg, 0)
+        mult = plan["multipliers"]
         with jax.named_scope("embed"):
             embed = _weight(self, "embed", (vocab, hidden))
             x = _times(embedding.lookup(embed, tokens),
                        mult["embedding"])
-        if positions is None and "rope_parameters" not in cfg:
+        if positions is None and plan["rope"] is not None and \
+                "rope_parameters" not in cfg:
             # text: every stream counts the tokens (three where the config
             # divides the frequency pairs into sections, else one). Given
             # as streams the compiler may not fold, the rotary tables are
@@ -2056,18 +2510,25 @@ class Decoder(nn.Module):
         # a layer: it neither scores nor chooses again) and the product's
         # output; a model whose heads the kernels do not take keeps
         # nothing, and its blocked scores are computed again as before
+        # (differential attention's products are as wide as two heads)
         names = ()
         if cfg.get("sa_config"):
             names = ("selected_keys",) + SAVED_NAMES
-        elif kernels_take(layer_plan(cfg, 0)["head_dim"]):
+        elif kernels_take(plan["head_dim"] * (1 + plan["differential"])):
             names = SAVED_NAMES + OPERAND_NAMES
         block = nn.remat(Block, policy=jax.checkpoint_policies
                          .save_only_these_names(*names) if names else None)
+        # SambaY's cross-layer state: the memory and the K and V that later
+        # layers read, carried from block to block (and, going backward,
+        # the gradients of every reader back into the layer that wrote it)
+        shared = {} if "mb_per_layer" in cfg else None
         for i in range(cfg["num_hidden_layers"]):
-            x = block(self.cfg, i, name=f"layers_{i}")(x, *where)
-        x = rms_norm(x, _norm_weight(self, "final_norm", hidden,
-                                     bool(cfg.get("zero_centered_norm"))),
-                     _norm_eps(cfg))
+            if shared is None:
+                x = block(self.cfg, i, name=f"layers_{i}")(x, *where)
+            else:
+                x, shared = block(self.cfg, i, name=f"layers_{i}")(
+                    x, positions, shared)
+        x = _block_norm(self, x, "final_norm", plan)
         with jax.named_scope("lm_head"):
             if cfg.get("tie_word_embeddings"):
                 # the embedding is the head: one leaf, both uses in its

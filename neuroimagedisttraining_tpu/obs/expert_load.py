@@ -8,7 +8,8 @@ all free unless a caller opens the collection. :func:`record_expert_load`
 runs one forward of a batch with it open and sets three gauges in a metrics
 registry (and a fourth where a layer selects its keys: :mod:`.selection`;
 two more where a layer has a state-space mixer: :mod:`.ssm_carry`; one more
-where a layer is linear attention: :mod:`.linear_carry`):
+where a layer is linear attention: :mod:`.linear_carry`; one more where a
+layer has a Mamba-1 mixer: :mod:`.mamba_carry`):
 
 ``expert_load_max_over_mean``
     the fullest held expert's slots over the mean of the held experts',
@@ -68,10 +69,12 @@ def record_expert_load(algo, params, registry) -> dict:
     ``selected_key_share`` (:mod:`.selection`); where one has a state-space
     mixer, ``ssm_chunk_carry`` and ``ssm_dt_mean`` (:mod:`.ssm_carry`);
     where one is linear attention, ``linear_chunk_carry``
-    (:mod:`.linear_carry`). ``{}`` and no gauge for a model that sows nothing. Returns what it
-    set."""
+    (:mod:`.linear_carry`); where one has a Mamba-1 mixer,
+    ``mamba_chunk_carry`` (:mod:`.mamba_carry`). ``{}`` and no gauge for a
+    model that sows nothing. Returns what it set."""
     import jax
 
+    from . import mamba_carry
     from .linear_carry import carry_share, set_linear_carry
     from .selection import (key_share, set_selected_key_share,
                             stacked_selection)
@@ -83,14 +86,15 @@ def record_expert_load(algo, params, registry) -> dict:
         kept = stacked_selection(sown)
         return (stacked_stats(sown),
                 None if kept is None else key_share(kept), carry_stats(sown),
-                carry_share(sown))
+                carry_share(sown), mamba_carry.carry_share(sown))
 
     x = algo.data.x_train[0, :algo.hp.batch_size]
-    stats, share, carry, linear = jax.jit(gauges)(params, x)
+    stats, share, carry, linear, mamba = jax.jit(gauges)(params, x)
     return {**set_expert_load(stats, registry),
             **set_selected_key_share(share, registry),
             **set_ssm_carry(carry, registry),
-            **set_linear_carry(linear, registry)}
+            **set_linear_carry(linear, registry),
+            **mamba_carry.set_mamba_carry(mamba, registry)}
 
 
 def set_expert_load(stats: dict, registry) -> dict:

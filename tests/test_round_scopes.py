@@ -215,10 +215,13 @@ def test_folding_round_of_the_decoder_holds_its_scopes():
             layers = args.get("layers", {})
             if isinstance(layers, dict):    # row -> the scope it is timed under
                 asked.update(layers.values())
+    # a selecting layer's scopes and cross attention's are other models'
+    # (their own tests hold them)
+    others = set(SELECTING_SCOPES) | {"attention/cross"}
     assert asked == {"attention", "attention/full", "attention/window",
                      "router", "experts", "lm_head", "dense_mlp",
-                     "shared_expert", "embed"} | set(SELECTING_SCOPES)
-    for scope in asked - set(SELECTING_SCOPES):
+                     "shared_expert", "embed"} | others
+    for scope in asked - others:
         assert count(names, scope) > 0, scope
 
 
@@ -468,6 +471,69 @@ def test_folding_round_of_the_linear_attention_decoder_holds_its_scopes(
     from neuroimagedisttraining_tpu.algorithms import base
     assert "linear_attention (a Gated DeltaNet layer)" in inspect.getsource(
         base)
+
+
+def test_folding_round_of_the_sambay_decoder_holds_its_scopes():
+    """The tiny SambaY decoder (Mamba-1 mixers, differential attention in
+    window, full and cross form, gated memory units), compiled round:
+    ``mamba`` around the whole mixer with ``scan`` inside it, ``gmu``,
+    ``attention`` with ``window``, ``full`` and ``cross`` inside it, in both
+    passes (the scopes the new metric files ask for by name, and the
+    accepted attention metrics read in this model too), the dense MLP, and
+    none of the scopes of layers this model has none of; the gauge the
+    ``mamba_chunk_carry`` file names is the one the program sets."""
+    import json
+    import os
+
+    from neuroimagedisttraining_tpu.data.tokens import make_token_shards
+    from neuroimagedisttraining_tpu.models import decoder
+    from neuroimagedisttraining_tpu.obs import mamba_carry
+
+    share = decoder.Share(layers=10, tensor_shards=2, vocab_shards=4,
+                          mlp_shards=8, first_layer=8)
+    data = make_token_shards(0, n_clients=4, vocab=16, sequence_length=32,
+                             train_per_client=1)
+    hp = HyperParams(lr=0.05, local_epochs=1, steps_per_epoch=1,
+                     batch_size=1)
+    algo = FedAvg(decoder.decoder("phi4_flash_tiny", share), data, hp,
+                  loss_type="token_ce", frac=0.5, seed=3, client_chunk=1,
+                  track_personal=False)
+    state = algo.init_state(jax.random.PRNGKey(3))
+    compiled = algo._round_jit.lower(
+        state, jnp.arange(2, dtype=jnp.int32), jnp.asarray(0, jnp.float32),
+        data.x_train, data.y_train, data.n_train).compile()
+    names = op_names(compiled.as_text())
+    for scope in ("local_train", "aggregate"):
+        assert count(names, scope) > 0, scope
+    new = ("mamba", "mamba/scan", "gmu", "attention/cross")
+    for scope in ("embed", "attention", "attention/window", "attention/full",
+                  "dense_mlp", "lm_head") + new:
+        assert count(names, scope, "fwd") > 0, (scope, "forward")
+        assert count(names, scope, "bwd") > 0, (scope, "backward")
+    assert count(names, "scan") == count(names, "mamba/scan")
+    assert any("while" in n for n in names if scopes.under(n, "mamba/scan"))
+    for scope in ("ssm", "linear_attention", "short_conv", "router",
+                  "experts", "shared_expert", "attention/indexer"):
+        assert count(names, scope) == 0, scope
+    metrics = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "metrics")
+    asked = set()
+    for name in ("mamba_ms_per_round", "mamba_scan_ms_per_round",
+                 "mamba_scan_roofline", "gmu_ms_per_round",
+                 "attention_cross_ms_per_round"):
+        with open(os.path.join(metrics, name + ".json")) as f:
+            args = json.load(f)["args"]
+        asked |= {args["scope"]} | set(args.get("layers", {}).values())
+    assert asked == set(new)
+    with open(os.path.join(metrics, "mamba_chunk_carry.json")) as f:
+        assert json.load(f)["args"]["gauge"] == mamba_carry.GAUGE
+    # and the table of scopes names them in its one place
+    import inspect
+
+    from neuroimagedisttraining_tpu.algorithms import base
+    source = inspect.getsource(base)
+    assert "mamba (a Mamba-1 mixer) with scan" in source
+    assert "gmu (a gated memory unit)" in source
 
 
 @pytest.mark.parametrize("widest,spelling,widths", [
