@@ -160,6 +160,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import delta_rule, embedding, grouped_mlp
+from ..ops import selective_scan as selective_scan_op
 from ..ops.index_select import index_select
 from ..ops.masked_attention import (
     OPERAND_NAMES,
@@ -1752,20 +1753,40 @@ def selective_scan(u, delta, a, b, c, d_skip, chunk: int):
     [D]``; all of it float32. Returns ``(y [B, S, D]`` float32, ``keep [B,
     chunks]``), the second the mean over channels and states of ``exp(a sum
     over the chunk of delta)``, the share of a chunk's entering state that
-    survives it.
+    survives it, computed here from ``delta``.
+
+    The recurrence is ``ops/selective_scan.selective_scan``: Pallas kernels
+    on one TPU where the channels are a multiple of 128, the state of 8 and
+    the chunk of 8, and elsewhere :func:`_selective_scan_chunks`,
+    differentiated by JAX; D's skip is added here."""
+    y = selective_scan_op.selective_scan(u, delta, a, b, c,
+                                         _selective_scan_chunks, chunk)
+    bsz, s_len, channels = u.shape
+    nc = -(-s_len // chunk)
+    delta = jnp.pad(delta, [(0, 0), (0, nc * chunk - s_len), (0, 0)])
+    keep = jnp.mean(jnp.exp(
+        jnp.sum(delta.reshape(bsz, nc, chunk, channels), axis=2)[..., None]
+        * a), axis=(-2, -1))
+    return y + d_skip * u, keep
+
+
+def _selective_scan_chunks(u, delta, a, b, c, chunk: int):
+    """XLA's spelling of :func:`selective_scan`'s recurrence without D's
+    skip: ``(y [B, S, D], states [B, chunks, N, D])``, the second the state
+    entering every chunk.
 
     The decay differs per channel and per state, so there is no matrix form
     (no state-space duality): it is vector work, sequential in time. A
     ``lax.scan`` over chunks of ``chunk`` tokens carries the state ``[B, N,
     D]`` (channels on the lanes); inside a chunk a ``lax.scan`` over its
-    tokens, a multiply-add a token. Each chunk is computed again going
-    backward, so that of the per-token states ([B, S, N, D]: 5.4 GB a layer
-    at the published widths and 16,384 tokens) only the states entering the
-    chunks are kept. (The tokens of a chunk as ``lax.associative_scan``
-    instead took 638 ms for 39 a layer's forward and backward at chunk 128
-    and 390 for 43 at chunk 64: PERF.md section 5.) A sequence the chunk
-    does not divide is padded with tokens of ``delta`` 0, which neither
-    decay nor add."""
+    tokens, a multiply-add a token (:func:`scan_token`). Each chunk is
+    computed again going backward, so that of the per-token states ([B, S,
+    N, D]: 5.4 GB a layer at the published widths and 16,384 tokens) only the
+    states entering the chunks are kept. (The tokens of a chunk as
+    ``lax.associative_scan`` instead took 638 ms for 39 a layer's forward and
+    backward at chunk 128 and 390 for 43 at chunk 64: PERF.md section 5.) A
+    sequence the chunk does not divide is padded with tokens of ``delta`` 0,
+    which neither decay nor add."""
     bsz, s_len, channels = u.shape
     pad = -s_len % chunk
     if pad:
@@ -1786,15 +1807,14 @@ def selective_scan(u, delta, a, b, c, d_skip, chunk: int):
 
     @jax.checkpoint
     def one(h, xs):
-        return jax.lax.scan(token, h, xs)
+        leaving, y = jax.lax.scan(token, h, xs)
+        return leaving, (y, h)
 
-    _, y = jax.lax.scan(one, jnp.zeros((bsz,) + a_t.shape, jnp.float32),
-                        tuple(tokens_of_chunks(t) for t in (u, delta, b, c)))
+    _, (y, states) = jax.lax.scan(
+        one, jnp.zeros((bsz,) + a_t.shape, jnp.float32),
+        tuple(tokens_of_chunks(t) for t in (u, delta, b, c)))
     y = jnp.moveaxis(y, 2, 0).reshape(bsz, nc * chunk, channels)[:, :s_len]
-    keep = jnp.mean(jnp.exp(
-        jnp.sum(delta.reshape(bsz, nc, chunk, channels), axis=2)[..., None]
-        * a), axis=(-2, -1))
-    return y + d_skip * u[:, :s_len], keep
+    return y, jnp.moveaxis(states, 0, 1)
 
 
 def _s4d_real(key, shape, dtype=jnp.float32):

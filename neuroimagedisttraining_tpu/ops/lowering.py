@@ -1,6 +1,6 @@
 """The seam between the Pallas kernel modules (``pool_vjp``,
-``masked_attention``, ``grouped_mlp``, ``delta_rule``, ``index_select``) and
-the compiler, written once.
+``masked_attention``, ``grouped_mlp``, ``delta_rule``, ``index_select``,
+``selective_scan``) and the compiler, written once.
 
 Each of their primitives has one meaning and two spellings, and the lowering
 picks one where the program is lowered (:func:`register`): the module's

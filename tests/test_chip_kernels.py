@@ -1,7 +1,7 @@
 """The stem pool's two backward kernels, the attention product's three
 (under a selection, and under the positions' rule), the held experts' seven,
-the gated delta rule's two and the indexer's choice of keys compiled by the
-chip's own compiler
+the gated delta rule's two, the indexer's choice of keys and Mamba-1's
+selective scan's two compiled by the chip's own compiler
 at the benchmark's shapes, for a v5e that is described, not attached
 (``on-chip-measurement`` guide, section 2.3). Interpret mode passes what
 Mosaic refuses: a strided load of 16-bit data, a DMA slice of a memref whose
@@ -28,6 +28,7 @@ from neuroimagedisttraining_tpu.ops import (
     index_select,
     masked_attention,
     pool_vjp,
+    selective_scan,
 )
 
 
@@ -278,6 +279,55 @@ def test_compiled_delta_rule_kernels_stand_under_the_callers_scope(one_chip):
     assert sorted(scopes.direction(n) for n in names) == ["bwd", "fwd"]
     for name in names:
         assert scopes.under(name, "linear_attention/scan"), name
+        assert name.endswith("/pallas_call"), name
+
+
+# phi4_mini_flash_fed.longctx: 16,384 tokens, 5,120 channels held whole, 16
+# states, chunks of 64
+MAMBA = dict(tokens=(1, 16384, 5120, jnp.float32), a=(5120, 16, jnp.float32),
+             rows=(1, 16384, 16, jnp.float32),
+             states=(1, 256, 16, 5120, jnp.float32))
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+def test_the_selective_scans_kernels_compile_for_the_chip(one_chip,
+                                                          backward):
+    """At the channel block the shapes pick (every channel: VMEM holds five
+    ``[64, 5120]`` float32 tiles twice over in the backward)."""
+    a = MAMBA
+    shapes = [a["tokens"], a["tokens"], a["a"], a["rows"], a["rows"]]
+    if backward:
+        fn = functools.partial(selective_scan._backward_pallas, chunk=64)
+        shapes += [a["states"], a["tokens"]]
+    else:
+        fn = functools.partial(selective_scan._forward_pallas, chunk=64)
+    text = compiled_text(fn, one_chip, *shapes)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+
+
+def test_compiled_selective_scan_kernels_stand_under_the_callers_scope(
+        one_chip):
+    """``mamba_scan_ms_per_round`` and ``mamba_scan_roofline`` join a
+    kernel's time to ``mamba/scan`` by the ``op_name`` of the COMPILED
+    program, in both passes."""
+    def grads(u, delta, a, b, c, d):
+        def loss(u, delta, a, b, c, d):
+            with jax.named_scope("mamba"), jax.named_scope("scan"):
+                y = decoder.selective_scan(u, delta, a, b, c, d, 64)[0]
+            return y.sum()
+        return jax.grad(loss, argnums=range(6))(u, delta, a, b, c, d)
+
+    f32 = jnp.float32
+    text = compiled_text(grads, one_chip, (1, 1024, 256, f32),
+                         (1, 1024, 256, f32), (256, 16, f32),
+                         (1, 1024, 16, f32), (1, 1024, 16, f32), (256, f32))
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(scopes.direction(n) for n in names) == ["bwd", "fwd"]
+    for name in names:
+        assert scopes.under(name, "mamba/scan"), name
         assert name.endswith("/pallas_call"), name
 
 

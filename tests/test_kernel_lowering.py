@@ -21,6 +21,7 @@ from neuroimagedisttraining_tpu.ops import (
     lowering,
     masked_attention,
     pool_vjp,
+    selective_scan,
 )
 
 BF16, F32 = jnp.bfloat16, jnp.float32
@@ -29,6 +30,7 @@ ATTENTION = [(Q, BF16), (KV, BF16), (KV, BF16), ((2, 1024, 1024), jnp.int8)]
 EXPERTS = [((512, 128), BF16), ((512,), F32)] + [((2, 128, 128), BF16)] * 3 \
     + [((4,), jnp.int32)]
 LINEAR = [((2, 16, 1, 128), BF16)] * 3 + [((2, 16, 1), F32)] * 2
+MAMBA = [((2, 16, 128), F32)] * 2 + [((128, 8), F32)] + [((2, 16, 8), F32)] * 2
 
 
 def experts(backward):
@@ -66,6 +68,14 @@ CASES = {
         LINEAR + [((2, 1, 1, 128, 128), F32), ((2, 16, 1, 128), BF16)],
         dict(body=decoder._delta_rule_chunks, chunk=8, span=2),
         "linear_scan_lowerings", {"pass=backward"}, "delta_rule_backward"),
+    "selective_scan": (
+        MAMBA, dict(body=decoder._selective_scan_chunks, chunk=8),
+        "mamba_scan_lowerings", {"pass=forward"}, "selective_scan_forward"),
+    "selective_scan_backward": (
+        MAMBA + [((2, 2, 8, 128), F32), ((2, 16, 128), F32)],
+        dict(body=decoder._selective_scan_chunks, chunk=8),
+        "mamba_scan_lowerings", {"pass=backward"},
+        "selective_scan_backward"),
     "index_select": (
         [((2, 4096, 16, 64), BF16), ((2, 4096, 16), F32),
          ((2, 4096, 64), BF16)],
@@ -76,7 +86,8 @@ PRIMITIVES = {p.name: p for p in (
     pool_vjp._scatter_p, masked_attention._forward_p,
     masked_attention._backward_p, grouped_mlp._forward_p,
     grouped_mlp._backward_p, delta_rule._forward_p, delta_rule._backward_p,
-    index_select._select_p)}
+    index_select._select_p, selective_scan._forward_p,
+    selective_scan._backward_p)}
 
 
 @pytest.mark.parametrize("where", ["cpu", "tpu_interpreted_on_the_cpu",
